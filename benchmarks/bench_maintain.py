@@ -842,13 +842,18 @@ def _sharded_rows(quick: bool) -> list[str]:
     ``benchmarks/_sharded_probe.py`` for what each number means. The
     headline flags (``sharded_loss_bit_equal``, ``sharded_bytes_le_pack``,
     ``elastic_cycle_ok``) are deterministic and REQUIRED by
-    ``check_maintain_regression``; the wall-clock rides along recorded."""
+    ``check_maintain_regression``; the wall-clock rides along recorded.
+
+    The child is pinned to the CPU: its forced 8-device topology is a CPU
+    construct, and on a chip machine this process already holds the chip
+    the child would otherwise try to open. Its rows say ``platform=cpu``."""
     import os
     import subprocess
     import sys
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "benchmarks._sharded_probe"]
     if quick:
         cmd.append("--quick")
@@ -862,7 +867,7 @@ def _sharded_rows(quick: bool) -> list[str]:
     a = sh["arena"]
     rows = [csv_row(
         "maint_sweep_sharded", a["overhead_us"],
-        f"bytes_per_step={a['bytes_per_step']:.0f};"
+        f"platform=cpu;bytes_per_step={a['bytes_per_step']:.0f};"
         f"pack_bytes_per_step={sh['pytree']['bytes_per_step']:.0f};"
         f"shards={sh['shards']};"
         f"sharded_loss_bit_equal={bool(sh['loss_bit_equal'])};"
@@ -873,7 +878,7 @@ def _sharded_rows(quick: bool) -> list[str]:
         f"dcn_bytes_per_maintain={a['dcn_per_maintain']:.0f}")]
     rows.append(csv_row(
         "tier_soak_elastic_mesh", el["us_per_step"],
-        f"steps={el['steps']};mesh_resizes={el['mesh_resizes']};"
+        f"platform=cpu;steps={el['steps']};mesh_resizes={el['mesh_resizes']};"
         f"min_shards={el['min_shards']};final_shards={el['final_shards']};"
         f"live_packs={el['live_packs']};"
         f"losses_finite={bool(el['losses_finite'])};"

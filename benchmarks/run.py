@@ -2,7 +2,8 @@
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig7,...]
 
-Prints ``name,us_per_call,derived`` CSV rows. Sections:
+Prints ``name,us_per_call,derived`` CSV rows, and exits nonzero when any
+section raised (its ``<name>_ERROR`` row says what). Sections:
 
   fig3  — QP iteration cost vs Theorem 3.2 bound        (bench_qp_bound)
   fig5  — MLR random vs adversarial perturbations       (bench_mlr_bound)
@@ -17,12 +18,14 @@ Prints ``name,us_per_call,derived`` CSV rows. Sections:
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 from benchmarks import (bench_kernels, bench_maintain, bench_mlr_bound,
                         bench_overhead, bench_partial_recovery,
                         bench_priority, bench_qp_bound, bench_reset,
                         bench_tiered_recovery)
+from repro.launch.compile_cache import enable_compile_cache
 
 SECTIONS = {
     "fig3": bench_qp_bound.run,
@@ -43,6 +46,8 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else set(SECTIONS)
+    enable_compile_cache()
+    failed = []
 
     print("name,us_per_call,derived")
     for name, fn in SECTIONS.items():
@@ -51,12 +56,15 @@ def main() -> None:
         t0 = time.time()
         try:
             rows = fn(quick=args.quick)
-        except Exception as e:  # keep the harness running; report the break
+        except Exception as e:  # run the other sections, then exit nonzero
             rows = [f"{name}_ERROR,0.0,{type(e).__name__}:{e}"]
+            failed.append(name)
         for row in rows:
             print(row, flush=True)
         print(f"_section_{name}_seconds,{(time.time()-t0)*1e6:.0f},"
               f"wall={time.time()-t0:.0f}s", flush=True)
+    if failed:
+        sys.exit(f"benchmark sections failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.advisor import RunObservations, advise
 from repro.models.classic import make_model
 from repro.training import run_clean
@@ -48,4 +49,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

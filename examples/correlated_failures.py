@@ -16,6 +16,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.policy import CheckpointPolicy, RecoveryMode, SelectionStrategy
 from repro.fabric import FabricConfig, FailureDomainMap, FailureEvent
 from repro.models.classic import make_model
@@ -132,4 +133,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

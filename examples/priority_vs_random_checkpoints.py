@@ -13,6 +13,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.policy import CheckpointPolicy, RecoveryMode, SelectionStrategy
 from repro.models.classic import make_model
 from repro.training import run_clean, run_with_failure
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

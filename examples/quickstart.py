@@ -18,6 +18,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.iteration_cost import (estimate_contraction,
                                        single_perturbation_bound)
 from repro.core.policy import CheckpointPolicy
@@ -84,4 +85,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core.controller import FTController
 from repro.core.policy import CheckpointPolicy
@@ -63,4 +64,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -27,6 +27,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.checkpoint_io import ShardedCheckpointStore
 from repro.configs import get_config
 from repro.core.policy import CheckpointPolicy
@@ -108,4 +109,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

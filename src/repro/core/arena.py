@@ -39,12 +39,13 @@ raw bit patterns, not values):
 
 Alongside the word domain the layout describes a **value domain** for
 the optimizer seam: per leaf, ``seg_elems = seg_words · ratio`` f32
-values per block at ``value_offset`` — ``decode_values`` /
-``encode_values`` move between the two with one slice + bitcast per
-*run* of consecutive same-dtype leaves (coalesced; an all-bf16 model is
-a single run). For an all-f32 model ``total_values == total_words`` and
-both transforms are the identity, so gradients, moments and the
-optimizer update are bit-identical to the historical f32 arena.
+values per block — ``decode_values`` / ``encode_values`` move between
+the two with one slice per *run* of consecutive same-dtype leaves
+(coalesced; an all-bf16 model is a single run). A run of packed
+16/8-bit words decodes planar (field 0 of every word, then field 1, …).
+For an all-f32 model ``total_values == total_words`` and both
+transforms are the identity, so gradients, moments and the optimizer
+update are bit-identical to the historical f32 arena.
 
 Invariants (relied on by kernels, the store, and the property tests):
 
@@ -73,13 +74,6 @@ carries a flat ``NamedSharding`` over every mesh axis — device ``d`` of
 ``(8, 128)`` tiles by I1/I2. ``arena_block_homes`` derives the
 block→device map *from* that span ownership, so "each device owns the
 tile-aligned segments of its home blocks" holds by construction.
-
-.. warning:: jax 0.4.37's CPU SPMD partitioner miscompiles
-   ``concatenate`` of 1-D operands that carry a minor-mesh-axis
-   sharding (wrong *values*, not a perf hazard). ``pack_arena`` takes
-   ``out_sharding`` and pins every part and the result to the flat
-   arena sharding, which sidesteps the bug and is the layout we want
-   anyway; sharded callers must pass it.
 """
 from __future__ import annotations
 
@@ -92,9 +86,9 @@ import numpy as np
 
 from repro.core.blocks import (BlockPartition, decode_block_words,
                                dtype_word_ratio, expand_block_mask,
-                               leaf_block_view, leaf_block_words,
-                               leaf_frame_width, leaf_word_width,
-                               word_packable)
+                               fields_to_words, leaf_block_view,
+                               leaf_block_words, leaf_frame_width,
+                               leaf_word_width, word_fields, word_packable)
 
 PyTree = Any
 
@@ -166,7 +160,6 @@ class ArenaLayout:
     leaf_order: tuple[int, ...] = ()    # leaf indices in offset order
     payload_elems: tuple[int, ...] = () # live elements per block, per leaf
     seg_elems: tuple[int, ...] = ()     # value-domain elems per block, per leaf
-    value_offset: tuple[int, ...] = ()  # value-domain start per leaf
     total_values: int = -1              # f32 value-domain length
 
     @property
@@ -403,7 +396,6 @@ def build_arena_layout(partition: BlockPartition, shards: int = 1,
     payload_words = [0] * n
     payload_elems = [0] * n
     seg_elems = [0] * n
-    value_offset = [0] * n
     off = voff = 0
     tail_start = None
     for li in order:
@@ -418,7 +410,6 @@ def build_arena_layout(partition: BlockPartition, shards: int = 1,
         payload_words[li] = pw
         payload_elems[li] = leaf_frame_width(leaf, br)
         seg_elems[li] = seg * r
-        value_offset[li] = voff
         for b in range(leaf.n_blocks):
             blocks.append(ArenaBlock(leaf=li, gid=leaf.offset + b,
                                      offset=off, words=seg, payload=pw))
@@ -449,7 +440,6 @@ def build_arena_layout(partition: BlockPartition, shards: int = 1,
                        tail_start=tail_start, leaf_order=tuple(order),
                        payload_elems=tuple(payload_elems),
                        seg_elems=tuple(seg_elems),
-                       value_offset=tuple(value_offset),
                        total_values=total_values)
 
 
@@ -470,13 +460,11 @@ def pack_arena(values: PyTree, layout: ArenaLayout,
     value-stored (bitwise the historical layout); other word-packable
     dtypes are raw bit patterns via :func:`leaf_block_words`.
 
-    ``out_sharding`` (a flat 1-D ``NamedSharding``) pins every part and
-    the result; **required** when any input leaf is mesh-sharded — see
-    the module warning on the jax 0.4.37 sharded-``concatenate``
-    miscompile this constraint sidesteps."""
+    ``out_sharding`` (a flat 1-D ``NamedSharding``) pins the result to
+    the sharded-arena layout. Only the result is constrained: per-part
+    constraints change the gradient program's reduction order, and the
+    arena step then drifts from the PyTree step at ULP level."""
     part = layout.partition
-    con = ((lambda v: jax.lax.with_sharding_constraint(v, out_sharding))
-           if out_sharding is not None else (lambda v: v))
     leaves = jax.tree_util.tree_leaves(values)
     parts = []
     covered = 0
@@ -490,13 +478,14 @@ def pack_arena(values: PyTree, layout: ArenaLayout,
                 leaf_block_words(x, part.block_rows), jnp.float32)
         if view.shape[1] < seg:
             view = jnp.pad(view, ((0, 0), (0, seg - view.shape[1])))
-        parts.append(con(view.reshape(-1)))
+        parts.append(view.reshape(-1))
         covered += seg * leaf.n_blocks
     if layout.total_words > covered:
-        parts.append(con(jnp.zeros((layout.total_words - covered,),
-                                   jnp.float32)))
+        parts.append(jnp.zeros((layout.total_words - covered,), jnp.float32))
     out = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-    return con(out)
+    if out_sharding is not None:
+        out = jax.lax.with_sharding_constraint(out, out_sharding)
+    return out
 
 
 def _decode_leaf(arena: jnp.ndarray, layout: ArenaLayout, li: int):
@@ -529,33 +518,24 @@ def unpack_arena(arena: jnp.ndarray, layout: ArenaLayout) -> PyTree:
 def pack_values(values: PyTree, layout: ArenaLayout,
                 out_sharding=None) -> jnp.ndarray:
     """Pack a tree into the flat ``(total_values,)`` f32 value buffer —
-    the gradient/moment counterpart of :func:`pack_arena`. For an all-f32
-    layout this emits the *same program* as ``pack_arena`` (words are
-    values and ``seg_elems == seg_words``)."""
-    part = layout.partition
-    con = ((lambda v: jax.lax.with_sharding_constraint(v, out_sharding))
-           if out_sharding is not None else (lambda v: v))
-    leaves = jax.tree_util.tree_leaves(values)
-    parts = []
-    covered = 0
-    for li in layout.leaf_order:
-        x, leaf = leaves[li], part.leaves[li]
-        se = layout.seg_elems[li]
-        view = leaf_block_view(x.astype(jnp.float32), part.block_rows)
-        if view.shape[1] < se:
-            view = jnp.pad(view, ((0, 0), (0, se - view.shape[1])))
-        parts.append(con(view.reshape(-1)))
-        covered += se * leaf.n_blocks
-    if layout.total_values > covered:
-        parts.append(con(jnp.zeros((layout.total_values - covered,),
-                                   jnp.float32)))
-    out = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-    return con(out)
+    the gradient/moment counterpart of :func:`pack_arena`, and exactly
+    ``decode_values(pack_arena(values))``: the tree is packed to words in
+    its own (stored) dtypes and the words decode to f32, which is exact
+    for every packable float. For an all-f32 layout this is the *same
+    program* as ``pack_arena`` (words are values)."""
+    return decode_values(pack_arena(values, layout, out_sharding), layout)
 
 
 def decode_values(arena: jnp.ndarray, layout: ArenaLayout) -> jnp.ndarray:
-    """Word arena → ``(total_values,)`` f32 values, one slice + bitcast
-    per coalesced same-dtype run (identity for all-f32 layouts)."""
+    """Word arena → ``(total_values,)`` f32 values, one slice per
+    coalesced same-dtype run (identity for all-f32 layouts).
+
+    A run of ``nw`` words holding ``r`` elements each decodes *planar*:
+    its values are field 0 of every word, then field 1, … (see
+    :func:`~repro.core.blocks.word_fields`), not element order. The
+    optimizer is elementwise, so any fixed order serves; this one needs
+    no lane interleave, which the TPU can only do through padded
+    ``(…, r)`` intermediates."""
     if layout.uniform_f32:
         return arena
     parts = []
@@ -565,15 +545,13 @@ def decode_values(arena: jnp.ndarray, layout: ArenaLayout) -> jnp.ndarray:
             parts.append(w)
             continue
         bits = jax.lax.bitcast_convert_type(w, jnp.int32)
-        e = bits if dt == np.dtype(np.int32) \
-            else jax.lax.bitcast_convert_type(bits, dt)
-        parts.append(e.astype(jnp.float32).reshape(-1))
+        parts.extend(f.astype(jnp.float32) for f in word_fields(bits, dt))
     return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
 
 def encode_values(values: jnp.ndarray, layout: ArenaLayout) -> jnp.ndarray:
-    """Inverse of :func:`decode_values`: re-encode the f32 value buffer
-    into raw arena words (``astype`` to the stored dtype — the same
+    """Inverse of :func:`decode_values`: re-encode the (planar) f32 value
+    buffer into raw arena words (``astype`` to the stored dtype — the same
     rounding the PyTree optimizer path applies — then bitcast)."""
     if layout.uniform_f32:
         return values
@@ -584,10 +562,8 @@ def encode_values(values: jnp.ndarray, layout: ArenaLayout) -> jnp.ndarray:
             parts.append(v)
             continue
         r = dtype_word_ratio(dt)
-        e = v.astype(dt)
-        bits = e if dt == np.dtype(np.int32) else (
-            jax.lax.bitcast_convert_type(e, jnp.int32) if r == 1
-            else jax.lax.bitcast_convert_type(e.reshape(nw, r), jnp.int32))
+        bits = fields_to_words([v[k * nw:(k + 1) * nw].astype(dt)
+                                for k in range(r)])
         parts.append(jax.lax.bitcast_convert_type(bits, jnp.float32))
     return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
@@ -621,14 +597,13 @@ def arena_drift_scores(live: jnp.ndarray, ref: jnp.ndarray,
     words diff two zero words → exact +0.0 (I4)."""
     word_gid, word_code, dts = layout.word_tables()
     wc = (live - ref) ** 2
+    lbits = jax.lax.bitcast_convert_type(live, jnp.int32)
+    rbits = jax.lax.bitcast_convert_type(ref, jnp.int32)
     for k, dt in enumerate(dts, start=1):
-        r = dtype_word_ratio(dt)
-        ex = jax.lax.bitcast_convert_type(
-            jax.lax.bitcast_convert_type(live, jnp.int32), dt)
-        er = jax.lax.bitcast_convert_type(
-            jax.lax.bitcast_convert_type(ref, jnp.int32), dt)
-        d = ex.astype(jnp.float32) - er.astype(jnp.float32)
-        dk = jnp.sum(d * d, axis=-1) if r > 1 else d * d
+        dk = 0.0
+        for fl, fr in zip(word_fields(lbits, dt), word_fields(rbits, dt)):
+            d = fl.astype(jnp.float32) - fr.astype(jnp.float32)
+            dk = dk + d * d
         wc = jnp.where(jnp.asarray(word_code == k), dk, wc)
     total = layout.partition.total_blocks
     tile_gid = np.where(word_gid[::ARENA_TILE] >= 0,

@@ -17,10 +17,21 @@ Layout per leaf ``x`` of shape ``(d0, d1, ..., dn)``:
 Blocks of a leaf are contiguous row groups; global block ids concatenate
 leaves in flatten order. Padding rows (to fill the last block) are zeros on
 both sides of any distance computation, so they never affect scores.
+
+A leaf whose natural block would exceed ``MAX_BLOCK_ELEMS`` (a
+layer-stacked ``(n_layers, d_ff, d_model)`` weight has fewer rows than
+``block_rows``, so its one block is the whole stack) is viewed with
+narrower rows instead: ``row_width`` becomes the largest divisor ``w`` of
+``prod(d1..dn)`` with ``block_rows · w <= MAX_BLOCK_ELEMS``, and ``rows``
+grows to match (:func:`leaf_view_shape`). Each view row lies inside one
+natural row, so blocks stay contiguous. Parity frames are as wide as the
+widest block (``fabric/parity.py``), so one whole-stack block would size
+every group's frame to the stack.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Sequence
 
@@ -66,6 +77,33 @@ class BlockPartition:
         return max(1, math.ceil(fraction * self.total_blocks))
 
 
+# Widest block, in elements: 1 MiB of f32, 512 KiB of bf16. Wide enough
+# that no leaf of the reduced configs is cut; a published-width LM's
+# stacked layers are cut to blocks the size of its embedding's.
+MAX_BLOCK_ELEMS = 1 << 18
+
+
+@functools.lru_cache(maxsize=None)
+def leaf_view_shape(shape: tuple, block_rows: int) -> tuple[int, int]:
+    """``(rows, row_width)`` 2-D view of a leaf of this shape — the unit
+    every block op reshapes to (see the module docstring)."""
+    if not shape:
+        return 1, 1
+    rows, width = int(shape[0]), int(np.prod(shape[1:]))
+    if min(rows, block_rows) * width <= MAX_BLOCK_ELEMS:
+        return rows, width
+    limit = max(MAX_BLOCK_ELEMS // block_rows, 1)
+    best = 1
+    for d in range(1, math.isqrt(width) + 1):
+        if width % d == 0:
+            for c in (d, width // d):
+                if best < c <= limit:
+                    best = c
+    if best < 128:       # no lane-wide cut: keep the natural rows
+        return rows, width
+    return rows * (width // best), best
+
+
 def _leaf_name(path) -> str:
     return jax.tree_util.keystr(path)
 
@@ -92,8 +130,7 @@ def partition_pytree(params: PyTree, block_rows: int = 128,
     canonical_offsets: dict = {}
     for path, x in flat:
         shape = tuple(x.shape)
-        rows = shape[0] if len(shape) >= 1 else 1
-        row_width = int(np.prod(shape[1:])) if len(shape) >= 1 else 1
+        rows, row_width = leaf_view_shape(shape, block_rows)
         n_blocks = max(1, math.ceil(rows / block_rows))
         name = _leaf_name(path)
         leaf_offset = offset
@@ -160,6 +197,74 @@ def leaf_word_width(leaf: LeafMeta, block_rows: int) -> int:
     return -(-leaf_frame_width(leaf, block_rows) // r)
 
 
+def _field_bits(words: jnp.ndarray, r: int) -> list[jnp.ndarray]:
+    """The ``r`` unsigned bit fields of each int32 word, low bits first.
+
+    Shifts and masks on the words themselves: a bitcast to a trailing
+    axis of 2 or 4 elements would give the TPU an array whose minor
+    dimension is padded to 128 lanes (64× the bytes)."""
+    bits = 32 // r
+    udt = jnp.uint16 if r == 2 else jnp.uint8
+    return [(jax.lax.shift_right_logical(words, k * bits)
+             & ((1 << bits) - 1)).astype(udt) for k in range(r)]
+
+
+def word_fields(words: jnp.ndarray, dtype) -> list[jnp.ndarray]:
+    """The ``dtype_word_ratio`` elements packed in each int32 word, as
+    that many arrays shaped like ``words`` (field ``k`` holds element
+    ``k`` of every word), in ``dtype``."""
+    dt = np.dtype(dtype)
+    r = dtype_word_ratio(dt)
+    if r == 1:
+        return [words if dt == np.dtype(np.int32)
+                else jax.lax.bitcast_convert_type(words, dt)]
+    return [jax.lax.bitcast_convert_type(f, dt)
+            for f in _field_bits(words, r)]
+
+
+def words_to_elems(words: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``(..., W)`` int32 words → ``(..., W·r)`` elements of ``dtype`` in
+    element order (the inverse of :func:`elems_to_words`). The fields
+    interleave as raw bits, so NaN payloads survive."""
+    dt = np.dtype(dtype)
+    r = dtype_word_ratio(dt)
+    if r == 1:
+        return word_fields(words, dt)[0]
+    fields = _field_bits(words, r)
+    out = jnp.zeros(words.shape[:-1] + (words.shape[-1] * r,),
+                    fields[0].dtype)
+    for k, f in enumerate(fields):
+        out = out.at[..., k::r].set(f)
+    return jax.lax.bitcast_convert_type(out, dt)
+
+
+def fields_to_words(fields: list[jnp.ndarray]) -> jnp.ndarray:
+    """Inverse of :func:`word_fields`: ``r`` same-shaped arrays of one
+    word-packable dtype → int32 words, field ``k`` in bits
+    ``[k·32/r, (k+1)·32/r)``."""
+    r = len(fields)
+    if r == 1:
+        f = fields[0]
+        return f if f.dtype == jnp.int32 \
+            else jax.lax.bitcast_convert_type(f, jnp.int32)
+    bits = 32 // r
+    udt = jnp.uint16 if r == 2 else jnp.uint8
+    out = None
+    for k, f in enumerate(fields):
+        w = jax.lax.bitcast_convert_type(f, udt).astype(jnp.int32) \
+            << (k * bits)
+        out = w if out is None else out | w
+    return out
+
+
+def elems_to_words(elems: jnp.ndarray) -> jnp.ndarray:
+    """``(..., W·r)`` word-packable elements → ``(..., W)`` int32 words,
+    ``r = dtype_word_ratio`` consecutive elements per word, element 0 in
+    the low-order bits (numpy's little-endian ``.view(int32)``)."""
+    r = dtype_word_ratio(elems.dtype)
+    return fields_to_words([elems[..., k::r] for k in range(r)])
+
+
 def leaf_block_words(x: jnp.ndarray, block_rows: int) -> jnp.ndarray:
     """(n_blocks, payload_words) int32 raw bit pattern of a leaf's blocks.
 
@@ -173,16 +278,11 @@ def leaf_block_words(x: jnp.ndarray, block_rows: int) -> jnp.ndarray:
     if not word_packable(x.dtype):
         x = x.astype(jnp.float32)
     view = leaf_block_view(x, block_rows)
-    if r == 1:
-        if view.dtype == jnp.int32:
-            return view
-        return jax.lax.bitcast_convert_type(view, jnp.int32)
-    words = -(-view.shape[1] // r)
-    tail = words * r - view.shape[1]
-    if tail:
-        view = jnp.pad(view, ((0, 0), (0, tail)))
-    return jax.lax.bitcast_convert_type(
-        view.reshape(view.shape[0], words, r), jnp.int32)
+    if r > 1:
+        tail = -view.shape[1] % r
+        if tail:
+            view = jnp.pad(view, ((0, 0), (0, tail)))
+    return elems_to_words(view)
 
 
 def decode_block_words(words: jnp.ndarray, leaf: LeafMeta,
@@ -197,12 +297,8 @@ def decode_block_words(words: jnp.ndarray, leaf: LeafMeta,
     words = words[:, :pw]
     if not word_packable(dt):
         vals = jax.lax.bitcast_convert_type(words, jnp.float32)
-    elif dt == np.dtype(np.int32):
-        vals = words
     else:
-        vals = jax.lax.bitcast_convert_type(words, dt)
-        if r > 1:
-            vals = vals.reshape(words.shape[0], pw * r)
+        vals = words_to_elems(words, dt)
     vals = vals[:, :elems]
     rows = max(leaf.rows, 1)
     vals = vals.reshape(-1, max(leaf.row_width, 1))[:rows]
@@ -217,10 +313,7 @@ def leaf_block_view(x: jnp.ndarray, block_rows: int) -> jnp.ndarray:
     rows would be a 64× memory/compute blowup for zero benefit. Consumers
     reduce within blocks, so per-leaf block widths may differ.
     """
-    if x.ndim == 0:
-        x = x[None]
-    rows = x.shape[0]
-    row_width = int(np.prod(x.shape[1:])) if x.ndim > 1 else 1
+    rows, row_width = leaf_view_shape(tuple(x.shape), block_rows)
     flat = x.reshape(rows, row_width)
     n_blocks = max(1, math.ceil(rows / block_rows))
     if n_blocks == 1:
@@ -240,11 +333,17 @@ def expand_block_mask(block_mask: jnp.ndarray, leaf: LeafMeta,
                       block_rows: int) -> jnp.ndarray:
     """(n_blocks,) bool -> bool array broadcastable to the leaf shape.
 
-    Expands over rows then broadcasts across trailing dims.
+    Expands over rows then broadcasts across trailing dims. A leaf viewed
+    with narrower rows than its leading axis (:func:`leaf_view_shape`)
+    gets a full leaf-shaped mask.
     """
     row_mask = jnp.repeat(block_mask, block_rows)[:leaf.rows]
     if len(leaf.shape) == 0:
         return row_mask[0]
+    if leaf.rows != leaf.shape[0]:
+        return jnp.broadcast_to(row_mask[:, None],
+                                (leaf.rows, leaf.row_width)).reshape(
+                                    leaf.shape)
     return row_mask.reshape((leaf.rows,) + (1,) * (len(leaf.shape) - 1))
 
 
@@ -289,6 +388,12 @@ def block_scores(a: PyTree, b: PyTree, partition: BlockPartition,
     return out
 
 
+# The two norms below are jitted so that the f32 difference of a leaf is
+# reduced as it is formed: run op by op, each leaf would hold two f32
+# copies and their difference at once (2.7 GB for a 151,936-row vocab's
+# embedding at d_model 1536), on the recovery path where the device is
+# fullest.
+@functools.partial(jax.jit, static_argnums=(3,))
 def masked_sq_norm(a: PyTree, b: PyTree, global_mask: jnp.ndarray,
                    partition: BlockPartition) -> jnp.ndarray:
     """||(a − b) restricted to masked blocks||² — the δ' of Theorem 4.1."""
@@ -298,6 +403,7 @@ def masked_sq_norm(a: PyTree, b: PyTree, global_mask: jnp.ndarray,
     return jnp.sum(jnp.where(global_mask, per_block, 0.0))
 
 
+@jax.jit
 def tree_sq_norm(a: PyTree, b: PyTree) -> jnp.ndarray:
     """||a − b||² over the whole tree — the δ of full recovery."""
     diffs = jax.tree_util.tree_map(

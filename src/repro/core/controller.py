@@ -78,8 +78,8 @@ class FTController:
                                 block_rows=policy.block_rows)
         # flat-arena checkpoint state (set up after the fabric below):
         # when active, _ckpt_arena is the canonical running-checkpoint
-        # value store and _ckpt.values may be stale (_ckpt_dirty) until
-        # the ckpt property re-materializes the tree on demand
+        # value store and _ckpt.values is stale (_ckpt_dirty): the ckpt
+        # property decodes the tree on demand
         self._arena_layout = None
         self._ckpt_arena = None
         self._ckpt_dirty = False
@@ -144,6 +144,12 @@ class FTController:
                 lambda t: pack_arena(t, layout, out_sharding=sh))
             self._unpack_jit = jax.jit(lambda a: unpack_arena(a, layout))
             self._ckpt_arena = self._pack_jit(params)
+            # the arena is canonical from here on: drop the tree copy
+            # (a whole model's bytes of device memory) and let the
+            # ``ckpt`` property decode it on demand
+            self._ckpt = RunningCheckpoint(None, self._ckpt.saved_iter,
+                                           self._ckpt.rr_cursor)
+            self._ckpt_dirty = True
         if store is not None:
             if self.recorder.enabled and hasattr(store, "attach_recorder"):
                 store.attach_recorder(self.recorder)
@@ -243,13 +249,14 @@ class FTController:
     @property
     def ckpt(self) -> RunningCheckpoint:
         """The running checkpoint. In arena mode the canonical values are
-        ``_ckpt_arena``; the tree form is re-materialized here on demand
-        (recovery/analysis paths — never the per-save hot path)."""
+        ``_ckpt_arena``; the tree form is decoded here on every call
+        (recovery/analysis paths — never the per-save hot path) and not
+        kept: a kept copy would hold a second model's bytes of device
+        memory until the next save."""
         if self._ckpt_dirty:
-            values = self._unpack_jit(self._ckpt_arena)
-            self._ckpt = RunningCheckpoint(values, self._ckpt.saved_iter,
-                                           self._ckpt.rr_cursor)
-            self._ckpt_dirty = False
+            return RunningCheckpoint(self._unpack_jit(self._ckpt_arena),
+                                     self._ckpt.saved_iter,
+                                     self._ckpt.rr_cursor)
         return self._ckpt
 
     @ckpt.setter

@@ -159,17 +159,20 @@ class CheckpointFabric:
         self.replicas = (ReplicaSet(partition, self.view)
                          if self.cfg.replicate else None)
         self.parity = None
+        # GSPMD cannot partition a Mosaic kernel, and the codecs read
+        # frames of the sharded arena: on a mesh they run their jnp paths
+        codec_pallas = False if mesh is not None else self.cfg.use_pallas
         if self.cfg.parity:
             if self.cfg.rs_parity > 0:
                 from repro.fabric.rs import RSCodec
                 self.parity = RSCodec(partition, self.view,
                                       group_size=self.cfg.parity_group,
                                       n_parity=self.cfg.rs_parity,
-                                      use_pallas=self.cfg.use_pallas)
+                                      use_pallas=codec_pallas)
             else:
                 self.parity = ParityCodec(partition, self.view,
                                           group_size=self.cfg.parity_group,
-                                          use_pallas=self.cfg.use_pallas)
+                                          use_pallas=codec_pallas)
         self.planner = TieredRecovery(partition, self.view,
                                       replicas=self.replicas,
                                       parity=self.parity)
@@ -226,7 +229,10 @@ class CheckpointFabric:
             "mesh_resizes": 0, "tier_fallbacks": 0,
             "rs_arena_encodes": 0, "scrubs": 0,
             "silent_errors_detected": 0, "silent_errors_corrected": 0,
-            "arena_padding_ratio": 0.0})
+            "arena_padding_ratio": 0.0,
+            # which arena sweep the last built program runs ("pallas" or
+            # "jnp") and, for "jnp", why the Pallas kernel is not eligible
+            "arena_sweep": "", "arena_sweep_reason": ""})
         if self.arena_layout is not None:
             # gauge, not a counter: pad words / payload words of the live
             # layout — the number tail packing shrinks (run-report +
@@ -263,7 +269,7 @@ class CheckpointFabric:
         lands a whole failure domain away — the rotation maximizing
         cross-host, then cross-rack, pairs in the *bound* topology), and
         the per-transfer local/ICI/DCN byte split the maintain events
-        report."""
+        report (by physical slice, not by failure domain)."""
         from jax.sharding import Mesh, NamedSharding, PartitionSpec
         from repro.sharding.partition import arena_sharding
         self.mesh = mesh
@@ -287,14 +293,19 @@ class CheckpointFabric:
         rolled = np.roll(devs, -shift)      # span j -> devs[(j+shift) % n]
         self._replica_sharding = NamedSharding(
             Mesh(rolled, ("arena",)), PartitionSpec("arena"))
-        # classify each span's replica hop: same host = ICI, cross-host =
-        # DCN (same device = no wire at all)
+        # classify each span's replica hop by the wire it crosses: chips
+        # of one slice talk over ICI, slices over DCN (same device = no
+        # wire at all). The failure-domain map above is logical — a
+        # one-host mesh may declare every chip its own host domain — so
+        # the wire comes from where the devices physically are.
         dst = (np.arange(n) + shift) % n
+        wire = np.asarray([getattr(d, "slice_index", d.process_index)
+                           for d in devs])
         sw = self.arena_layout.shard_words * 4
-        local = int(np.sum(dst == np.arange(n))) * sw
-        ici = int(np.sum((hosts[dst] == hosts)
-                         & (dst != np.arange(n)))) * sw
-        dcn = int(np.sum(hosts[dst] != hosts)) * sw
+        moved = dst != np.arange(n)
+        local = int(np.sum(~moved)) * sw
+        ici = int(np.sum(moved & (wire[dst] == wire))) * sw
+        dcn = int(np.sum(moved & (wire[dst] != wire))) * sw
         self._xfer_split = (local, ici, dcn)
 
     def _replica_xfer(self, rep):
@@ -618,6 +629,8 @@ class CheckpointFabric:
                 out_sharding=self._arena_sharding)
             self._arena_version = self.view.version
             self._traffic = None
+            self.stats["arena_sweep"] = self._arena_fn.sweep
+            self.stats["arena_sweep_reason"] = self._arena_fn.sweep_reason
         return self._arena_fn
 
     def _fused_maintain_fn(self):

@@ -9,8 +9,9 @@ through VMEM is the roofline-optimal schedule.
 
 Layout: inputs are (n_blocks, E) with E = block_rows·row_width padded to a
 multiple of 128 lanes. Grid is (⌈n_blocks/BB⌉, ⌈E/BE⌉); the j axis walks
-element tiles and accumulates partial sums into the (BB,)-shaped output
-block, which lives in VMEM across the j sweep (revisiting grid pattern).
+element tiles and accumulates partial sums into the (BB, 1) output
+column block, which lives in VMEM across the j sweep (revisiting grid
+pattern; a rank-1 (BB,) block is not a legal TPU block shape).
 """
 from __future__ import annotations
 
@@ -34,7 +35,7 @@ def _block_dist_kernel(a_ref, b_ref, out_ref):
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
     d = a - b
-    out_ref[...] += jnp.sum(d * d, axis=1)
+    out_ref[...] += jnp.sum(d * d, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -59,8 +60,8 @@ def block_dist_pallas(a: jnp.ndarray, b: jnp.ndarray,
             pl.BlockSpec((BB, BE), lambda i, j: (i, j)),
             pl.BlockSpec((BB, BE), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((BB,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.float32),
+        out_specs=pl.BlockSpec((BB, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.float32),
         interpret=interpret,
     )(a, b)
-    return out[:n]
+    return out[:n, 0]

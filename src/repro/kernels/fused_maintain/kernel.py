@@ -38,10 +38,48 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BE = 512    # elements per tile (lanes; multiple of 128)
+BE = 512    # lanes per scatter_save column tile (multiple of 128)
+LANES = 128
+SCORE_SLOTS = 8 * LANES   # per-step scores held by one (8, 128) output tile
+# grid steps per call of a sweep whose routing is scalar-prefetched: three
+# int32 tables of this length take 384 KiB of the 1 MiB of SMEM
+SMEM_STEPS = 1 << 15
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one native ``(rows, 128)`` VMEM tile for ``dtype``: 8 for
+    32-bit, 16 for 16-bit elements."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _put_score(sc_ref, s, val):
+    """Store the scalar ``val`` of grid step ``s`` into the lane-dense
+    ``(8, 128)`` score tile that holds steps ``[s - s % 1024, +1024)``.
+
+    Consecutive steps revisit the same output tile, which stays in VMEM
+    until the index map moves on; one 4-byte slot per step keeps the
+    score output at 1/1024 of the swept words, where a ``(1, 1)`` block
+    per step is not a legal TPU block and a ``(T, 1)`` array would pad
+    every slot to a full 128-lane row in HBM."""
+    pos = s % SCORE_SLOTS
+
+    @pl.when(pos == 0)
+    def _init():
+        sc_ref[...] = jnp.zeros_like(sc_ref)
+
+    rows = jax.lax.broadcasted_iota(jnp.int32, sc_ref.shape, 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, sc_ref.shape, 1)
+    hit = (rows == pos // LANES) & (lanes == pos % LANES)
+    sc_ref[...] = jnp.where(hit, val, sc_ref[...])
+
+
+def _score_rows(n_steps: int) -> int:
+    """Rows of the lane-dense score output for ``n_steps`` grid steps."""
+    return -(-max(n_steps, 1) // SCORE_SLOTS) * 8
 
 
 # ---------------------------------------------------------------------------
@@ -51,11 +89,11 @@ BE = 512    # elements per tile (lanes; multiple of 128)
 def _fused_maintain_kernel(perm_ref, outrow_ref, first_ref, x_ref, z_ref,
                            rep_ref, sc_ref, par_ref):
     s = pl.program_id(1)
-    x = x_ref[...]                               # (1, BE), leaf dtype
+    x = x_ref[...]                               # (sub, 128), leaf dtype
     rep_ref[...] = x                             # (a) replica snapshot
     x32 = x.astype(jnp.float32)
     d = x32 - z_ref[...].astype(jnp.float32)
-    sc_ref[0, 0] = jnp.sum(d * d)                # (c) score partial
+    _put_score(sc_ref, s, jnp.sum(d * d))        # (c) score partial
     bits = jax.lax.bitcast_convert_type(x32, jnp.int32)
 
     @pl.when(first_ref[s] == 1)
@@ -81,46 +119,57 @@ def fused_maintain_pallas(x: jnp.ndarray, z: jnp.ndarray,
     first:   (S,) int32 — 1 where s is the first sorted position of its row.
     n_out_rows — number of distinct parity rows (static).
 
+    Each block row is retiled as ``(E / 128, 128)`` and swept in native
+    ``(sub, 128)`` tiles (``sub`` = 8 for f32, 16 for 16-bit dtypes).
+
     Returns (replica (S, E) x.dtype, scores (S,) f32,
     parity_contrib (n_out_rows, E) int32 — XOR of the f32 bit patterns of
     each row's member blocks).
     """
     s_dim, e = x.shape
-    e_pad = -e % BE
+    sub = _sublanes(x.dtype)
+    e_pad = -e % (sub * LANES)
     if e_pad:
         x = jnp.pad(x, ((0, 0), (0, e_pad)))
         z = jnp.pad(z, ((0, 0), (0, e_pad)))
     ep = x.shape[1]
-    jt = ep // BE
+    jt = ep // (sub * LANES)
+    x3 = x.reshape(s_dim, ep // LANES, LANES)
+    z3 = z.reshape(s_dim, ep // LANES, LANES)
+    tile = (None, sub, LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(jt, s_dim),                        # E tiles OUTER: parity row
         in_specs=[                               # revisits stay consecutive
-            pl.BlockSpec((1, BE), lambda j, s, p, o, f: (p[s], j)),
-            pl.BlockSpec((1, BE), lambda j, s, p, o, f: (p[s], j)),
+            pl.BlockSpec(tile, lambda j, s, p, o, f: (p[s], j, 0)),
+            pl.BlockSpec(tile, lambda j, s, p, o, f: (p[s], j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, BE), lambda j, s, p, o, f: (p[s], j)),
-            pl.BlockSpec((1, 1), lambda j, s, p, o, f: (p[s], j)),
-            pl.BlockSpec((1, BE), lambda j, s, p, o, f: (o[s], j)),
+            pl.BlockSpec(tile, lambda j, s, p, o, f: (p[s], j, 0)),
+            pl.BlockSpec((None, 8, LANES),
+                         lambda j, s, p, o, f: (j, s // SCORE_SLOTS, 0)),
+            pl.BlockSpec(tile, lambda j, s, p, o, f: (o[s], j, 0)),
         ],
     )
     rep, sc, par = pl.pallas_call(
         _fused_maintain_kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((s_dim, ep), x.dtype),
-            jax.ShapeDtypeStruct((s_dim, jt), jnp.float32),
-            jax.ShapeDtypeStruct((n_out_rows, ep), jnp.int32),
+            jax.ShapeDtypeStruct(x3.shape, x.dtype),
+            jax.ShapeDtypeStruct((jt, _score_rows(s_dim), LANES),
+                                 jnp.float32),
+            jax.ShapeDtypeStruct((n_out_rows, ep // LANES, LANES),
+                                 jnp.int32),
         ],
         interpret=interpret,
-    )(perm, outrow, first, x, z)
-    return rep[:, :e], jnp.sum(sc, axis=1), par[:, :e]
+    )(perm, outrow, first, x3, z3)
+    # per-step partials are in sorted order: sum the E tiles, then undo
+    # the group sort so scores come back in natural block order
+    sc_sorted = jnp.sum(sc.reshape(jt, -1)[:, :s_dim], axis=0)
+    scores = jnp.zeros((s_dim,), jnp.float32).at[perm].set(sc_sorted)
+    return (rep.reshape(s_dim, ep)[:, :e], scores,
+            par.reshape(n_out_rows, ep)[:, :e])
 
-
-# ---------------------------------------------------------------------------
-# scatter_save: donation-based in-place partial checkpoint write
-# ---------------------------------------------------------------------------
 
 # ---------------------------------------------------------------------------
 # arena_maintain: parity XOR + priority scores over the flat arena,
@@ -138,7 +187,7 @@ def _arena_maintain_kernel(perm_ref, dest_ref, first_ref, x_ref, z_ref,
     s = pl.program_id(0)
     x = x_ref[...]                               # (8, 128) f32 arena tile
     d = x - z_ref[...]
-    sc_ref[0, 0] = jnp.sum(d * d)                # per-tile score partial
+    _put_score(sc_ref, s, jnp.sum(d * d))        # per-tile score partial
     bits = jax.lax.bitcast_convert_type(x, jnp.int32)
 
     @pl.when(first_ref[s] == 1)
@@ -150,29 +199,8 @@ def _arena_maintain_kernel(perm_ref, dest_ref, first_ref, x_ref, z_ref,
         par_ref[...] ^= bits
 
 
-def arena_maintain_pallas(x2d: jnp.ndarray, z2d: jnp.ndarray,
-                          perm: jnp.ndarray, dest: jnp.ndarray,
-                          first: jnp.ndarray, n_dest_tiles: int,
-                          interpret: bool = False,
-                          ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """One maintenance sweep over the whole 2D-retiled arena.
-
-    x2d, z2d: ``(R, 128)`` float32 — live (replica) arena and running-
-    checkpoint arena, ``R`` a multiple of 8. The grid walks ``(8, 128)``
-    sublane-aligned tiles in an order sorted by parity destination:
-
-    perm:  (T,) int32 — arena tile visited at grid step ``s`` (all tiles
-           XOR-ing into one parity tile arrive consecutively).
-    dest:  (T,) int32 — compact parity output tile per sorted step.
-    first: (T,) int32 — 1 at the first step of its destination (seed vs
-           fold, exactly the per-leaf kernel's revisit accumulation).
-
-    Returns ``(sc (T, 1) f32 per-step score partials, par
-    (n_dest_tiles·8, 128) int32 compact parity tiles)``. The caller
-    segment-sums ``sc`` by block id and scatters ``par`` into the
-    ``(n_groups, frame_elems)`` codec layout (both O(output) epilogues —
-    the O(model) sweep is this single dispatch).
-    """
+def _arena_maintain_call(x2d, z2d, perm, dest, first, n_dest_tiles,
+                         interpret):
     t = perm.shape[0]
     br = ARENA_SUBLANES
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -183,7 +211,8 @@ def arena_maintain_pallas(x2d: jnp.ndarray, z2d: jnp.ndarray,
             pl.BlockSpec((br, ARENA_LANES), lambda s, p, d, f: (p[s], 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1), lambda s, p, d, f: (s, 0)),
+            pl.BlockSpec((8, ARENA_LANES),
+                         lambda s, p, d, f: (s // SCORE_SLOTS, 0)),
             pl.BlockSpec((br, ARENA_LANES), lambda s, p, d, f: (d[s], 0)),
         ],
     )
@@ -191,12 +220,76 @@ def arena_maintain_pallas(x2d: jnp.ndarray, z2d: jnp.ndarray,
         _arena_maintain_kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((t, 1), jnp.float32),
+            jax.ShapeDtypeStruct((_score_rows(t), ARENA_LANES), jnp.float32),
             jax.ShapeDtypeStruct((n_dest_tiles * br, ARENA_LANES), jnp.int32),
         ],
         interpret=interpret,
-    )(perm, dest, first, x2d, z2d)
-    return sc, par
+    )(jnp.asarray(perm), jnp.asarray(dest), jnp.asarray(first), x2d, z2d)
+    return sc.reshape(-1)[:t], par
+
+
+def _dest_chunks(first: np.ndarray) -> list[tuple[int, int]]:
+    """Split ``[0, T)`` into runs of at most ``SMEM_STEPS`` steps that
+    start where a destination starts, so no parity tile's contributors
+    straddle two calls."""
+    t = first.size
+    starts = np.nonzero(first)[0]
+    out, lo = [], 0
+    while lo < t:
+        hi = t
+        if t - lo > SMEM_STEPS:
+            hi = int(starts[np.searchsorted(starts, lo + SMEM_STEPS,
+                                            side="right") - 1])
+            assert hi > lo, "a parity tile has more contributors than fit"
+        out.append((lo, hi))
+        lo = hi
+    return out
+
+
+def arena_maintain_pallas(x2d: jnp.ndarray, z2d: jnp.ndarray,
+                          perm: np.ndarray, dest: np.ndarray,
+                          first: np.ndarray, n_dest_tiles: int,
+                          interpret: bool = False,
+                          ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One maintenance sweep over the whole 2D-retiled arena.
+
+    x2d, z2d: ``(R, 128)`` float32 — live (replica) arena and running-
+    checkpoint arena, ``R`` a multiple of 8. The grid walks ``(8, 128)``
+    sublane-aligned tiles in an order sorted by parity destination. The
+    routing is host-resident (static per striping):
+
+    perm:  (T,) int32 — arena tile visited at grid step ``s`` (all tiles
+           XOR-ing into one parity tile arrive consecutively).
+    dest:  (T,) int32 — compact parity output tile per sorted step.
+    first: (T,) int32 — 1 at the first step of its destination (seed vs
+           fold, exactly the per-leaf kernel's revisit accumulation).
+
+    The routing tables are scalar-prefetched into SMEM, which holds
+    ``SMEM_STEPS`` steps of them; a longer sweep is cut at destination
+    starts into several calls in the same program, each writing its own
+    contiguous range of parity tiles.
+
+    Returns ``(sc (T,) f32 per-step score partials, par
+    (n_dest_tiles·8, 128) int32 compact parity tiles)``. The caller
+    segment-sums ``sc`` by block id and scatters ``par`` into the
+    ``(n_groups, frame_elems)`` codec layout (both O(output) epilogues).
+    """
+    perm, dest, first = (np.asarray(a, np.int32) for a in (perm, dest, first))
+    if perm.size == 0:
+        return (jnp.zeros((0,), jnp.float32),
+                jnp.zeros((n_dest_tiles * ARENA_SUBLANES, ARENA_LANES),
+                          jnp.int32))
+    scs, pars = [], []
+    for lo, hi in _dest_chunks(first):
+        d0, d1 = int(dest[lo]), int(dest[hi - 1]) + 1
+        sc, par = _arena_maintain_call(x2d, z2d, perm[lo:hi],
+                                       dest[lo:hi] - d0, first[lo:hi],
+                                       d1 - d0, interpret)
+        scs.append(sc)
+        pars.append(par)
+    if len(scs) == 1:
+        return scs[0], pars[0]
+    return jnp.concatenate(scs), jnp.concatenate(pars)
 
 
 # ---------------------------------------------------------------------------
@@ -214,27 +307,32 @@ def arena_scatter_pallas(dst2d: jnp.ndarray, src2d: jnp.ndarray,
     """Copy the selected ``(8, 128)`` tiles of ``src2d`` into ``dst2d``
     in place (``dst2d`` donated/aliased — unselected tiles are never
     DMA'd). ``tiles``: (k,) int32 tile indices, duplicates idempotent
-    (bucket padding). The whole-model partial save is this one dispatch —
-    the per-leaf ``scatter_save`` launched one program per touched leaf.
+    (bucket padding). The whole-model partial save is one program: the
+    tile list is scalar-prefetched ``SMEM_STEPS`` at a time, each call
+    aliasing the previous one's output — the per-leaf ``scatter_save``
+    launched one program per touched leaf.
     """
-    k = tiles.shape[0]
     br = ARENA_SUBLANES
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(k,),
-        in_specs=[
-            pl.BlockSpec((br, ARENA_LANES), lambda i, t: (t[i], 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # aliased, untouched
-        ],
-        out_specs=pl.BlockSpec((br, ARENA_LANES), lambda i, t: (t[i], 0)),
-    )
-    return pl.pallas_call(
-        _arena_scatter_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(dst2d.shape, dst2d.dtype),
-        input_output_aliases={2: 0},             # dst (after scalars) -> out
-        interpret=interpret,
-    )(tiles, src2d, dst2d)
+    out = dst2d
+    for lo in range(0, tiles.shape[0], SMEM_STEPS):
+        part = tiles[lo:lo + SMEM_STEPS]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(part.shape[0],),
+            in_specs=[
+                pl.BlockSpec((br, ARENA_LANES), lambda i, t: (t[i], 0)),
+                pl.BlockSpec(memory_space=pl.ANY),     # aliased, untouched
+            ],
+            out_specs=pl.BlockSpec((br, ARENA_LANES), lambda i, t: (t[i], 0)),
+        )
+        out = pl.pallas_call(
+            _arena_scatter_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(dst2d.shape, dst2d.dtype),
+            input_output_aliases={2: 0},         # dst (after scalars) -> out
+            interpret=interpret,
+        )(part, src2d, out)
+    return out
 
 
 def _scatter_save_kernel(rows_ref, src_ref, dst_ref, out_ref):
@@ -267,7 +365,7 @@ def scatter_save_pallas(dst: jnp.ndarray, src: jnp.ndarray,
         grid=(k, jt),
         in_specs=[
             pl.BlockSpec((br, bw), lambda i, j, rows: (rows[i], j)),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # aliased, untouched
+            pl.BlockSpec(memory_space=pl.ANY),     # aliased, untouched
         ],
         out_specs=pl.BlockSpec((br, bw), lambda i, j, rows: (rows[i], j)),
     )

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.blocks import (BlockPartition, leaf_block_view,
-                               leaf_block_words)
+                               leaf_block_words, leaf_view_shape)
 from repro.fabric.parity import FrameLayout
 from repro.kernels.fused_maintain.kernel import (fused_maintain_pallas,
                                                  scatter_save_pallas)
@@ -286,10 +286,17 @@ class ArenaMaintainProgram:
             interpret = not _is_tpu()
         # the compiled arena kernel assumes words == f32 values on
         # exclusively owned aligned tiles; quantized or tail-packed
-        # layouts run the jnp word sweep (same outputs) instead
-        pallas_eligible = (arena_layout.uniform_f32
-                           and not arena_layout.has_tail)
-        use_pallas = bool(use_pallas and pallas_eligible)
+        # layouts run the jnp word sweep (same outputs) instead, and so
+        # does a mesh-sharded arena (GSPMD cannot partition a Mosaic
+        # kernel). ``sweep``/``sweep_reason`` say which one this runs
+        reasons = [why for why, bad in (
+            ("pallas off", not use_pallas),
+            ("not uniform_f32", not arena_layout.uniform_f32),
+            ("has_tail", arena_layout.has_tail),
+            ("sharded", out_sharding is not None)) if bad]
+        use_pallas = not reasons
+        self.sweep = "pallas" if use_pallas else "jnp"
+        self.sweep_reason = ", ".join(reasons)
         self.layout = arena_layout
         self.routing = arena_routing(arena_layout, frame_layout, group_of)
         r = self.routing
@@ -297,9 +304,6 @@ class ArenaMaintainProgram:
         n_dest = int(r.touched.size)
         full_tiles = n_groups * r.frame_tiles
         frame_elems = frame_layout.frame_elems
-        perm = jnp.asarray(r.perm)
-        dest = jnp.asarray(r.dest)
-        first = jnp.asarray(r.first)
         touched = jnp.asarray(r.touched)
         members = jnp.asarray(np.where(r.members >= 0, r.members, 0))
         valid = jnp.asarray(r.members >= 0)
@@ -342,8 +346,8 @@ class ArenaMaintainProgram:
                     arena_maintain_pallas
                 sc, par = arena_maintain_pallas(
                     rep.reshape(-1, 128), z_arena.reshape(-1, 128),
-                    perm, dest, first, n_dest, interpret=interpret)
-                scores = jax.ops.segment_sum(sc[:, 0], gid_sorted,
+                    r.perm, r.dest, r.first, n_dest, interpret=interpret)
+                scores = jax.ops.segment_sum(sc, gid_sorted,
                                              num_segments=total)
                 par_c = par.reshape(n_dest, ARENA_TILE)
             else:
@@ -370,9 +374,7 @@ class ArenaMaintainProgram:
             return scores, parity.reshape(n_groups, frame_elems)
 
         # ``out_sharding`` (SPMD meshes) pins the internal pack to the
-        # flat arena sharding — both the layout the sweep wants and the
-        # workaround for jax 0.4.37's sharded-concatenate miscompile
-        # (see core/arena.py)
+        # flat arena sharding the sweep reads
         def _scored(params, z_arena):
             rep = pack_arena(params, arena_layout, out_sharding=out_sharding)
             scores, parity = _sweep(rep, z_arena)
@@ -493,9 +495,14 @@ def arena_scatter_save(dst_arena: jnp.ndarray, src_arena: jnp.ndarray,
 
     ``global_idx``: host-resident selected global block ids (colocated
     leaves' segments ride along — they share gids). Returns
-    ``(updated_arena, bytes_moved)``; ``dst_arena`` is donated."""
+    ``(updated_arena, bytes_moved)``; ``dst_arena`` is donated. An arena
+    sharded over several devices takes the jnp scatter: GSPMD cannot
+    partition a Mosaic kernel."""
     if use_pallas is None:
         use_pallas = _is_tpu()
+    sharding = getattr(dst_arena, "sharding", None)
+    if sharding is not None and len(sharding.device_set) > 1:
+        use_pallas = False
     if interpret is None:
         interpret = not _is_tpu()
     main, tail = arena_layout.split_tail_blocks(global_idx)
@@ -559,8 +566,7 @@ def _scatter_leaf_fn(shape: tuple, dtype, k_hat: int, block_rows: int,
     fn = _SCATTER_CACHE.get(key)
     if fn is not None:
         return fn
-    rows_total = shape[0] if len(shape) >= 1 else 1
-    width = int(np.prod(shape[1:])) if len(shape) >= 1 else 1
+    rows_total, width = leaf_view_shape(shape, block_rows)
 
     def _scatter(dst, src, sel):
         d2 = dst.reshape(max(rows_total, 1), max(width, 1))

@@ -7,7 +7,8 @@ path builds a (rows, 1)-broadcast bool per leaf) and performs exactly one
 HBM read per input element and one write — memory-roofline optimal.
 
 Grid/layout identical to block_dist: (n_blocks, E) tiles of (BB, BE);
-the (BB,) int32 mask block rides along the i axis.
+the mask rides along the i axis as a (BB, 1) int32 column block (a
+rank-1 (BB,) block is not a legal TPU block shape).
 """
 from __future__ import annotations
 
@@ -22,8 +23,7 @@ BE = 512
 
 
 def _masked_restore_kernel(dst_ref, src_ref, mask_ref, out_ref):
-    m = mask_ref[...]                        # (BB,) int32
-    sel = (m > 0)[:, None]
+    sel = mask_ref[...] > 0                  # (BB, 1)
     out_ref[...] = jnp.where(sel, src_ref[...], dst_ref[...])
 
 
@@ -35,11 +35,11 @@ def masked_restore_pallas(dst: jnp.ndarray, src: jnp.ndarray,
     n, e = dst.shape
     n_pad = -n % BB
     e_pad = -e % BE
-    mask_i = mask.astype(jnp.int32)
+    mask_i = mask.astype(jnp.int32).reshape(n, 1)
     if n_pad or e_pad:
         dst = jnp.pad(dst, ((0, n_pad), (0, e_pad)))
         src = jnp.pad(src, ((0, n_pad), (0, e_pad)))
-        mask_i = jnp.pad(mask_i, (0, n_pad))
+        mask_i = jnp.pad(mask_i, ((0, n_pad), (0, 0)))
     np_, ep_ = dst.shape
     grid = (np_ // BB, ep_ // BE)
     out = pl.pallas_call(
@@ -48,7 +48,7 @@ def masked_restore_pallas(dst: jnp.ndarray, src: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((BB, BE), lambda i, j: (i, j)),
             pl.BlockSpec((BB, BE), lambda i, j: (i, j)),
-            pl.BlockSpec((BB,), lambda i, j: (i,)),
+            pl.BlockSpec((BB, 1), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((BB, BE), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, ep_), dst.dtype),

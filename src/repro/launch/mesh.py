@@ -10,23 +10,17 @@ importing this module never touches jax device state.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; older releases lack it
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def mesh_axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` kwargs for ``jax.make_mesh``, or ``{}`` on jax
-    versions without ``jax.sharding.AxisType`` (everything is Auto there)."""
-    if AxisType is None:
-        return {}
+    """``axis_types=`` kwargs for ``jax.make_mesh``: every axis Auto."""
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` that works across jax versions (Auto axis types)."""
+    """``jax.make_mesh`` with Auto axis types (GSPMD sharding propagation
+    on every axis)."""
     return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
 
 

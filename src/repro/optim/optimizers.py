@@ -49,9 +49,11 @@ class Optimizer:
     name: str = "opt"
 
 
+# ``zeros_like`` (not ``zeros(shape)``) keeps a mesh-sharded param's
+# sharding: plain ``zeros`` would put every moment on the default device
 def _zeros_like_f32(params):
     return jax.tree_util.tree_map(
-        lambda x: jnp.zeros(x.shape, jnp.float32), params)
+        lambda x: jnp.zeros_like(x, dtype=jnp.float32), params)
 
 
 def sgd(lr: float) -> Optimizer:
@@ -98,7 +100,7 @@ def adamw(lr: float, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
 def _adam_like(lr, b1, b2, eps, wd, name, moment_dtype=jnp.float32) -> Optimizer:
     def _zeros_like_m(params):
         return jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, moment_dtype), params)
+            lambda x: jnp.zeros_like(x, dtype=moment_dtype), params)
 
     def init(params):
         return OptState(jnp.zeros((), jnp.int32),

@@ -106,13 +106,14 @@ def make_arena_train_step(ops: ModelOps, cfg: ModelConfig, ctx: DistContext,
     adam moments carry the flat :func:`~repro.sharding.partition
     .arena_sharding` (each device owns a contiguous tile-aligned span),
     decoded leaves are constrained to the model's FSDP+TP partition
-    specs, and the grads pack pins every part to the flat sharding
-    (both the layout we want and the workaround for jax 0.4.37's
-    sharded-``concatenate`` miscompile — see ``core/arena.py``). The
+    specs, and the packed grads land on the flat sharding. The
     elementwise apply partitions exactly along the flat shards, so the
     sharded step stays bit-equal to the PyTree step *on the same mesh*
     (asserted in ``tests/test_sharded_arena.py``; across topologies,
-    reduction order differs at ULP level as with any SPMD change).
+    reduction order differs at ULP level as with any SPMD change). Only
+    the packed result carries the constraint: constraining each packed
+    part as well changes how XLA reduces the gradients, and the two
+    steps then part at ULP level within a few steps.
     """
     from repro.core.arena import pack_values, unpack_arena
     from repro.sharding.partition import (arena_sharding,

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_config
 from repro.core.arena import (ARENA_TILE, ArenaLayout, arena_compatible,
                               arena_restore, build_arena_layout,
                               frames_from_arena, frames_gather_index,
@@ -393,8 +394,8 @@ def test_arena_ckpt_tree_materialization_is_lazy_and_correct():
     ctl.maintain(1, live)
     ctl.checkpoint_now(1, live)
     assert ctl._ckpt_dirty                      # hot path left it lazy
-    vals = ctl.ckpt.values                      # materializes once
-    assert not ctl._ckpt_dirty
+    vals = ctl.ckpt.values                      # decoded on demand ...
+    assert ctl._ckpt_dirty and ctl._ckpt.values is None   # ... not kept
     _tree_equal(vals, unpack_arena(ctl._ckpt_arena, ctl._arena_layout))
 
 
@@ -471,3 +472,58 @@ def test_arena_store_one_append_write_per_host(tmp_path, monkeypatch):
     ctl.checkpoint_now(1, live)
     store.flush()
     assert writes and all(n <= 4 for n in writes)   # ≤ one per host shard
+
+
+@pytest.mark.parametrize("smem_steps", [3, 8])
+def test_arena_kernels_split_by_smem_capacity(monkeypatch, smem_steps):
+    """A sweep or scatter longer than the SMEM routing capacity runs as
+    several calls (cut at parity-destination starts for the sweep) with
+    the same outputs as one call."""
+    from repro.kernels.fused_maintain import kernel as fk
+    params = _params()
+    part = partition_pytree(params, 16)
+    codec = _codec(params, part)
+    lay = build_arena_layout(part, tail_pack=False)
+    r = arena_routing(lay, codec.layout, codec.group_of)
+    x2d = pack_arena(params, lay).reshape(-1, 128)
+    z2d = pack_arena(_drift(params), lay).reshape(-1, 128)
+    tiles = jnp.asarray(np.arange(lay.n_tiles, dtype=np.int32)[::-1])
+    n_dest = int(r.touched.size)
+    want_sc, want_par = fk.arena_maintain_pallas(
+        x2d, z2d, r.perm, r.dest, r.first, n_dest, interpret=True)
+    want_dst = fk.arena_scatter_pallas(jnp.zeros_like(z2d), x2d, tiles,
+                                       interpret=True)
+    monkeypatch.setattr(fk, "SMEM_STEPS", smem_steps)
+    assert len(fk._dest_chunks(r.first)) > 1
+    sc, par = fk.arena_maintain_pallas(x2d, z2d, r.perm, r.dest, r.first,
+                                       n_dest, interpret=True)
+    np.testing.assert_array_equal(np.asarray(par), np.asarray(want_par))
+    np.testing.assert_array_equal(np.asarray(sc), np.asarray(want_sc))
+    dst = fk.arena_scatter_pallas(jnp.zeros_like(z2d), x2d, tiles,
+                                  interpret=True)
+    np.testing.assert_array_equal(np.asarray(dst), np.asarray(want_dst))
+    np.testing.assert_array_equal(np.asarray(dst), np.asarray(x2d))
+
+
+@pytest.mark.parametrize("model,want", [
+    ("reduced_f32", ("jnp", "has_tail")),
+    ("reduced_bf16", ("jnp", "not uniform_f32, has_tail")),
+    ("aligned_f32", ("pallas", "")),
+])
+def test_fabric_records_which_arena_sweep_runs(model, want):
+    """The fabric's stats say which arena sweep runs and why the Pallas
+    kernel was passed over (reduced qwen2: its sub-tile k/v biases put a
+    tail region in the layout)."""
+    import dataclasses
+    from repro.models import get_model
+    if model == "aligned_f32":
+        params = {"w": jnp.asarray(RNG.normal(size=(64, 128)), jnp.float32)}
+    else:
+        cfg = get_config("qwen2-1.5b", reduced=True)
+        if model == "reduced_bf16":
+            cfg = dataclasses.replace(cfg, dtype="bfloat16")
+        params = get_model(cfg).init_params(jax.random.PRNGKey(0), cfg)
+    part = partition_pytree(params, 16)
+    fab = CheckpointFabric(part, FabricConfig(use_pallas=True))
+    fab.maintain(1, params)
+    assert (fab.stats["arena_sweep"], fab.stats["arena_sweep_reason"]) == want

@@ -160,15 +160,14 @@ def test_arena_apply_matches_tree_update(opt_name):
                 == np.asarray(arena)).all(), f"step {i} diverged"
     # word-domain pads still zero after three updates
     pad_mask = np.ones((layout.total_words,), bool)
-    vpad_mask = np.ones((layout.total_values,), bool)
     for li, leaf in enumerate(part.leaves):
         off, seg, pay = (layout.leaf_offset[li], layout.seg_words[li],
                          layout.payload_words[li])
-        voff, vseg, vpay = (layout.value_offset[li], layout.seg_elems[li],
-                            layout.payload_elems[li])
         for b in range(leaf.n_blocks):
             pad_mask[off + b * seg: off + b * seg + pay] = False
-            vpad_mask[voff + b * vseg: voff + b * vseg + vpay] = False
+    # value-domain payload positions: where a tree of ones decodes to 1
+    ones = jax.tree_util.tree_map(jnp.ones_like, params)
+    vpad_mask = np.asarray(pack_values(ones, layout)) == 0.0
     assert (np.asarray(arena)[pad_mask] == 0.0).all()
     if opt_name == "adamw":
         # moments are value-domain mirrors; their pads stay zero too

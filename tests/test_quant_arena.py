@@ -242,15 +242,20 @@ def test_decode_encode_value_domain():
 
 def test_decode_values_matches_astype_f32():
     """Per-leaf semantics: the decoded f32 values of a bf16 leaf are
-    exactly ``leaf.astype(float32)`` (widening, hence lossless)."""
+    exactly ``leaf.astype(float32)`` (widening, hence lossless), laid out
+    planar — the low element of every word, then the high one."""
     rng = np.random.default_rng(17)
     w = _leaf((16, 6), jnp.bfloat16, rng)
     part = partition_pytree({"w": w}, 16)
     lay = build_arena_layout(part)
     vals = np.asarray(decode_values(pack_arena({"w": w}, lay), lay))
-    want = np.asarray(w).astype(np.float32).ravel()
-    np.testing.assert_array_equal(vals[:want.size], want)
-    np.testing.assert_array_equal(vals[want.size:], 0.0)
+    flat = np.asarray(w).astype(np.float32).ravel()
+    nw = lay.value_runs()[0][1]          # words of the bf16 run
+    np.testing.assert_array_equal(vals[:flat.size // 2], flat[0::2])
+    np.testing.assert_array_equal(vals[nw:nw + flat.size // 2], flat[1::2])
+    payload = np.zeros(vals.shape, bool)
+    payload[:flat.size // 2] = payload[nw:nw + flat.size // 2] = True
+    np.testing.assert_array_equal(vals[~payload], 0.0)
 
 
 def test_value_domain_identity_for_f32():

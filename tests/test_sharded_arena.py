@@ -222,6 +222,11 @@ assert all(bool((np.asarray(x) == np.asarray(y)).all())
 fab = la.controller.fabric
 assert fab.stats["live_packs"] == 0
 assert fab.stats["arena_resident_maintains"] == fab.stats["arena_maintains"]
+# GSPMD cannot partition a Mosaic kernel: the meshed sweep and codecs
+# take their jnp paths, and the stats say so
+assert fab.stats["arena_sweep"] == "jnp"
+assert "sharded" in fab.stats["arena_sweep_reason"], fab.stats
+assert fab.parity.use_pallas is False
 # the replica landed on a rotated device order (anti-affinity is real)
 rot = [d.id for d in fab._replica_sharding.mesh.devices.reshape(-1)]
 assert rot != sorted(rot), rot

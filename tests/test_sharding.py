@@ -86,6 +86,27 @@ def test_topology_aware_failure_mask(fake16):
     assert 0.1 <= mask.mean() <= 0.45
 
 
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code;
+    without it the cache goes to the fixed ``<repo>/.jax_cache``."""
+    import pathlib
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"
+            assert compile_cache.enable_compile_cache() == str(want)
+            assert jax.config.jax_compilation_cache_dir == str(want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_real_1x1_mesh_constraint_roundtrip(mesh):
     ctx = make_dist_ctx(mesh)
     x = jnp.ones((4, 8))

@@ -131,3 +131,25 @@ def test_microbatched_train_step_matches_single():
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    rtol=1e-4, atol=1e-5)
+
+
+def test_benchmark_driver_exits_nonzero_when_a_section_raises(
+        monkeypatch, capsys):
+    """A section that raises still prints its ``_ERROR`` row, the other
+    sections still run, and the driver's exit code says it failed."""
+    import sys
+    from benchmarks import run
+
+    def broken(quick):
+        raise RuntimeError("boom")
+
+    monkeypatch.setattr(run, "SECTIONS", {
+        "good": lambda quick: ["good_row,1.0,x=1"], "bad": broken})
+    monkeypatch.setattr(run, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", ["run"])
+    with pytest.raises(SystemExit) as exc:
+        run.main()
+    assert exc.value.code not in (0, None)
+    out = capsys.readouterr().out
+    assert "good_row,1.0,x=1" in out
+    assert "bad_ERROR,0.0,RuntimeError:boom" in out
