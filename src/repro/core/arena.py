@@ -78,6 +78,7 @@ tile-aligned segments of its home blocks" holds by construction.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -677,13 +678,21 @@ def arena_restore(dst: PyTree, arena: jnp.ndarray, global_mask,
     for li, (x, leaf) in enumerate(zip(jax.tree_util.tree_leaves(dst),
                                        part.leaves)):
         seg = mask[leaf.offset:leaf.offset + leaf.n_blocks]
-        if not seg.any():
-            out.append(x)
-            continue
-        decoded = _decode_leaf(arena, layout, li).astype(x.dtype)
-        em = expand_block_mask(jnp.asarray(seg), leaf, part.block_rows)
-        out.append(jnp.where(em, decoded, x))
+        out.append(_restore_leaf(x, arena, jnp.asarray(seg), layout, li)
+                   if seg.any() else x)
     return jax.tree_util.tree_unflatten(part.treedef, out)
+
+
+# One program per leaf: run op by op, the decode of a packed leaf holds
+# several leaf-sized intermediates at once — 4 GB over the steady state
+# for a 151,936-row bf16 embedding at d_model 1536, on the recovery path
+# where the device is fullest.
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _restore_leaf(x, arena, seg, layout: ArenaLayout, li: int):
+    leaf = layout.partition.leaves[li]
+    decoded = _decode_leaf(arena, layout, li).astype(x.dtype)
+    em = expand_block_mask(seg, leaf, layout.partition.block_rows)
+    return jnp.where(em, decoded, x)
 
 
 # ---------------------------------------------------------------------------
