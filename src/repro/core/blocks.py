@@ -301,10 +301,10 @@ def decode_block_words(words: jnp.ndarray, leaf: LeafMeta,
         vals = words_to_elems(words, dt)
     vals = vals[:, :elems]
     rows = max(leaf.rows, 1)
-    # the barrier keeps the TPU compiler from fusing the two reshapes
-    # into one relayout (see leaf_block_view)
-    vals = jax.lax.optimization_barrier(
-        vals.reshape(-1, max(leaf.row_width, 1))[:rows])
+    vals = vals.reshape(-1, max(leaf.row_width, 1))[:rows]
+    if leaf.shape and leaf.rows != leaf.shape[0]:
+        # a cut leaf: keep the two reshapes apart (see leaf_block_view)
+        vals = jax.lax.optimization_barrier(vals)
     return vals.reshape(leaf.shape).astype(leaf.dtype)
 
 
@@ -317,12 +317,14 @@ def leaf_block_view(x: jnp.ndarray, block_rows: int) -> jnp.ndarray:
     reduce within blocks, so per-leaf block widths may differ.
     """
     rows, row_width = leaf_view_shape(tuple(x.shape), block_rows)
-    # Leaf -> rows -> blocks are two relayouts on a TPU when the rows are
-    # narrower than the leaf's trailing dims. Fused into one, the v5e
-    # compiler spends over a minute on it for a (4, 1536, 8960) bf16 leaf
-    # (80.8 s, against 2.8 s apart, on a CPU host); the barrier keeps them
-    # apart and leaves every program's device memory as it was.
-    flat = jax.lax.optimization_barrier(x.reshape(rows, row_width))
+    flat = x.reshape(rows, row_width)
+    if x.ndim and rows != x.shape[0]:
+        # A cut leaf: leaf -> rows -> blocks are two relayouts on a TPU.
+        # Fused into one, the v5e compiler spends over a minute on it for
+        # a (4, 1536, 8960) bf16 leaf (80.8 s, against 2.8 s apart, on a
+        # CPU host); the barrier keeps them apart and leaves every
+        # program's device memory as it was.
+        flat = jax.lax.optimization_barrier(flat)
     n_blocks = max(1, math.ceil(rows / block_rows))
     if n_blocks == 1:
         return flat.reshape(1, rows * row_width)

@@ -107,13 +107,19 @@ def make_arena_train_step(ops: ModelOps, cfg: ModelConfig, ctx: DistContext,
     .arena_sharding` (each device owns a contiguous tile-aligned span),
     decoded leaves are constrained to the model's FSDP+TP partition
     specs, and the packed grads land on the flat sharding. The
-    elementwise apply partitions exactly along the flat shards, so the
-    sharded step stays bit-equal to the PyTree step *on the same mesh*
-    (asserted in ``tests/test_sharded_arena.py``; across topologies,
-    reduction order differs at ULP level as with any SPMD change). Only
-    the packed result carries the constraint: constraining each packed
-    part as well changes how XLA reduces the gradients, and the two
-    steps then part at ULP level within a few steps.
+    elementwise apply partitions exactly along the flat shards. On a
+    forced-CPU mesh the sharded step is bit-equal to the PyTree step on
+    the same mesh (asserted in ``tests/test_sharded_arena.py``). On a
+    TPU v5e 2x2 mesh it is not: at qwen2-1.5b widths (4 layers, f32) the
+    step-1 losses agree and the losses then part by up to 2.0e-5
+    relative within 8 steps; at ``jax_default_matmul_precision=highest``
+    the gap falls to 7.7e-8 (one f32 ulp at one of the 8 steps), so the
+    default one-pass bf16 matmul precision accounts for most of it.
+    Across topologies reduction order differs at ULP level as with any
+    SPMD change. Only the packed result carries
+    the constraint: constraining each packed part as well changes how
+    XLA reduces the gradients, and the two steps then part at ULP level
+    within a few steps on the CPU too.
     """
     from repro.core.arena import pack_values, unpack_arena
     from repro.sharding.partition import (arena_sharding,

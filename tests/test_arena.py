@@ -527,3 +527,65 @@ def test_fabric_records_which_arena_sweep_runs(model, want):
     fab = CheckpointFabric(part, FabricConfig(use_pallas=True))
     fab.maintain(1, params)
     assert (fab.stats["arena_sweep"], fab.stats["arena_sweep_reason"]) == want
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_cut_leaf_round_trips_bit_exact(monkeypatch, tmp_path, dtype):
+    """A layer-stacked leaf wider than ``MAX_BLOCK_ELEMS`` is viewed with
+    narrower rows (``leaf_view_shape``). Pack/unpack, the arena and kernel
+    partial restores, and the arena store's write/read must all treat
+    block ``b`` as view rows ``[b·br, (b+1)·br)``, bit for bit."""
+    from repro.checkpoint_io import ShardedCheckpointStore
+    from repro.core import blocks
+    from repro.kernels.masked_restore.ops import tree_masked_restore
+    br = 16
+    monkeypatch.setattr(blocks, "MAX_BLOCK_ELEMS", br * 128)
+    blocks.leaf_view_shape.cache_clear()
+    try:
+        def tree(scale):
+            return {"stack": jnp.asarray(scale * RNG.normal(size=(3, 24, 256)),
+                                         dtype),
+                    "emb": jnp.asarray(scale * RNG.normal(size=(40, 128)),
+                                       dtype),
+                    "b": jnp.asarray(scale * RNG.normal(size=(5,)), dtype)}
+        params, other = tree(1.0), tree(3.0)
+        part = partition_pytree(params, br)
+        stack = part.leaves[[l.name for l in part.leaves].index("['stack']")]
+        assert stack.rows != stack.shape[0] and stack.n_blocks > 1, stack
+        lay = build_arena_layout(part)
+        arena = jax.jit(lambda p: pack_arena(p, lay))(params)
+        _tree_equal(unpack_arena(arena, lay), params)
+
+        mask = np.zeros((part.total_blocks,), bool)
+        mask[stack.offset + np.arange(0, stack.n_blocks, 2)] = True
+        mask[part.leaves[0].offset] = True
+
+        def reference(dst, src):
+            """Per view row: src where the row's block is masked."""
+            out = {}
+            for leaf, (k, d) in zip(part.leaves, sorted(dst.items())):
+                rows = max(leaf.rows, 1)
+                keep = np.repeat(mask[leaf.offset:leaf.offset
+                                      + leaf.n_blocks], br)[:rows]
+                dv = np.asarray(d).reshape(rows, -1)
+                sv = np.asarray(src[k]).reshape(rows, -1)
+                out[k] = np.where(keep[:, None], sv, dv).reshape(leaf.shape)
+            return out
+
+        want = reference(other, params)
+        _tree_equal(arena_restore(other, arena, mask, lay), want)
+        _tree_equal(tree_masked_restore(other, params, mask, part,
+                                        interpret=True), want)
+
+        store = ShardedCheckpointStore(str(tmp_path))
+        other_arena = jax.jit(lambda p: pack_arena(p, lay))(other)
+        store.init(other, part, arena_layout=lay,
+                   arena_values=np.asarray(other_arena))
+        _tree_equal(store.read_all(), other)
+        tiles = lay.tiles_for_blocks(np.nonzero(mask)[0])
+        data = np.asarray(arena).reshape(-1, ARENA_TILE)[tiles]
+        store.write_arena(mask, tiles, data, step=1, background=False)
+        _tree_equal(store.read_all(), want)
+    finally:
+        monkeypatch.undo()
+        blocks.leaf_view_shape.cache_clear()
