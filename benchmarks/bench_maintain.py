@@ -47,7 +47,7 @@ not the model):
                        clean-step overhead p50 per mode, bit-equality
                        of losses + running checkpoint, fraction of the
                        async sweep hidden under the next step's compute
-                       (``overlap_efficiency``), and maintain-span /
+                       (``overlap_efficiency``), and async-sweep /
                        train-step span overlap counts from the tracer.
   maint_sweep_sharded / tier_soak_elastic_mesh
                      — SPMD rows, measured in a forced-8-device CPU
@@ -616,10 +616,10 @@ def _overlap_rows(quick: bool) -> list[str]:
         overhead_us = float(np.median(
             [m["overhead_seconds"] for m in ms])) * 1e6
         step_us = float(np.median([m["seconds"] for m in ms])) * 1e6
-        trains = rec.tracer.intervals("train_step")
+        trains = rec.tracer.intervals("scar/step/train")
         overlapping = sum(
             any(m0 < t1 and t0 < m1 for (t0, t1) in trains)
-            for (m0, m1) in rec.tracer.intervals("maintain"))
+            for (m0, m1) in rec.tracer.intervals("scar/async_sweep"))
         eff = loop.overhead_summary()["overlap_efficiency"]
         out[name] = {
             "overhead_us": overhead_us,

@@ -38,6 +38,7 @@ died remain reconstructable offline from the surviving members + parity.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import queue
@@ -51,8 +52,20 @@ import numpy as np
 
 from repro.core.blocks import BlockPartition
 from repro.telemetry.recorder import NULL_RECORDER
+from repro.telemetry.spans import current_span, current_tracer, span
 
 PyTree = Any
+
+
+@dataclasses.dataclass
+class _Write:
+    """One queued background write: its segments, and what the writer
+    books when it lands (bytes, enqueue time, the enqueuer's tracer)."""
+    jobs: list
+    step: int
+    nbytes: int
+    t_enqueue: float
+    tracer: Any
 
 
 def _shard_name(gen: int) -> str:
@@ -261,14 +274,7 @@ class ShardedCheckpointStore:
                     blk = arr[lo:hi] if hi > lo else arr[:1]
                     jobs.append((leaf_meta.offset + int(b), blk))
                     nbytes += blk.nbytes
-        if background:
-            self._ensure_worker()
-            self._q.put(("write", jobs, step))
-        else:
-            self._do_write(jobs, step)
-        if self.recorder.enabled:
-            self.recorder.event("mirror", step=int(step), bytes=nbytes,
-                                segments=len(jobs), background=background)
+        self._submit(jobs, step, nbytes, background)
         return nbytes
 
     def write_arena(self, mask, tiles: np.ndarray, data: np.ndarray,
@@ -306,15 +312,35 @@ class ShardedCheckpointStore:
             payload = flat[start:start + ab.payload]
             jobs.append((int(ab_index), payload))
             nbytes += payload.nbytes
+        self._submit(jobs, step, nbytes, background)
+        return nbytes
+
+    def _submit(self, jobs, step: int, nbytes: int, background: bool,
+                ) -> None:
+        """Write ``jobs`` now, or queue them for the background writer
+        with the time and the tracer of the enqueue (the writer books the
+        write's span, bytes and enqueue-to-publish lag into that tracer).
+        The queue depth at enqueue rides on the caller's open span and on
+        the ``mirror`` event."""
+        depth = None
         if background:
             self._ensure_worker()
-            self._q.put(("write", jobs, step))
+            depth = self._q.qsize()
+            sp = current_span()
+            if sp is not None:
+                sp.set(queue_depth=depth)
+            self._q.put(_Write(jobs, int(step), int(nbytes),
+                               time.perf_counter(),
+                               current_tracer(self.recorder.tracer)))
         else:
-            self._do_write(jobs, step)
+            with self.recorder.span("scar/store/write",
+                                    step=int(step)) as sp:
+                self._do_write(jobs, step)
+                sp.add_bytes(nbytes)
         if self.recorder.enabled:
             self.recorder.event("mirror", step=int(step), bytes=nbytes,
-                                segments=len(jobs), background=background)
-        return nbytes
+                                segments=len(jobs), background=background,
+                                queue_depth=depth)
 
     def write_parity(self, step: int, parity: np.ndarray,
                      parity_homes: np.ndarray,
@@ -327,6 +353,15 @@ class ShardedCheckpointStore:
         reconstruction after a restart — each group's member block ids as
         of encode time, which elastic re-striping changes). Synchronous —
         the parity buffer is 1/g the size of a block write."""
+        with self.recorder.span("scar/store/parity_write",
+                                step=int(step)) as sp:
+            nbytes = self._write_parity(step, parity, parity_homes,
+                                        domains, members)
+            sp.add_bytes(nbytes)
+        return nbytes
+
+    def _write_parity(self, step: int, parity, parity_homes, domains,
+                      members) -> int:
         parity = np.asarray(parity)
         # XOR homes are (n_groups,); RS(k, m) homes are (n_groups, m) with
         # a (n_groups, m, E) parity array — each group's rows share a file,
@@ -393,8 +428,13 @@ class ShardedCheckpointStore:
             try:
                 if item is None:
                     return
-                _, jobs, step = item
-                self._write_with_retry(item, jobs, step)
+                with span("scar/store/write", item.tracer,
+                          step=item.step) as sp:
+                    self._write_with_retry(item, item.jobs, item.step)
+                    sp.add_bytes(item.nbytes)
+                if item.tracer is not None:
+                    # the manifest is published: the write is durable
+                    item.tracer.add_lag(time.perf_counter() - item.t_enqueue)
             except BaseException as e:  # keep draining; surface on flush()
                 if self._worker_error is None:
                     # keep the FIRST failure's context — later failures
@@ -440,7 +480,7 @@ class ShardedCheckpointStore:
         ``flush()`` raises and the ``store_write_failed`` event."""
         ctx = {"step": None, "segment": None, "host": None, "path": None}
         try:
-            _, jobs, step = item
+            jobs, step = item.jobs, item.step
             ctx["step"] = int(step)
             if jobs:
                 seg = int(jobs[0][0])

@@ -512,6 +512,23 @@ def unpack_arena(arena: jnp.ndarray, layout: ArenaLayout) -> PyTree:
     return jax.tree_util.tree_unflatten(layout.partition.treedef, out)
 
 
+def arena_pack_program(layout: ArenaLayout, out_sharding=None):
+    """:func:`pack_arena` over ``layout``, jitted under the stable program
+    name ``jit_arena_pack`` (a device trace names it apart from other
+    programs)."""
+    def arena_pack(tree):
+        return pack_arena(tree, layout, out_sharding=out_sharding)
+    return jax.jit(arena_pack)
+
+
+def arena_unpack_program(layout: ArenaLayout):
+    """:func:`unpack_arena` over ``layout``, jitted under the stable
+    program name ``jit_arena_unpack``."""
+    def arena_unpack(arena):
+        return unpack_arena(arena, layout)
+    return jax.jit(arena_unpack)
+
+
 # ---------------------------------------------------------------------------
 # value domain (the optimizer seam)
 # ---------------------------------------------------------------------------

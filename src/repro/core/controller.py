@@ -136,13 +136,13 @@ class FTController:
                 and score_fn is None
                 and (policy.strategy != SelectionStrategy.PRIORITY
                      or policy.norm == "l2")):
-            from repro.core.arena import pack_arena, unpack_arena
+            from repro.core.arena import (arena_pack_program,
+                                          arena_unpack_program)
             layout = self.fabric.arena_layout
             sh = getattr(self.fabric, "_arena_sharding", None)
             self._arena_layout = layout
-            self._pack_jit = jax.jit(
-                lambda t: pack_arena(t, layout, out_sharding=sh))
-            self._unpack_jit = jax.jit(lambda a: unpack_arena(a, layout))
+            self._pack_jit = arena_pack_program(layout, sh)
+            self._unpack_jit = arena_unpack_program(layout)
             self._ckpt_arena = self._pack_jit(params)
             # the arena is canonical from here on: drop the tree copy
             # (a whole model's bytes of device memory) and let the
@@ -215,14 +215,14 @@ class FTController:
         through any number of shrink/re-grow cycles."""
         assert self.arena_ready and self.fabric is not None, \
             "rebind_arena needs an arena-native controller with a fabric"
-        from repro.core.arena import pack_arena, relayout_arena, unpack_arena
+        from repro.core.arena import (arena_pack_program,
+                                      arena_unpack_program, relayout_arena)
         old = self._arena_layout
         layout = self.fabric.arena_layout
         sh = getattr(self.fabric, "_arena_sharding", None)
         self._arena_layout = layout
-        self._pack_jit = jax.jit(
-            lambda t: pack_arena(t, layout, out_sharding=sh))
-        self._unpack_jit = jax.jit(lambda a: unpack_arena(a, layout))
+        self._pack_jit = arena_pack_program(layout, sh)
+        self._unpack_jit = arena_unpack_program(layout)
         self._arena_score_jit = None
         self._arena_score_live_jit = None
         if self._ckpt_arena is not None and layout is not old:
@@ -292,6 +292,11 @@ class FTController:
         ``own_live`` rides along to the post-save freshness maintain (see
         :meth:`maintain`) so a tree-stepping runner's throwaway pack is
         adopted, not re-copied, when that forced sweep runs."""
+        with self.recorder.span("scar/save", step=int(step)):
+            return self._checkpoint_now(int(step), params, own_live)
+
+    def _checkpoint_now(self, step: int, params: PyTree,
+                        own_live: bool) -> jnp.ndarray:
         if self.fabric is not None \
                 and getattr(self.fabric, "has_pending_maintenance", False):
             # consume point: the save may source from the published slot
@@ -305,67 +310,43 @@ class FTController:
         full_plain = (self.policy.fraction >= 1.0 and
                       self.policy.strategy != SelectionStrategy.PRIORITY)
         arena_hot = self._arena_layout is not None and not full_plain
-        if live is not None and full_plain:
-            # full save from the live arena: ONE contiguous device copy
-            ck = self._ckpt
-            self._ckpt_arena = jnp.array(live)
-            self._ckpt = RunningCheckpoint(
-                ck.values, jnp.full_like(ck.saved_iter, jnp.int32(step)),
-                ck.rr_cursor)
-            self._ckpt_dirty = True
-            mask = jnp.ones((self.partition.total_blocks,), bool)
-        elif arena_hot:
-            mask = self._arena_checkpoint(step, params)
-        elif full_plain:
-            self.ckpt = full_save(self.ckpt, params, jnp.int32(step))
-            mask = jnp.ones((self.partition.total_blocks,), bool)
-        else:
-            assert live is None, ("live-arena saves need the arena "
-                                  "checkpoint path (arena-capable fabric)")
-            self._rng, sub = jax.random.split(self._rng)
-            scores = None
-            if self.policy.strategy == SelectionStrategy.PRIORITY:
-                if self._score_fn is not None:
-                    scores = self._score_fn(params, self.ckpt.values)
-                elif (self.fabric is not None
-                        and self.fabric.last_scores_step == int(step)
-                        and self.policy.norm == "l2"):
-                    # this step's fused maintenance sweep already measured
-                    # the drift vs the running checkpoint — reuse it
-                    # instead of a third full read of params + ckpt
-                    scores = self.fabric.last_scores
-            if self.inplace_save:
-                mask, cursor = self._jit_select(self.ckpt, params, rng=sub,
-                                                scores=scores)
-                idx = np.nonzero(np.asarray(mask))[0]
-                from repro.kernels.fused_maintain.ops import tree_scatter_save
-                new_values, moved = tree_scatter_save(
-                    self.ckpt.values, params, idx, self.partition)
-                new_saved = jnp.where(mask, jnp.int32(step),
-                                      self.ckpt.saved_iter)
-                self.ckpt = RunningCheckpoint(new_values, new_saved, cursor)
-                self.stats["save_bytes_moved"] += moved
+        picked = None
+        if not full_plain:
+            with self.recorder.span("scar/save/select"):
+                picked = (self._arena_select(step, params) if arena_hot
+                          else self._tree_select(step, params, live))
+        with self.recorder.span("scar/save/scatter"):
+            if live is not None and full_plain:
+                # full save from the live arena: ONE contiguous device copy
+                ck = self._ckpt
+                self._ckpt_arena = jnp.array(live)
+                self._ckpt = RunningCheckpoint(
+                    ck.values, jnp.full_like(ck.saved_iter, jnp.int32(step)),
+                    ck.rr_cursor)
+                self._ckpt_dirty = True
+                mask = jnp.ones((self.partition.total_blocks,), bool)
+            elif arena_hot:
+                mask = self._arena_scatter(step, params, *picked)
+            elif full_plain:
+                self.ckpt = full_save(self.ckpt, params, jnp.int32(step))
+                mask = jnp.ones((self.partition.total_blocks,), bool)
             else:
-                self.ckpt, mask = self._jit_save(self.ckpt, params,
-                                                 jnp.int32(step), rng=sub,
-                                                 scores=scores)
-        if self.fabric is not None:
-            # the save invalidated the drift the cached scores measured
-            self.fabric.invalidate_scores()
-        # block until the in-memory cache is consistent (paper: training may
-        # resume now), then mirror to disk. In arena mode the arena IS the
-        # cache — the tree form stays lazily dirty (never materialized on
-        # the hot path).
-        jax.block_until_ready(self._ckpt_arena if self._arena_layout
-                              is not None else self.ckpt.values)
-        n_blocks = int(jnp.sum(mask))
+                mask = self._tree_scatter(step, params, *picked)
+            if self.fabric is not None:
+                # the save invalidated the drift the cached scores measured
+                self.fabric.invalidate_scores()
+            # block until the in-memory cache is consistent (paper:
+            # training may resume now), then mirror to disk. In arena mode
+            # the arena IS the cache — the tree form stays lazily dirty
+            # (never materialized on the hot path).
+            jax.block_until_ready(self._ckpt_arena if self._arena_layout
+                                  is not None else self.ckpt.values)
+            n_blocks = int(jnp.sum(mask))
         save_seconds = time.perf_counter() - t0
         self.stats["saves"] += 1
         self.stats["blocks_saved"] += n_blocks
         self.stats["save_seconds"] += save_seconds
         if self.recorder.enabled:
-            self.recorder.histogram("controller/save_seconds").observe(
-                save_seconds)
             self.recorder.event(
                 "save", step=int(step), blocks=n_blocks,
                 bytes_moved=self.stats["save_bytes_moved"] - moved0,
@@ -373,54 +354,91 @@ class FTController:
                 mode="arena" if self._arena_layout is not None else "tree")
         if self.store is not None:
             if self._arena_layout is not None:
-                mask_np = np.asarray(mask)
-                tiles = self._arena_layout.tiles_for_blocks(
-                    np.nonzero(mask_np)[0])
-                from repro.core.arena import ARENA_TILE
-                data = np.asarray(
-                    self._ckpt_arena.reshape(-1, ARENA_TILE)[tiles])
-                self.stats["bytes_mirrored"] += self.store.write_arena(
-                    mask_np, tiles, data, step,
-                    background=self.policy.async_persist)
+                with self.recorder.span("scar/save/tiles_to_host") as sp:
+                    mask_np = np.asarray(mask)
+                    tiles = self._arena_layout.tiles_for_blocks(
+                        np.nonzero(mask_np)[0])
+                    from repro.core.arena import ARENA_TILE
+                    data = np.asarray(
+                        self._ckpt_arena.reshape(-1, ARENA_TILE)[tiles])
+                    sp.add_bytes(data.nbytes)
+                with self.recorder.span("scar/save/store_enqueue"):
+                    self.stats["bytes_mirrored"] += self.store.write_arena(
+                        mask_np, tiles, data, step,
+                        background=self.policy.async_persist)
             else:
-                self.stats["bytes_mirrored"] += self.store.write_blocks(
-                    mask, self.ckpt.values, step,
-                    background=self.policy.async_persist)
+                with self.recorder.span("scar/save/store_enqueue"):
+                    self.stats["bytes_mirrored"] += self.store.write_blocks(
+                        mask, self.ckpt.values, step,
+                        background=self.policy.async_persist)
         if self.fabric is not None:
             if not self.fabric.is_fresh(int(step)):
                 # keep the redundancy tiers at least as fresh as the
                 # checkpoint (a same-step maintain() may have skipped an
                 # off-interval tier — force refreshes every tier)
-                self.fabric.maintain(int(step), params, force=True,
-                                     own_live=own_live)
+                with self.recorder.span("scar/save/refresh"):
+                    self.fabric.maintain(int(step), params, force=True,
+                                         own_live=own_live)
             if (self.store is not None
                     and getattr(self.fabric, "parity", None) is not None
                     and self.fabric.parity.parity is not None
                     and hasattr(self.store, "write_parity")):
                 # mirror parity to disk: blocks whose domain shard died stay
                 # reconstructable offline from survivors + parity
+                with self.recorder.span("scar/save/parity_to_host") as sp:
+                    parity = np.asarray(self.fabric.parity.parity)
+                    sp.add_bytes(parity.nbytes)
                 self.stats["bytes_mirrored"] += self.store.write_parity(
-                    int(step), np.asarray(self.fabric.parity.parity),
-                    self.fabric.parity.parity_homes,
+                    int(step), parity, self.fabric.parity.parity_homes,
                     domains=self.fabric.domains,
                     members=self.fabric.parity.members)
         return mask
 
-    def _arena_checkpoint(self, step: int, params: PyTree) -> jnp.ndarray:
-        """Partial save in arena mode: select blocks, then ONE donated
-        tile scatter into the checkpoint arena, sourced from the live
-        arena itself when the training state is arena-resident (it *is*
-        this step's values — no pack and no replica freshness gating),
-        else from the maintenance sweep's replica arena (this step's
-        snapshot — zero extra reads of the live tree) or, off-schedule,
-        a fresh pack. O(k·seg_bytes) moved, a single dispatch each way."""
-        from repro.kernels.fused_maintain.ops import arena_scatter_save
+    def _tree_select(self, step: int, params: PyTree, live) -> tuple:
+        """Tree-path selection: ``(rng, scores, mask, cursor, idx)``; the
+        last three are None where the jitted save selects for itself."""
+        assert live is None, ("live-arena saves need the arena "
+                              "checkpoint path (arena-capable fabric)")
+        self._rng, sub = jax.random.split(self._rng)
+        scores = None
+        if self.policy.strategy == SelectionStrategy.PRIORITY:
+            if self._score_fn is not None:
+                scores = self._score_fn(params, self.ckpt.values)
+            elif (self.fabric is not None
+                    and self.fabric.last_scores_step == int(step)
+                    and self.policy.norm == "l2"):
+                # this step's fused maintenance sweep already measured
+                # the drift vs the running checkpoint — reuse it
+                # instead of a third full read of params + ckpt
+                scores = self.fabric.last_scores
+        if not self.inplace_save:
+            return sub, scores, None, None, None
+        mask, cursor = self._jit_select(self.ckpt, params, rng=sub,
+                                        scores=scores)
+        return sub, scores, mask, cursor, np.nonzero(np.asarray(mask))[0]
+
+    def _tree_scatter(self, step: int, params: PyTree, sub, scores, mask,
+                      cursor, idx) -> jnp.ndarray:
+        if mask is None:
+            self.ckpt, mask = self._jit_save(self.ckpt, params,
+                                             jnp.int32(step), rng=sub,
+                                             scores=scores)
+            return mask
+        from repro.kernels.fused_maintain.ops import tree_scatter_save
+        new_values, moved = tree_scatter_save(
+            self.ckpt.values, params, idx, self.partition)
+        new_saved = jnp.where(mask, jnp.int32(step), self.ckpt.saved_iter)
+        self.ckpt = RunningCheckpoint(new_values, new_saved, cursor)
+        self.stats["save_bytes_moved"] += moved
+        return mask
+
+    def _arena_select(self, step: int, params: PyTree) -> tuple:
+        """Arena-mode selection: ``(idx, mask, cursor)`` — the ``k`` blocks
+        this partial save writes, as host indices and a host mask."""
         pol = self.policy
         total = self.partition.total_blocks
         k = self.partition.blocks_for_k(pol.fraction)
-        ck = self._ckpt
-        cursor = ck.rr_cursor
-        live = self._live_arena(params)
+        cursor = self._ckpt.rr_cursor
         self._rng, sub = jax.random.split(self._rng)
         if pol.strategy == SelectionStrategy.PRIORITY:
             if (self.fabric.last_scores_step == int(step)
@@ -431,7 +449,7 @@ class FTController:
             _, idx = jax.lax.top_k(scores, k)
             idx = np.asarray(idx)
         elif pol.strategy == SelectionStrategy.ROUND_ROBIN:
-            c = int(ck.rr_cursor)
+            c = int(cursor)
             idx = (c + np.arange(k)) % total
             cursor = jnp.int32((c + k) % total)
         elif pol.strategy == SelectionStrategy.RANDOM:
@@ -441,6 +459,20 @@ class FTController:
             raise ValueError(f"unknown strategy {pol.strategy}")
         mask = np.zeros((total,), bool)
         mask[idx] = True
+        return idx, mask, cursor
+
+    def _arena_scatter(self, step: int, params: PyTree, idx, mask,
+                       cursor) -> jnp.ndarray:
+        """Partial save in arena mode: ONE donated tile scatter into the
+        checkpoint arena, sourced from the live arena itself when the
+        training state is arena-resident (it *is* this step's values — no
+        pack and no replica freshness gating), else from the maintenance
+        sweep's replica arena (this step's snapshot — zero extra reads of
+        the live tree) or, off-schedule, a fresh pack. O(k·seg_bytes)
+        moved, a single dispatch each way."""
+        from repro.kernels.fused_maintain.ops import arena_scatter_save
+        ck = self._ckpt
+        live = self._live_arena(params)
         rep = self.fabric.replicas
         published = (rep is not None and rep.arena is not None
                      and rep.is_fresh(int(step)))
@@ -485,8 +517,10 @@ class FTController:
                 # segment-sum on an all-f32 tail-free layout
                 return arena_drift_scores(rep, z, layout)
 
-            self._arena_score_jit = jax.jit(
-                lambda p, z: _tile_scores(pack_arena(p, layout), z))
+            def _tree_scores(p, z):
+                return _tile_scores(pack_arena(p, layout), z)
+
+            self._arena_score_jit = jax.jit(_tree_scores)
             self._arena_score_live_jit = jax.jit(_tile_scores)
         live = self._live_arena(params)
         if live is not None:
@@ -643,13 +677,23 @@ class FTController:
         ``ArenaTrainState`` (the cold path pays the two conversions; the
         hot path never does).
         """
-        live = self._live_arena(params)
-        if live is not None:
-            recovered, info = self.on_failure(
-                self.unpack_live(live), lost_mask,
-                failed_devices=failed_devices, step=step,
-                persist_failure=persist_failure)
-            return self.pack_live(recovered), info
+        with self.recorder.span("scar/recovery",
+                                step=None if step is None else int(step)):
+            live = self._live_arena(params)
+            if live is None:
+                return self._recover(params, lost_mask, failed_devices,
+                                     step, persist_failure)
+            with self.recorder.span("scar/recovery/decode"):
+                tree = self.unpack_live(live)
+            recovered, info = self._recover(tree, lost_mask, failed_devices,
+                                            step, persist_failure)
+            with self.recorder.span("scar/recovery/repack"):
+                return self.pack_live(recovered), info
+
+    def _recover(self, params: PyTree, lost_mask, failed_devices,
+                 step: Optional[int], persist_failure: Optional[bool],
+                 ) -> tuple[PyTree, dict]:
+        """Tree-form recovery behind :meth:`on_failure`."""
         if self.recorder.enabled:
             self.recorder.event(
                 "failure", step=None if step is None else int(step),

@@ -351,10 +351,15 @@ class CheckpointFabric:
         truly resident state (donated through the train step) must leave
         this False so the sweep emits an independent replica copy.
         """
-        from repro.core.arena import as_live_arena
         step = int(step)
         if step == self.last_maintained_step and not force:
             return
+        with self.recorder.span("scar/maintain", step=step):
+            self._maintain(step, params, ckpt_values, force, own_live)
+
+    def _maintain(self, step: int, params: PyTree, ckpt_values,
+                  force: bool, own_live: bool) -> None:
+        from repro.core.arena import as_live_arena
         # note: without an arena layout a 1-D input is treated as what it
         # always was — a bare single-leaf param tree on the per-component
         # paths (a genuine live arena can only come from an arena-capable
@@ -367,9 +372,9 @@ class CheckpointFabric:
         if self.cfg.async_maintain and live is not None \
                 and (due_replica or due_parity):
             # pipelined path: dispatch only, no fence — the sweep runs
-            # under the trainer's next step. No sync span here either;
-            # the deferred [dispatch, fence] span is recorded when the
-            # pending sweep settles, so the trace shows the true overlap.
+            # under the trainer's next step. The sweep's [dispatch,
+            # settle] interval is kept when the pending sweep settles,
+            # so the trace shows the true overlap.
             self._async_maintain(step, live, ckpt_values, own_live=own_live)
             self.last_maintained_step = step
             if self.recorder.enabled:
@@ -381,30 +386,28 @@ class CheckpointFabric:
                     replica=due_replica, parity=due_parity)
             return
         mode = "components"
-        with self.recorder.span("maintain", step=step,
-                                fence=self.block_until_maintained):
-            if self.arena_layout is not None and (
-                    (due_replica and due_parity)
-                    or (live is not None and (due_replica or due_parity))):
-                self._arena_maintain(step, params, ckpt_values,
-                                     own_live=own_live)
-                mode = ("arena_resident" if self.live_arena_mode
-                        and live is not None and not own_live else "arena")
-            elif self.cfg.fused and due_replica and due_parity:
-                self._fused_maintain(step, params, ckpt_values)
-                mode = "fused"
-            else:
-                t = self._traffic_model()
-                if due_replica:
-                    self.replicas.refresh(step, params)
-                    self.stats["replica_refreshes"] += 1
-                    self.stats["maintain_bytes_moved"] += t["replica_pass"]
-                if due_parity:
-                    self.parity.encode(step, params)
-                    self.stats["parity_encodes"] += 1
-                    self.stats["maintain_bytes_moved"] += t["parity_pass"]
-                if due_replica or due_parity:
-                    self.published_epoch = step
+        if self.arena_layout is not None and (
+                (due_replica and due_parity)
+                or (live is not None and (due_replica or due_parity))):
+            self._arena_maintain(step, params, ckpt_values,
+                                 own_live=own_live)
+            mode = ("arena_resident" if self.live_arena_mode
+                    and live is not None and not own_live else "arena")
+        elif self.cfg.fused and due_replica and due_parity:
+            self._fused_maintain(step, params, ckpt_values)
+            mode = "fused"
+        else:
+            t = self._traffic_model()
+            if due_replica:
+                self.replicas.refresh(step, params)
+                self.stats["replica_refreshes"] += 1
+                self.stats["maintain_bytes_moved"] += t["replica_pass"]
+            if due_parity:
+                self.parity.encode(step, params)
+                self.stats["parity_encodes"] += 1
+                self.stats["maintain_bytes_moved"] += t["parity_pass"]
+            if due_replica or due_parity:
+                self.published_epoch = step
         self.last_maintained_step = step
         if self.recorder.enabled:
             self.recorder.event(
@@ -519,11 +522,15 @@ class CheckpointFabric:
             inactive = 1 - self._active_slot
             stale = self._slots[inactive]
             if self._snap_fresh is None:
-                self._snap_fresh = jax.jit(
-                    lambda a: jax.lax.optimization_barrier(a))
-                self._snap_donate = jax.jit(
-                    lambda slot, a: jax.lax.optimization_barrier(a),
-                    donate_argnums=(0,))
+                def snapshot_arena(a):
+                    return jax.lax.optimization_barrier(a)
+
+                def snapshot_arena_into(slot, a):
+                    return jax.lax.optimization_barrier(a)
+
+                self._snap_fresh = jax.jit(snapshot_arena)
+                self._snap_donate = jax.jit(snapshot_arena_into,
+                                            donate_argnums=(0,))
             if self._donate_slots and stale is not None \
                     and stale.shape == live.shape \
                     and stale.dtype == live.dtype:
@@ -567,9 +574,10 @@ class CheckpointFabric:
     def _settle_pending(self) -> float:
         """Fence the in-flight async sweep (no-op without one); returns
         the seconds actually waited. Books the epoch's hidden/total time
-        into the overlap-efficiency accounting and records the deferred
-        ``maintain`` span covering [dispatch, fence] — the interval the
-        Chrome trace shows overlapping the next ``train_step``."""
+        into the overlap-efficiency accounting and keeps (with a recorder)
+        the ``scar/async_sweep`` record covering [dispatch, fence] — the
+        interval the Chrome trace shows overlapping the next
+        ``scar/step/train``."""
         p = self._pending
         if p is None:
             return 0.0
@@ -590,8 +598,8 @@ class CheckpointFabric:
             self.recorder.gauge("fabric/overlap_efficiency").set(
                 self.overlap_efficiency())
             self.recorder.tracer.record(
-                "maintain", p["span_t0"], self.recorder.tracer.now(),
-                step=p["step"], mode="arena_async", deferred=True)
+                "scar/async_sweep", p["span_t0"], self.recorder.tracer.now(),
+                step=p["step"], mode="arena_async")
         return wait
 
     def overlap_efficiency(self) -> float:
@@ -611,10 +619,9 @@ class CheckpointFabric:
                 "checkpoint arena does not match this fabric's layout"
             return ckpt_values
         if self._pack_fn is None:
-            from repro.core.arena import pack_arena
-            layout, sh = self.arena_layout, self._arena_sharding
-            self._pack_fn = jax.jit(
-                lambda t: pack_arena(t, layout, out_sharding=sh))
+            from repro.core.arena import arena_pack_program
+            self._pack_fn = arena_pack_program(self.arena_layout,
+                                               self._arena_sharding)
         return self._pack_fn(ckpt_values)
 
     def _arena_maintain_fn(self):
@@ -622,11 +629,15 @@ class CheckpointFabric:
         re-striped since the last build."""
         if self._arena_fn is None or self._arena_version != self.view.version:
             from repro.kernels.fused_maintain.ops import ArenaMaintainProgram
-            self._arena_fn = ArenaMaintainProgram(
-                self.partition, self.arena_layout, self.parity.layout,
-                self.parity.group_of, self.parity.n_groups,
-                use_pallas=self.cfg.use_pallas,
-                out_sharding=self._arena_sharding)
+            # the host side of a rebuild: routing tables for the new
+            # striping (the program's trace and compile follow at its
+            # first call, in the caller's span)
+            with self.recorder.span("scar/maintain/build"):
+                self._arena_fn = ArenaMaintainProgram(
+                    self.partition, self.arena_layout, self.parity.layout,
+                    self.parity.group_of, self.parity.n_groups,
+                    use_pallas=self.cfg.use_pallas,
+                    out_sharding=self._arena_sharding)
             self._arena_version = self.view.version
             self._traffic = None
             self.stats["arena_sweep"] = self._arena_fn.sweep
@@ -652,7 +663,7 @@ class CheckpointFabric:
         helper for loops that report per-step maintenance overhead — owns
         the knowledge of which tensor represents the sweep's completion.
         With a pending async epoch this is the deferred fence: it settles
-        the pending sweep (books overlap accounting + the deferred span)
+        the pending sweep (books overlap accounting + the sweep's record)
         rather than bare-blocking."""
         if self._pending is not None:
             self._settle_pending()
@@ -864,10 +875,12 @@ class CheckpointFabric:
             bool(persist_failure)
         if persist and failed.size:
             self.view.mark_failed(failed)
-        plan = self.planner.plan(lost_mask, failed, recovered_epoch)
-        recovered, stats = self.planner.recover(params, ckpt_values, plan,
-                                                disk_values=disk_values,
-                                                disk_reader=disk_reader)
+        with self.recorder.span("scar/recovery/plan"):
+            plan = self.planner.plan(lost_mask, failed, recovered_epoch)
+        with self.recorder.span("scar/recovery/restore"):
+            recovered, stats = self.planner.recover(
+                params, ckpt_values, plan, disk_values=disk_values,
+                disk_reader=disk_reader)
         self.stats["recoveries"] += 1
         stats["failed_devices"] = int(failed.size)
         stats["recovered_epoch"] = recovered_epoch
@@ -887,6 +900,10 @@ class CheckpointFabric:
         """Post-failure elastic re-plan: re-home displaced blocks, re-seed
         replicas, re-stripe parity — all against the recovered params, so
         every tier is fresh on the new placement."""
+        with self.recorder.span("scar/replan", step=step):
+            return self._replan_tiers(step, params)
+
+    def _replan_tiers(self, step: int, params: PyTree) -> dict:
         displaced = rehome_blocks(self.view)
         if self.arena_layout is not None:
             # arena mode: re-seed + re-stripe, then one arena sweep
@@ -933,6 +950,10 @@ class CheckpointFabric:
         whose snapshot matches the encode step — otherwise the pass
         reports ``checked=False`` and touches nothing.
         """
+        with self.recorder.span("scar/scrub", step=step):
+            return self._scrub(step)
+
+    def _scrub(self, step: Optional[int]) -> dict:
         out = {"checked": False, "detected": 0, "corrected": 0,
                "reports": []}
         codec = self.parity
@@ -1020,6 +1041,11 @@ class CheckpointFabric:
         the restored capacity and re-seeds/re-stripes the redundancy tiers
         (against ``params`` when given, so they are immediately fresh;
         otherwise the next ``maintain`` refreshes them)."""
+        with self.recorder.span("scar/heal", step=step):
+            return self._heal(kind, index, params, step)
+
+    def _heal(self, kind: str, index: int, params: Optional[PyTree],
+              step: Optional[int]) -> dict:
         # consume point: an elastic heal re-stripes the tiers — never
         # against a half-swept async epoch
         self._settle_pending()
@@ -1030,8 +1056,9 @@ class CheckpointFabric:
         self.stats["heals"] += 1
         if not self.cfg.elastic:
             if self.recorder.enabled:
-                self.recorder.event("heal", kind=kind, index=int(index),
-                                    step=step, **info)
+                self.recorder.event("heal", domain_kind=kind,
+                                    domain_index=int(index), step=step,
+                                    **info)
             return info
         at = int(step) if step is not None else self.last_maintained_step
         moved = rebalance_homes(self.view)

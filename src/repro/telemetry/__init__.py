@@ -14,17 +14,20 @@ Quick tour::
     print(format_report(run_report(rec)))
     rec.close()                                    # trace.json + metrics.json
 
-The default everywhere is :data:`NULL_RECORDER` — all emit points are
-no-ops and the hot path is unchanged. See DESIGN.md "Observability".
+The default everywhere is :data:`NULL_RECORDER` — its emit points are
+no-ops and the hot path is unchanged. Host spans (``scar/...``) are on
+either way: each writes a profiler annotation and books its seconds into
+the training loop's per-step rollup; a Recorder also keeps their records.
+See DESIGN.md "Observability".
 """
 from repro.telemetry.ledger import LedgerEntry, PerturbationLedger
 from repro.telemetry.recorder import (EVENT_SCHEMA, NULL_RECORDER, Counter,
                                       Gauge, Histogram, NullRecorder,
                                       Recorder, read_events_jsonl)
 from repro.telemetry.report import format_report, run_report
-from repro.telemetry.spans import SpanRecord, SpanTracer
+from repro.telemetry.spans import SpanRecord, SpanTracer, span
 
 __all__ = ["Recorder", "NullRecorder", "NULL_RECORDER", "Counter", "Gauge",
            "Histogram", "EVENT_SCHEMA", "read_events_jsonl",
            "PerturbationLedger", "LedgerEntry", "SpanTracer", "SpanRecord",
-           "run_report", "format_report"]
+           "span", "run_report", "format_report"]

@@ -19,11 +19,14 @@ fabric/controller/loops previously kept as scattered ad-hoc state:
   in :data:`EVENT_SCHEMA` (DESIGN.md "Observability" has the table).
 
 The default everywhere is the :data:`NULL_RECORDER` singleton — every
-method is a no-op returning shared singletons, so instrumented hot paths
-cost one attribute check and no allocation.
+method but ``span`` is a no-op returning shared singletons, so
+instrumented hot paths cost one attribute check and no allocation. Spans
+are on either way (:mod:`repro.telemetry.spans`); a recorder only keeps
+their records.
 
 A :class:`Recorder` also owns a :class:`~repro.telemetry.spans.SpanTracer`
-(``span("maintain")`` context manager, Chrome-trace export) and a
+that keeps every span's record (``span("scar/maintain")``, Chrome-trace
+export) and a
 :class:`~repro.telemetry.ledger.PerturbationLedger` fed by
 ``record_recovery`` — the Thm-3.2/4.1 iteration-cost bound of every
 recovery event becomes a first-class observable of the run.
@@ -38,6 +41,8 @@ from typing import Any, Optional
 
 import numpy as np
 
+from repro.telemetry.spans import SpanTracer, span
+
 # event kinds with their documented payload fields (informative — extra
 # fields are allowed and preserved; the JSONL round-trip is schema-free).
 # ``seq``/``ts``/``kind`` are stamped on every event by the recorder.
@@ -48,7 +53,7 @@ EVENT_SCHEMA: dict[str, tuple[str, ...]] = {
                  "applied_sq", "failed_devices"),
     "maintain": ("step", "mode", "bytes_moved", "replica", "parity"),
     "save":     ("step", "blocks", "bytes_moved", "seconds", "mode"),
-    "mirror":   ("step", "bytes", "segments", "background"),
+    "mirror":   ("step", "bytes", "segments", "background", "queue_depth"),
     "store_write_failed": ("step", "segment", "host", "path", "error"),
     "store_write_retried": ("step", "segment", "host", "path", "error",
                             "attempt", "delay_seconds"),
@@ -162,26 +167,11 @@ class _NullMetric:
 _NULL_METRIC = _NullMetric()
 
 
-class _NullSpan:
-    """Reusable no-op context manager (one shared instance per process)."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 # -- recorders ---------------------------------------------------------------
 
 
 class NullRecorder:
-    """The default: every instrumented emit point is a no-op.
+    """The default: every instrumented emit point but ``span`` is a no-op.
 
     Components are written against this interface; the real
     :class:`Recorder` subclasses it. ``enabled`` lets hot paths skip
@@ -212,8 +202,10 @@ class NullRecorder:
     def event(self, kind: str, **fields: Any) -> None:
         pass
 
-    def span(self, name: str, fence: Any = None, **attrs: Any):
-        return _NULL_SPAN
+    def span(self, name: str, *, step: Optional[int] = None, **attrs: Any):
+        """A host span (:mod:`repro.telemetry.spans`): on with or without
+        a recorder; this recorder's tracer keeps its record."""
+        return span(name, self.tracer, step=step, **attrs)
 
     def record_recovery(self, step: Optional[int], lost_blocks: int,
                         tier_counts: Optional[dict], applied_sq: float,
@@ -249,7 +241,6 @@ class Recorder(NullRecorder):
                  ledger: Optional[Any] = None,
                  clock=time.perf_counter) -> None:
         from repro.telemetry.ledger import PerturbationLedger
-        from repro.telemetry.spans import SpanTracer
         self.out_dir = out_dir
         self._clock = clock
         self._t0 = clock()
@@ -258,7 +249,7 @@ class Recorder(NullRecorder):
         self.gauges: dict[str, Gauge] = {}
         self.histograms: dict[str, Histogram] = {}
         self.events: list[dict] = []
-        self.tracer = SpanTracer(clock=clock)
+        self.tracer = SpanTracer()
         self.ledger = ledger if ledger is not None else PerturbationLedger()
         self._lock = threading.Lock()
         self._jsonl = None
@@ -304,11 +295,6 @@ class Recorder(NullRecorder):
             if self._jsonl is not None:
                 self._jsonl.write(json.dumps(rec) + "\n")
                 self._jsonl.flush()
-
-    # -- spans --------------------------------------------------------------
-
-    def span(self, name: str, fence: Any = None, **attrs: Any):
-        return self.tracer.span(name, fence=fence, **attrs)
 
     # -- ledger -------------------------------------------------------------
 

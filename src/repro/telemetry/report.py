@@ -102,7 +102,8 @@ def _overhead(rec: Any) -> dict:
         # classic runners book per-phase spans instead of a histogram —
         # fall back to the maintain-span durations
         tracer = getattr(rec, "tracer", None)
-        samples = tracer.durations("maintain") if tracer is not None else []
+        samples = (tracer.durations("scar/maintain") if tracer is not None
+                   else [])
         if not samples:
             return {"count": 0, "mean": 0.0, "p50": 0.0, "p95": 0.0,
                     "max": 0.0}

@@ -37,7 +37,6 @@ from repro.core.perturb import (adversarial_perturbation, random_perturbation,
 from repro.core.policy import CheckpointPolicy
 from repro.core.blocks import partition_pytree, tree_sq_norm
 from repro.models.classic import IterativeModel
-from repro.telemetry.recorder import NULL_RECORDER
 
 PyTree = Any
 
@@ -137,7 +136,6 @@ def run_with_failure(model: IterativeModel, policy: CheckpointPolicy, *,
     if fail_domain != "uniform" and fabric is None:
         raise ValueError("correlated fail_domain needs a fabric")
     key = _keys(seed)
-    rec = recorder if recorder is not None else NULL_RECORDER
     p = model.init(jax.random.PRNGKey(1))
     ctl = FTController(p, policy, norm_aux=model.norm_aux, store=store,
                        rng=jax.random.PRNGKey(seed + 13),
@@ -172,15 +170,14 @@ def run_with_failure(model: IterativeModel, policy: CheckpointPolicy, *,
             ctl.fabric.block_until_maintained()
         maint_seconds += time.perf_counter() - t0
         if i == fail_iter:
-            with rec.span("recovery", step=i, domain=fail_domain):
-                if fail_domain == "uniform":
-                    lost = ctl.sample_failure(fail_fraction)
-                    p, recovery_info = ctl.on_failure(p, lost, step=i)
-                else:
-                    lost, failed = ctl.sample_domain_failure(fail_domain)
-                    p, recovery_info = ctl.on_failure(p, lost,
-                                                      failed_devices=failed,
-                                                      step=i)
+            if fail_domain == "uniform":
+                lost = ctl.sample_failure(fail_fraction)
+                p, recovery_info = ctl.on_failure(p, lost, step=i)
+            else:
+                lost, failed = ctl.sample_domain_failure(fail_domain)
+                p, recovery_info = ctl.on_failure(p, lost,
+                                                  failed_devices=failed,
+                                                  step=i)
         losses.append(float(model.loss(p)))
     if ctl.fabric is not None:
         # settle the last async epoch (no-op in sync mode) — its fence
@@ -231,7 +228,6 @@ def run_with_trace(model: IterativeModel, policy: CheckpointPolicy, *,
     if fabric is None:
         raise ValueError("run_with_trace needs a fabric")
     key = _keys(seed)
-    rec = recorder if recorder is not None else NULL_RECORDER
     p = model.init(jax.random.PRNGKey(1))
     ctl = FTController(p, policy, norm_aux=model.norm_aux, store=store,
                        rng=jax.random.PRNGKey(seed + 13),
@@ -264,10 +260,8 @@ def run_with_trace(model: IterativeModel, policy: CheckpointPolicy, *,
         if len(evs) > 1:
             # same-step events are one correlated multi-domain loss:
             # recover the union in one tier-planned pass (multi-erasure)
-            names = ",".join(f"{e.kind}:{e.index}" for e in evs)
-            with rec.span("recovery", step=i, domain=names):
-                p, info = ctl.on_domain_events(
-                    p, [(e.kind, e.index) for e in evs], step=i)
+            p, info = ctl.on_domain_events(
+                p, [(e.kind, e.index) for e in evs], step=i)
             info["step"] = i
             events_out.append(info)
             if heal_after is not None:
@@ -278,16 +272,13 @@ def run_with_trace(model: IterativeModel, policy: CheckpointPolicy, *,
                         heal_at.setdefault(i + heal_after, []).append(ev)
         elif evs:
             ev = evs[0]
-            with rec.span("recovery", step=i,
-                          domain=f"{ev.kind}:{ev.index}"):
-                p, info = ctl.on_domain_event(p, ev.kind, ev.index, step=i)
+            p, info = ctl.on_domain_event(p, ev.kind, ev.index, step=i)
             info["step"] = i
             events_out.append(info)
             if heal_after is not None and not info.get("skipped"):
                 heal_at.setdefault(i + heal_after, []).append(ev)
         for ev in heal_at.pop(i, []):
-            with rec.span("heal", step=i, domain=f"{ev.kind}:{ev.index}"):
-                ctl.heal_domain(ev.kind, ev.index, p, step=i)
+            ctl.heal_domain(ev.kind, ev.index, p, step=i)
         # placement-health flag AFTER this step's events/heals — the
         # availability report turns these into time-to-full-redundancy
         redundancy_full.append(ctl.fabric.redundancy_state()["full"])

@@ -47,6 +47,7 @@ from repro.models import get_model
 from repro.optim.optimizers import Optimizer, adamw
 from repro.sharding.partition import DistContext, named_shardings
 from repro.telemetry.recorder import NULL_RECORDER, Histogram
+from repro.telemetry.spans import SpanTracer, merge_rollup
 from repro.training.train_state import ArenaTrainState, TrainState
 
 PyTree = Any
@@ -121,6 +122,13 @@ class TrainLoopConfig:
                              "a fabric (set TrainLoopConfig.fabric)")
 
 
+def _clean(rec: dict) -> bool:
+    """A step whose maintenance overhead was measured and that no
+    failure, heal or injected fault touched."""
+    return ("overhead_seconds" in rec and "failures" not in rec
+            and "heals" not in rec and "failure" not in rec)
+
+
 class TrainLoop:
     def __init__(self, cfg: ModelConfig, ctx: DistContext,
                  optimizer: Optional[Optimizer] = None,
@@ -156,18 +164,11 @@ class TrainLoop:
         self._overhead_hist = (
             self.recorder.histogram("train/overhead_seconds")
             if self.recorder.enabled else Histogram())
-        # per-phase split of the clean-step overhead (sweep dispatch,
-        # checkpoint save, fence wait) — overhead_summary() attributes
-        # the async overlap win to the phase that shrank. The loop-side
-        # fence histogram holds sync-mode blocking samples; async-mode
-        # deferred-fence waits live in the fabric's own fence histogram
-        # and the two are merged at summary time.
-        self._sweep_hist = (self.recorder.histogram("train/sweep_seconds")
-                            if self.recorder.enabled else Histogram())
-        self._save_hist = (self.recorder.histogram("train/save_seconds")
-                           if self.recorder.enabled else Histogram())
-        self._fence_hist = (self.recorder.histogram("train/fence_seconds")
-                            if self.recorder.enabled else Histogram())
+        # host spans are on with or without a recorder: each step's record
+        # takes this tracer's rollup (a recorder's tracer also keeps the
+        # span records for trace.json)
+        self.tracer = (self.recorder.tracer if self.recorder.enabled
+                       else SpanTracer(keep=False))
 
         from repro.training.step import make_train_step
         self._train_step = jax.jit(
@@ -364,6 +365,13 @@ class TrainLoop:
 
     def run(self, state, batches, n_steps: int,
             on_step: Optional[Callable[[int, float], None]] = None):
+        """Train ``n_steps`` steps, appending one record per step to
+        :attr:`metrics`: ``step``, ``loss``, ``seconds`` (the train step,
+        fenced by reading the loss), ``overhead_seconds`` (maintain +
+        save, and the sync-mode fence on the sweep), event fields, and the
+        step's span rollup (:func:`repro.telemetry.spans.new_rollup`):
+        ``spans``, ``compiles``, ``bytes``, ``store_lag_s``. Background
+        writes that land in the closing flush go on the last record."""
         it = iter(batches)
         events_at = self._sample_trace(n_steps)
         heal_at: dict[int, list] = {}
@@ -374,147 +382,16 @@ class TrainLoop:
             flips_at.setdefault(max(1, min(s, n_steps)), []).append(blk)
         elastic = self._elastic_enabled(state)
         self._last_batch_dim = None
+        step0 = int(state.step)
+        self.tracer.take()          # what ran before this run is not a step's
         for i in range(1, n_steps + 1):
-            # re-read each iteration: an elastic resize swaps the jitted
-            # step under our feet mid-run
-            step_fn = (self._arena_step if isinstance(state, ArenaTrainState)
-                       else self._train_step)
-            batch = next(it)
-            if elastic:
-                self._last_batch_dim = int(
-                    jax.tree_util.tree_leaves(batch)[0].shape[0])
-                if self._mesh_resized:
-                    batch = self._place_batch(batch)
-            t0 = time.perf_counter()
-            with self.recorder.span("train_step", step=i):
-                state, loss = step_fn(state, batch)
-                loss = float(loss)   # fences on the loss output
-            dt = time.perf_counter() - t0
-            rec = {"step": int(state.step), "loss": loss, "seconds": dt}
-
-            if self.controller is not None:
-                # maintain first: the fused maintenance sweep scores the
-                # blocks against the running checkpoint in the same read,
-                # and a same-step partial save below reuses those scores
-                tm0 = time.perf_counter()
-                live = self._live(state)
-                self.controller.maintain(int(state.step), live)
-                t_maint = time.perf_counter()
-                with self.recorder.span("save", step=int(state.step)):
-                    if self.controller.maybe_checkpoint(int(state.step),
-                                                        live):
-                        rec["checkpointed"] = True
-                t_save = time.perf_counter()
-                fab = self.controller.fabric
-                async_mode = (fab is not None
-                              and getattr(fab.cfg, "async_maintain", False))
-                # per-step fault-tolerance overhead (maintain + save),
-                # excluding the rare failure/heal events timed below —
-                # the examples report this next to the step time. Sync
-                # mode blocks on the sweep's device outputs first:
-                # checkpoint_now only blocks on save steps, and under
-                # async dispatch a maintain-only step would otherwise
-                # book dispatch time here and push the sweep's compute
-                # into the NEXT step's "seconds". Async-maintain mode
-                # must NOT block — hiding the sweep under the next step
-                # is the whole point; its overhead is the dispatch cost,
-                # and the sweep's un-hidden remainder books into the
-                # fabric's fence histogram at the deferred fence instead.
-                t_fence = t_save
-                if self.loop_cfg.measure_overhead:
-                    if fab is not None and not async_mode:
-                        fab.block_until_maintained()
-                        t_fence = time.perf_counter()
-                    rec["overhead_seconds"] = t_fence - tm0
-                evs = events_at.pop(i, [])
-                if len(evs) > 1:
-                    # simultaneous multi-domain loss: every event resolves
-                    # against the pre-failure view and the union recovers
-                    # in ONE tier-planned pass (the RS tier's multi-erasure
-                    # case — applying them sequentially would let the first
-                    # recovery's re-encode hide the correlation)
-                    names = ",".join(f"{e.kind}:{e.index}" for e in evs)
-                    with self.recorder.span("recovery", step=int(state.step),
-                                            domain=names):
-                        live, info = self.controller.on_domain_events(
-                            live, [(e.kind, e.index) for e in evs],
-                            step=int(state.step))
-                    state = self._with_live(state, live)
-                    rec.setdefault("failures", []).append(info)
-                    if self.loop_cfg.heal_after is not None:
-                        applied = {(a["kind"], a["index"])
-                                   for a in info.get("events", [])}
-                        for ev in evs:
-                            if (ev.kind, ev.index) in applied:
-                                heal_at.setdefault(
-                                    i + self.loop_cfg.heal_after,
-                                    []).append(ev)
-                elif evs:
-                    ev = evs[0]
-                    with self.recorder.span("recovery", step=int(state.step),
-                                            domain=f"{ev.kind}:{ev.index}"):
-                        live, info = self.controller.on_domain_event(
-                            live, ev.kind, ev.index, step=int(state.step))
-                    state = self._with_live(state, live)
-                    rec.setdefault("failures", []).append(info)
-                    if (self.loop_cfg.heal_after is not None
-                            and not info.get("skipped")):
-                        heal_at.setdefault(i + self.loop_cfg.heal_after,
-                                           []).append(ev)
-                for ev in heal_at.pop(i, []):
-                    with self.recorder.span("heal", step=int(state.step),
-                                            domain=f"{ev.kind}:{ev.index}"):
-                        heal = self.controller.heal_domain(
-                            ev.kind, ev.index, live, step=int(state.step))
-                    rec.setdefault("heals", []).append(heal)
-                if elastic and ("failures" in rec or "heals" in rec):
-                    # domain events changed the survivor set: shrink the
-                    # mesh to the alive devices (or re-grow after a heal),
-                    # relayout the arena state, and re-jit the step —
-                    # training continues on the new topology next step
-                    state = self._maybe_resize(state, int(state.step), rec)
-                for blk in flips_at.pop(i, []):
-                    # soft-error injection: corrupt the replica snapshot
-                    # invisibly — only the scrub (or the honestly-priced
-                    # perturbation of a later replica recovery) sees it
-                    if fab is not None and fab.replicas is not None \
-                            and fab.replicas.arena is not None:
-                        where = fab.inject_arena_bit_flip(block=blk,
-                                                          rng=self._rng)
-                        rec.setdefault("bit_flips", []).append(where)
-                if (self.loop_cfg.scrub_interval
-                        and i % self.loop_cfg.scrub_interval == 0):
-                    with self.recorder.span("scrub", step=int(state.step)):
-                        sc = self.controller.scrub(step=int(state.step))
-                    if sc["checked"]:
-                        rec["scrub"] = {"detected": sc["detected"],
-                                        "corrected": sc["corrected"]}
-                if (self.loop_cfg.fail_prob > 0
-                        and self._rng.random() < self.loop_cfg.fail_prob):
-                    with self.recorder.span("recovery",
-                                            step=int(state.step)):
-                        new_live, info = self._inject(state)
-                    state = self._with_live(state, new_live)
-                    rec["failure"] = info
-                # clean-step overhead sample: failure/heal steps are
-                # excluded so the distribution answers "what does fault
-                # tolerance cost when nothing is on fire"
-                if "overhead_seconds" in rec and "failures" not in rec \
-                        and "heals" not in rec and "failure" not in rec:
-                    self._overhead_hist.observe(rec["overhead_seconds"])
-                    self._sweep_hist.observe(t_maint - tm0)
-                    self._save_hist.observe(t_save - t_maint)
-                    if not async_mode:
-                        self._fence_hist.observe(t_fence - t_save)
-                if self.controller.fabric is not None:
-                    # per-step placement health — availability_summary()
-                    # folds these into the soak goodput report
-                    full = self.controller.fabric.redundancy_state()["full"]
-                    rec["redundancy_full"] = full
-                    self._redundancy_flags.append(full)
+            with self.tracer.span("scar/step", step=step0 + i):
+                state, rec = self._step(i, state, it, elastic, events_at,
+                                        heal_at, flips_at)
+            rec.update(self.tracer.take())
             self.metrics.append(rec)
             if on_step is not None:
-                on_step(i, loss)
+                on_step(i, rec["loss"])
         # epoch boundary: settle any in-flight async sweep (the deferred
         # fence's last consume point) and drain the background store
         # writer so run() returns with redundancy published and durable —
@@ -526,7 +403,130 @@ class TrainLoop:
             if self.controller.store is not None \
                     and hasattr(self.controller.store, "flush"):
                 self.controller.store.flush()
+        if n_steps > 0:
+            # writes that landed in the closing flush: the last step's
+            merge_rollup(self.metrics[-1], self.tracer.take())
         return state
+
+    def _step(self, i: int, state, it, elastic: bool, events_at: dict,
+              heal_at: dict, flips_at: dict):
+        """One iteration of :meth:`run` (inside its ``scar/step`` span):
+        the train step, then maintenance, the save, and this step's
+        domain events, heals, bit flips and scrubs."""
+        # re-read each iteration: an elastic resize swaps the jitted
+        # step under our feet mid-run
+        step_fn = (self._arena_step if isinstance(state, ArenaTrainState)
+                   else self._train_step)
+        batch = next(it)
+        if elastic:
+            self._last_batch_dim = int(
+                jax.tree_util.tree_leaves(batch)[0].shape[0])
+            if self._mesh_resized:
+                batch = self._place_batch(batch)
+        t0 = time.perf_counter()
+        with self.tracer.span("scar/step/train"):
+            state, loss = step_fn(state, batch)
+            loss = float(loss)   # fences on the loss output
+        dt = time.perf_counter() - t0
+        rec = {"step": int(state.step), "loss": loss, "seconds": dt}
+        if self.controller is None:
+            return state, rec
+        # maintain first: the fused maintenance sweep scores the blocks
+        # against the running checkpoint in the same read, and a
+        # same-step partial save below reuses those scores
+        tm0 = time.perf_counter()
+        live = self._live(state)
+        self.controller.maintain(int(state.step), live)
+        if self.controller.maybe_checkpoint(int(state.step), live):
+            rec["checkpointed"] = True
+        fab = self.controller.fabric
+        async_mode = (fab is not None
+                      and getattr(fab.cfg, "async_maintain", False))
+        # per-step fault-tolerance overhead (maintain + save), excluding
+        # the rare failure/heal events below — the examples report this
+        # next to the step time. Sync mode blocks on the sweep's device
+        # outputs first: checkpoint_now only blocks on save steps, and
+        # under async dispatch a maintain-only step would otherwise book
+        # dispatch time here and push the sweep's compute into the NEXT
+        # step's "seconds". Async-maintain mode must NOT block — hiding
+        # the sweep under the next step is the whole point; its overhead
+        # is the dispatch cost, and the sweep's un-hidden remainder books
+        # into the fabric's fence histogram at the deferred fence instead.
+        if self.loop_cfg.measure_overhead:
+            if fab is not None and not async_mode:
+                with self.tracer.span("scar/step/fence"):
+                    fab.block_until_maintained()
+            rec["overhead_seconds"] = time.perf_counter() - tm0
+        evs = events_at.pop(i, [])
+        if len(evs) > 1:
+            # simultaneous multi-domain loss: every event resolves
+            # against the pre-failure view and the union recovers in ONE
+            # tier-planned pass (the RS tier's multi-erasure case —
+            # applying them sequentially would let the first recovery's
+            # re-encode hide the correlation)
+            live, info = self.controller.on_domain_events(
+                live, [(e.kind, e.index) for e in evs],
+                step=int(state.step))
+            state = self._with_live(state, live)
+            rec.setdefault("failures", []).append(info)
+            if self.loop_cfg.heal_after is not None:
+                applied = {(a["kind"], a["index"])
+                           for a in info.get("events", [])}
+                for ev in evs:
+                    if (ev.kind, ev.index) in applied:
+                        heal_at.setdefault(i + self.loop_cfg.heal_after,
+                                           []).append(ev)
+        elif evs:
+            ev = evs[0]
+            live, info = self.controller.on_domain_event(
+                live, ev.kind, ev.index, step=int(state.step))
+            state = self._with_live(state, live)
+            rec.setdefault("failures", []).append(info)
+            if (self.loop_cfg.heal_after is not None
+                    and not info.get("skipped")):
+                heal_at.setdefault(i + self.loop_cfg.heal_after,
+                                   []).append(ev)
+        for ev in heal_at.pop(i, []):
+            heal = self.controller.heal_domain(ev.kind, ev.index, live,
+                                               step=int(state.step))
+            rec.setdefault("heals", []).append(heal)
+        if elastic and ("failures" in rec or "heals" in rec):
+            # domain events changed the survivor set: shrink the mesh to
+            # the alive devices (or re-grow after a heal), relayout the
+            # arena state, and re-jit the step — training continues on
+            # the new topology next step
+            state = self._maybe_resize(state, int(state.step), rec)
+        for blk in flips_at.pop(i, []):
+            # soft-error injection: corrupt the replica snapshot
+            # invisibly — only the scrub (or the honestly-priced
+            # perturbation of a later replica recovery) sees it
+            if fab is not None and fab.replicas is not None \
+                    and fab.replicas.arena is not None:
+                where = fab.inject_arena_bit_flip(block=blk, rng=self._rng)
+                rec.setdefault("bit_flips", []).append(where)
+        if (self.loop_cfg.scrub_interval
+                and i % self.loop_cfg.scrub_interval == 0):
+            sc = self.controller.scrub(step=int(state.step))
+            if sc["checked"]:
+                rec["scrub"] = {"detected": sc["detected"],
+                                "corrected": sc["corrected"]}
+        if (self.loop_cfg.fail_prob > 0
+                and self._rng.random() < self.loop_cfg.fail_prob):
+            new_live, info = self._inject(state)
+            state = self._with_live(state, new_live)
+            rec["failure"] = info
+        # clean-step overhead sample: failure/heal steps are excluded so
+        # the distribution answers "what does fault tolerance cost when
+        # nothing is on fire"
+        if _clean(rec):
+            self._overhead_hist.observe(rec["overhead_seconds"])
+        if fab is not None:
+            # per-step placement health — availability_summary() folds
+            # these into the soak goodput report
+            full = fab.redundancy_state()["full"]
+            rec["redundancy_full"] = full
+            self._redundancy_flags.append(full)
+        return state, rec
 
     def availability_summary(self) -> dict:
         """Aggregate this loop's soak accounting (per-event tier counts +
@@ -553,10 +553,11 @@ class TrainLoop:
         from the telemetry histogram, so the p95 a dashboards reads and
         the one reported here are the same samples.
 
-        ``phases`` attributes the overhead: ``sweep`` (maintain call),
-        ``save`` (maybe_checkpoint), ``fence`` (blocking waits — the
-        loop's sync-mode blocks merged with the fabric's deferred
-        async-fence waits). ``overlap_efficiency`` is the fraction of
+        ``phases`` attributes the clean-step overhead, read from each
+        step's span rollup: ``sweep`` (``scar/maintain``), ``save``
+        (``scar/save``), ``fence`` (blocking waits — the loop's sync-mode
+        ``scar/step/fence`` merged with the fabric's deferred async-fence
+        waits). ``overlap_efficiency`` is the fraction of
         async sweep wall-clock hidden under the trainer's compute
         (0.0 in sync mode — nothing is overlapped)."""
         steps = [m["seconds"] for m in self.metrics]
@@ -571,12 +572,19 @@ class TrainLoop:
                "arena_state": self.arena_layout is not None}
         fab = (self.controller.fabric
                if self.controller is not None else None)
-        fence = Histogram()
-        fence.samples = list(self._fence_hist.samples)
+        sweep, save, fence = Histogram(), Histogram(), Histogram()
+        for m in filter(_clean, self.metrics):
+            sp = m["spans"]
+            # a save's forced refresh is a sweep inside the save: book it
+            # to the save, as the save's own wall clock always did
+            sweep.observe(sp.get("scar/maintain", 0.0)
+                          - sp.get("scar/save/refresh", 0.0))
+            save.observe(sp.get("scar/save", 0.0))
+            if "scar/step/fence" in sp:
+                fence.observe(sp["scar/step/fence"])
         if fab is not None:
             fence.samples += list(fab.fence_hist.samples)
-        out["phases"] = {"sweep": self._sweep_hist.summary(),
-                         "save": self._save_hist.summary(),
+        out["phases"] = {"sweep": sweep.summary(), "save": save.summary(),
                          "fence": fence.summary()}
         out["overlap_efficiency"] = (fab.overlap_efficiency()
                                      if fab is not None else 0.0)

@@ -15,8 +15,9 @@ The tentpole invariants of ``FabricConfig(async_maintain=True)``:
 - **deferred fence ordering** — the fence moves off the per-step hot
   path and is taken only at consume points (``maybe_checkpoint``,
   failure/elastic replan, ``block_until_maintained``, end of run);
-- **overlap** — the Chrome trace's deferred ``maintain`` spans cover
-  [dispatch, fence] and genuinely overlap the next ``train_step`` span.
+- **overlap** — the Chrome trace's ``scar/async_sweep`` records cover
+  [dispatch, fence] and genuinely overlap the next ``scar/step/train``
+  span.
 """
 import jax
 import jax.numpy as jnp
@@ -248,19 +249,21 @@ def test_async_lm_bit_identical_and_spans_overlap():
     fab = la.controller.fabric
     assert fab.stats["async_maintains"] == 10
     assert not fab.has_pending_maintenance   # end-of-run fence ran
-    # the Chrome trace shows maintain spans genuinely overlapping
-    # train_step spans — the deferred [dispatch, fence] intervals
-    trains = rec.tracer.intervals("train_step")
-    maints = rec.tracer.intervals("maintain")
+    # the Chrome trace shows async sweeps genuinely overlapping
+    # train-step spans — the deferred [dispatch, fence] intervals
+    trains = rec.tracer.intervals("scar/step/train")
+    maints = rec.tracer.intervals("scar/async_sweep")
     assert len(maints) == 10
     overlapping = sum(
         any(m0 < t1 and t0 < m1 for (t0, t1) in trains)
         for (m0, m1) in maints)
     assert overlapping >= 1
     deferred = [s for s in rec.tracer.spans
-                if s.name == "maintain" and s.args.get("deferred")]
+                if s.name == "scar/async_sweep"]
     assert len(deferred) == 10
     assert all(s.args["mode"] == "arena_async" for s in deferred)
+    # the host side of each maintain call is a span of its own
+    assert len(rec.tracer.intervals("scar/maintain")) == 10
     # phase split + overlap gauge are wired through overhead_summary
     out = la.overhead_summary()
     assert set(out["phases"]) == {"sweep", "save", "fence"}

@@ -125,24 +125,6 @@ def test_spans_nest_and_export_chrome_trace(tmp_path):
         assert len(json.load(f)["traceEvents"]) == 2
 
 
-def test_span_fence_runs_before_end_timestamp():
-    """The fence (device sync) must be *inside* the measured interval."""
-    tracer = SpanTracer()
-    with tracer.span("maintain", fence=lambda: time.sleep(0.02)):
-        pass
-    (dur,) = tracer.durations("maintain")
-    assert dur >= 0.02
-
-
-def test_span_fence_accepts_arrays():
-    import jax.numpy as jnp
-    tracer = SpanTracer()
-    x = jnp.ones((8,))
-    with tracer.span("maintain", fence=x * 2):
-        pass
-    assert tracer.durations("maintain")
-
-
 # ---------------------------------------------------------------------------
 # perturbation ledger: bounds bit-match core/iteration_cost
 # ---------------------------------------------------------------------------
@@ -193,12 +175,13 @@ def test_null_recorder_is_allocation_free_singletons():
     assert NULL_RECORDER.enabled is False
     assert isinstance(NULL_RECORDER, NullRecorder)
     # shared singletons, no per-call allocation
-    assert NULL_RECORDER.span("a") is NULL_RECORDER.span("b")
     assert NULL_RECORDER.histogram("x") is NULL_RECORDER.counter("y")
     d = {"k": 1}
     assert NULL_RECORDER.scope("s", d) is d
-    with NULL_RECORDER.span("noop", fence=lambda: 1 / 0):
-        pass               # the fence must never run on the null path
+    # spans stay on without a recorder: no tracer, so nothing is kept
+    assert NULL_RECORDER.tracer is None
+    with NULL_RECORDER.span("scar/noop") as sp:
+        assert sp.tracer is None
     NULL_RECORDER.event("anything", x=1)
     NULL_RECORDER.record_recovery(step=1, lost_blocks=1,
                                   tier_counts=None, applied_sq=0.0)
@@ -317,3 +300,165 @@ def test_histogram_summary_percentiles():
     assert s["p50"] == 3.0
     assert s["p95"] == pytest.approx(
         float(np.percentile([1, 2, 3, 4, 100], 95)))
+
+
+# ---------------------------------------------------------------------------
+# in-program spans: always on, never waiting, per-step rollup
+# ---------------------------------------------------------------------------
+
+def test_spans_never_wait_for_the_device(monkeypatch):
+    """No span calls ``jax.block_until_ready``: not a bare tracer's, not
+    the null recorder's, and not the fabric's ``scar/maintain`` with a
+    recorder attached (which once fenced the sweep)."""
+    import jax
+    import jax.numpy as jnp
+
+    def refuse(*_a, **_k):
+        raise AssertionError("a span waited for the device")
+
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    x = jnp.ones((16,))
+    rec = Recorder()
+    with SpanTracer(keep=False).span("scar/test/outer") as sp:
+        with NULL_RECORDER.span("scar/test/null"):
+            with rec.span("scar/test/kept"):
+                y = x * 2
+        sp.add_bytes(y.nbytes)
+    m = make_model("qp")
+    p = m.init(jax.random.PRNGKey(1))
+    ctl = FTController(p, CheckpointPolicy(fraction=0.5, full_interval=4),
+                       fabric=FabricConfig(n_devices=8), recorder=rec)
+    ctl.maintain(1, p)
+    assert rec.tracer.durations("scar/maintain")
+    assert rec.tracer.durations("scar/test/kept")
+
+
+def test_new_shape_books_one_compile_to_its_span():
+    """A program compiled inside nested spans books exactly one compile,
+    to the innermost span; a second call of the same shape books none."""
+    import jax
+    import jax.numpy as jnp
+    tracer = SpanTracer(keep=False)
+    f = jax.jit(lambda v: v * 3 + 1)
+    x = jnp.ones((7, 13))
+    tracer.take()
+    with tracer.span("scar/test/outer"):
+        with tracer.span("scar/test/inner"):
+            f(x)
+    r = tracer.take()
+    assert r["compiles"]["scar/test/inner"][0] == 1
+    assert r["compiles"]["scar/test/inner"][1] > 0
+    assert "scar/test/outer" not in r["compiles"]
+    assert set(r["spans"]) == {"scar/test/outer", "scar/test/inner"}
+    with tracer.span("scar/test/inner"):
+        f(x)
+    assert tracer.take()["compiles"] == {}
+
+
+def _lm_run(tmp_path, recorder):
+    """Reduced LM with fabric and a background store: 8 steps, a save
+    every 4th (the last step's), one host lost at step 3 and healed at
+    step 4."""
+    from repro.checkpoint_io.store import ShardedCheckpointStore
+    from repro.configs import get_config
+    from repro.core.policy import RecoveryMode, SelectionStrategy
+    from repro.data.pipeline import ShardedLMDataset
+    from repro.sharding import single_device_ctx
+    from repro.training import TrainLoop, TrainLoopConfig
+    ctx = single_device_ctx()
+    cfg = get_config("qwen2-1.5b", reduced=True)
+    pol = CheckpointPolicy(fraction=0.25, full_interval=16,
+                           strategy=SelectionStrategy.PRIORITY,
+                           recovery=RecoveryMode.PARTIAL, async_persist=True)
+    loop = TrainLoop(cfg, ctx, loop_cfg=TrainLoopConfig(
+        policy=pol, fabric=FabricConfig(), arena_state=True,
+        fail_schedule=[(3, "host", 1)], heal_after=1, recorder=recorder),
+        store=ShardedCheckpointStore(str(tmp_path)))
+    state = loop.init_state()
+    ds = ShardedLMDataset(cfg, batch=2, seq=32, ctx=ctx)
+    loop.run(state, iter(ds), 8)
+    return loop
+
+
+@pytest.fixture(scope="module")
+def lm_runs(tmp_path_factory):
+    bare = _lm_run(tmp_path_factory.mktemp("bare"), None)
+    rec = Recorder()
+    kept = _lm_run(tmp_path_factory.mktemp("kept"), rec)
+    return bare, kept, rec
+
+
+def test_lm_loop_step_rollup(lm_runs):
+    """Every step's record carries the span rollup; on save steps the
+    save's host path is broken down, and no child outlasts its parent."""
+    loop, _, _ = lm_runs
+    saves = [m for m in loop.metrics if m.get("checkpointed")]
+    assert [m["step"] for m in saves] == [4, 8]
+    for m in loop.metrics:
+        assert set(m) >= {"spans", "compiles", "bytes", "store_lag_s"}
+        sp = m["spans"]
+        for name, sec in sp.items():
+            assert name.startswith("scar/") and sec >= 0
+            # the background writer's span runs on its own thread, and
+            # may have started in an earlier step
+            if name not in ("scar/step", "scar/store/write"):
+                assert sec <= sp["scar/step"]
+        for k in ("scar/step/train", "scar/step/fence", "scar/maintain"):
+            assert k in sp
+    for m in saves:
+        sp = m["spans"]
+        kids = [k for k in sp if k.startswith("scar/save/")]
+        assert set(kids) >= {"scar/save/select", "scar/save/scatter",
+                             "scar/save/tiles_to_host",
+                             "scar/save/store_enqueue",
+                             "scar/save/parity_to_host"}
+        assert sum(sp[k] for k in kids) + sp["scar/store/parity_write"] \
+            <= sp["scar/save"]
+        assert m["bytes"]["scar/save/tiles_to_host"] > 0
+        assert m["bytes"]["scar/save/parity_to_host"] \
+            == m["bytes"]["scar/store/parity_write"] > 0
+    fail = next(m for m in loop.metrics if "failures" in m)["spans"]
+    assert sum(fail[k] for k in fail if k.startswith("scar/recovery/")) \
+        <= fail["scar/recovery"]
+    # one background write per save, each with its lag; the last save's
+    # lands on the last step, by run()'s closing flush at the latest
+    lags = [x for m in loop.metrics for x in m["store_lag_s"]]
+    assert len(lags) == 2 and all(x > 0 for x in lags)
+    assert loop.metrics[-1]["store_lag_s"]
+    assert sum(m["bytes"].get("scar/store/write", 0)
+               for m in loop.metrics) == sum(
+        m["bytes"]["scar/save/tiles_to_host"] for m in saves) > 0
+    # the overhead phases read the same rollup, over clean steps (the
+    # save at step 4 shares its step with the heal)
+    phases = loop.overhead_summary()["phases"]
+    assert phases["save"]["max"] == loop.metrics[-1]["spans"]["scar/save"]
+    assert phases["save"]["count"] == phases["fence"]["count"] == 6
+
+
+def test_recorder_changes_only_what_is_kept(lm_runs):
+    """Attaching a Recorder leaves the losses and the running checkpoint
+    bit-identical, and the same spans and bytes in every step's rollup
+    (but the background writer's, which lands where its timing puts it);
+    only the Recorder's tracer keeps records (with parents and steps)."""
+    bare, kept, rec = lm_runs
+    assert [m["loss"] for m in bare.metrics] == \
+        [m["loss"] for m in kept.metrics]
+    assert (np.asarray(bare.controller._ckpt_arena)
+            == np.asarray(kept.controller._ckpt_arena)).all()
+    assert (np.asarray(bare.controller.ckpt.saved_iter)
+            == np.asarray(kept.controller.ckpt.saved_iter)).all()
+    def booked(m, d):
+        # the background writer books into whichever step it lands in
+        return {k: v for k, v in m[d].items() if k != "scar/store/write"}
+
+    for a, b in zip(bare.metrics, kept.metrics):
+        assert set(booked(a, "spans")) == set(booked(b, "spans"))
+        assert booked(a, "bytes") == booked(b, "bytes")
+    assert bare.tracer.spans == []
+    recs = {s.sid: s for s in rec.tracer.spans}
+    save = next(s for s in recs.values() if s.name == "scar/save")
+    assert save.step == 4
+    child = next(s for s in recs.values()
+                 if s.name == "scar/save/tiles_to_host" and s.step == 4)
+    assert recs[child.parent] is save
+    assert recs[save.parent].name == "scar/step"
