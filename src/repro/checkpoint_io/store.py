@@ -25,7 +25,12 @@ new index over the new file, never live offsets into a rewritten file.
 Writes can be deferred to a background thread (``background=True``),
 matching §4.3: "the training algorithm can be resumed as soon as the
 in-memory caches have been updated, while output to the shared persistent
-storage happens asynchronously".
+storage happens asynchronously". Shard writes and parity mirrors share one
+FIFO writer, so a save's parity is published after its shard write; each
+queued item is a host snapshot taken at enqueue and dropped once written.
+:meth:`wait_writes` blocks until the writer is idle, which a caller uses
+to keep one save in flight; :meth:`flush` does the same and raises a
+parked failure.
 
 **Fabric-aware sharding** (optional ``homes``/``domains`` at ``init``):
 shards are keyed by failure domain — ``host_NNNN/blocks.shard`` per the
@@ -45,6 +50,7 @@ import queue
 import random
 import threading
 import time
+import traceback
 from typing import Any, Optional
 
 import jax
@@ -59,13 +65,67 @@ PyTree = Any
 
 @dataclasses.dataclass
 class _Write:
-    """One queued background write: its segments, and what the writer
-    books when it lands (bytes, enqueue time, the enqueuer's tracer)."""
+    """One queued shard write: its segments, and what the writer books
+    when it lands (bytes, enqueue time, the enqueuer's tracer). Each kind
+    of queued write names its span and says how to run itself, what
+    to do once landed, and what a failure names."""
     jobs: list
     step: int
     nbytes: int
     t_enqueue: float
     tracer: Any
+    span = "scar/store/write"
+
+    def run(self, store: "ShardedCheckpointStore") -> None:
+        store._do_write(self.jobs, self.step)
+
+    def landed(self) -> None:
+        # the manifest is published: the write is durable (the
+        # enqueue-to-publish lag is the shard writes' alone)
+        if self.tracer is not None:
+            self.tracer.add_lag(time.perf_counter() - self.t_enqueue)
+
+    def context(self, store: "ShardedCheckpointStore") -> dict:
+        """The batch's first job: enough to name the shard that broke."""
+        ctx = {"write": "shard", "step": int(self.step), "segment": None,
+               "host": None, "path": None}
+        if self.jobs:
+            seg = int(self.jobs[0][0])
+            ctx.update(segment=seg, path=store._shard_path(seg))
+            if store.host_of_block is not None:
+                ctx["host"] = int(store.host_of_block[store._seg_gid(seg)])
+        return ctx
+
+
+@dataclasses.dataclass
+class _ParityWrite:
+    """One parity mirror, snapshotted when it is made: the host parity
+    array and its ``PARITY.json`` (step, per-group file paths, homes and
+    members as of the save), and the tracer its span books into. ``path``
+    is the file being written, which a failure names."""
+    parity: np.ndarray
+    meta: dict
+    tracer: Any
+    path: Optional[str] = None
+    span = "scar/store/parity_write"
+
+    @property
+    def step(self) -> int:
+        return self.meta["step"]
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.parity.nbytes)
+
+    def run(self, store: "ShardedCheckpointStore") -> None:
+        store._put_parity(self)
+
+    def landed(self) -> None:
+        pass
+
+    def context(self, store: "ShardedCheckpointStore") -> dict:
+        return {"write": "parity", "step": int(self.step), "segment": None,
+                "host": None, "path": self.path}
 
 
 def _shard_name(gen: int) -> str:
@@ -345,57 +405,79 @@ class ShardedCheckpointStore:
     def write_parity(self, step: int, parity: np.ndarray,
                      parity_homes: np.ndarray,
                      domains: Optional[Any] = None,
-                     members: Optional[np.ndarray] = None) -> int:
+                     members: Optional[np.ndarray] = None,
+                     background: bool = False) -> int:
         """Mirror the fabric's parity blocks to disk for offline
         reconstruction. One file per group, keyed by the parity home's host
         when the store is domain-keyed, plus a small ``PARITY.json``
         manifest (step, frame width, per-group paths, and — essential for
         reconstruction after a restart — each group's member block ids as
-        of encode time, which elastic re-striping changes). Synchronous —
-        the parity buffer is 1/g the size of a block write."""
-        with self.recorder.span("scar/store/parity_write",
-                                step=int(step)) as sp:
-            nbytes = self._write_parity(step, parity, parity_homes,
-                                        domains, members)
-            sp.add_bytes(nbytes)
-        return nbytes
+        of encode time, which elastic re-striping changes). Returns the
+        parity bytes written (scheduled).
 
-    def _write_parity(self, step: int, parity, parity_homes, domains,
-                      members) -> int:
+        The parity covers every group on every call, so it can be several
+        times the size of a partial save's block write. ``background=True``
+        queues it behind the shard writes already queued: the host copy of
+        ``parity``, the homes, members and file paths are taken now, so a
+        later re-stripe, loss or heal cannot change what is written or
+        where. Its failures surface on :meth:`flush` like a shard write's."""
         parity = np.asarray(parity)
+        item = _ParityWrite(parity, self._parity_meta(
+            step, parity.shape, parity_homes, domains, members),
+            current_tracer(self.recorder.tracer))
+        if background:
+            self._ensure_worker()
+            self._q.put(item)
+        else:
+            with self.recorder.span("scar/store/parity_write",
+                                    step=int(step)) as sp:
+                self._put_parity(item)
+                sp.add_bytes(item.nbytes)
+        return item.nbytes
+
+    def _parity_meta(self, step: int, shape: tuple, parity_homes, domains,
+                     members) -> dict:
+        """``PARITY.json`` of a mirror of parity ``shape``, its file paths
+        keyed by the homes as they are now."""
         # XOR homes are (n_groups,); RS(k, m) homes are (n_groups, m) with
         # a (n_groups, m, E) parity array — each group's rows share a file,
         # keyed by row 0's host (the primary fingerprint row)
         homes = np.asarray(parity_homes, np.int32)
-        paths = []
-        for g in range(parity.shape[0]):
-            if self.host_of_block is not None and domains is not None:
-                key = int(np.ravel(homes[g])[0]) if homes.ndim > 1 \
-                    else int(homes[g])
-                host_dir = f"host_{int(domains.host_of(key)):04d}"
-                os.makedirs(os.path.join(self.root, host_dir), exist_ok=True)
-                rel = os.path.join(host_dir, f"parity_{g:06d}.npy")
-            else:
-                rel = f"parity_{g:06d}.npy"
-            path = os.path.join(self.root, rel)
-            tmp = path + ".tmp"
-            with open(tmp, "wb") as f:
-                np.save(f, parity[g])
-            os.replace(tmp, path)
-            paths.append(rel)
-        meta = {"step": int(step), "n_groups": int(parity.shape[0]),
-                "frame_elems": int(parity.shape[-1]) if parity.ndim > 1 else 1,
-                "n_parity": int(parity.shape[1]) if parity.ndim == 3 else 1,
+        if self.host_of_block is not None and domains is not None:
+            keys = homes.reshape(homes.shape[0], -1)[:, 0]
+            hosts = np.asarray(domains.host_of(keys)).reshape(-1)
+            paths = [os.path.join(f"host_{int(hosts[g]):04d}",
+                                  f"parity_{g:06d}.npy")
+                     for g in range(shape[0])]
+        else:
+            paths = [f"parity_{g:06d}.npy" for g in range(shape[0])]
+        meta = {"step": int(step), "n_groups": int(shape[0]),
+                "frame_elems": int(shape[-1]) if len(shape) > 1 else 1,
+                "n_parity": int(shape[1]) if len(shape) == 3 else 1,
                 "paths": paths,
                 "parity_homes": homes.tolist()}
         if members is not None:
             meta["members"] = [[int(b) for b in row if b >= 0]
                                for row in np.asarray(members)]
-        tmp = os.path.join(self.root, "PARITY.json.tmp")
+        return meta
+
+    def _put_parity(self, item: _ParityWrite) -> None:
+        """Write each group's file (``.tmp``, then replaced), then
+        replace ``PARITY.json`` last."""
+        paths = [os.path.join(self.root, rel) for rel in item.meta["paths"]]
+        for d in sorted({os.path.dirname(p) for p in paths}):
+            os.makedirs(d, exist_ok=True)
+        for g, path in enumerate(paths):
+            item.path = path
+            tmp = path + ".tmp"
+            with open(tmp, "wb") as f:
+                np.save(f, item.parity[g])
+            os.replace(tmp, path)
+        item.path = os.path.join(self.root, "PARITY.json")
+        tmp = item.path + ".tmp"
         with open(tmp, "w") as f:
-            json.dump(meta, f)
-        os.replace(tmp, os.path.join(self.root, "PARITY.json"))
-        return int(parity.nbytes)
+            json.dump(item.meta, f)
+        os.replace(tmp, item.path)
 
     def read_parity(self) -> Optional[tuple[np.ndarray, dict]]:
         """(parity array, manifest) from the last mirror, or None."""
@@ -428,14 +510,17 @@ class ShardedCheckpointStore:
             try:
                 if item is None:
                     return
-                with span("scar/store/write", item.tracer,
-                          step=item.step) as sp:
-                    self._write_with_retry(item, item.jobs, item.step)
+                with span(item.span, item.tracer, step=item.step) as sp:
+                    self._write_with_retry(item)
                     sp.add_bytes(item.nbytes)
-                if item.tracer is not None:
-                    # the manifest is published: the write is durable
-                    item.tracer.add_lag(time.perf_counter() - item.t_enqueue)
+                item.landed()
             except BaseException as e:  # keep draining; surface on flush()
+                # the parked error keeps its traceback, not the frames'
+                # locals: a failed write's snapshot is freed too
+                cause = e
+                while cause is not None:
+                    traceback.clear_frames(cause.__traceback__)
+                    cause = cause.__cause__
                 if self._worker_error is None:
                     # keep the FIRST failure's context — later failures
                     # are usually cascades of the same root cause
@@ -451,14 +536,17 @@ class ShardedCheckpointStore:
                                             error=repr(root),
                                             **self._worker_error_ctx)
             finally:
-                # task_done even on failure — otherwise q.join() in flush()
-                # deadlocks forever on the first bad write
+                # the snapshot is freed once written, not when the next
+                # item arrives; task_done even on failure — otherwise
+                # q.join() in flush() deadlocks forever on the first bad
+                # write
+                item = None
                 self._q.task_done()
 
-    def _write_with_retry(self, item, jobs, step: int) -> None:
+    def _write_with_retry(self, item) -> None:
         for attempt in range(self._retry_limit + 1):
             try:
-                self._do_write(jobs, step)
+                item.run(self)
                 return
             except BaseException as e:
                 if attempt >= self._retry_limit:
@@ -475,22 +563,15 @@ class ShardedCheckpointStore:
                 time.sleep(delay)
 
     def _job_context(self, item) -> dict:
-        """step/segment/host/path of a failed background write batch (its
-        first job — enough to name the shard that broke), for the error
-        ``flush()`` raises and the ``store_write_failed`` event."""
-        ctx = {"step": None, "segment": None, "host": None, "path": None}
+        """write kind, step, segment, host and path of a failed background
+        write, for the error ``flush()`` raises and the
+        ``store_write_failed`` event."""
         try:
-            jobs, step = item.jobs, item.step
-            ctx["step"] = int(step)
-            if jobs:
-                seg = int(jobs[0][0])
-                ctx["segment"] = seg
-                ctx["path"] = self._shard_path(seg)
-                if self.host_of_block is not None:
-                    ctx["host"] = int(self.host_of_block[self._seg_gid(seg)])
+            return item.context(self)
         except BaseException:
-            pass  # diagnostics must never mask the original failure
-        return ctx
+            # diagnostics must never mask the original failure
+            return {"write": None, "step": None, "segment": None,
+                    "host": None, "path": None}
 
     def _do_write(self, jobs, step: int) -> None:
         """Append the segments' payloads to their shards, then publish the
@@ -520,6 +601,13 @@ class ShardedCheckpointStore:
             manifest["segments"][seg] = new_segments[seg]
         self._write_manifest(manifest)
 
+    def wait_writes(self) -> None:
+        """Block until the background writer has finished every queued
+        write, landed or failed. Never raises: a failure stays parked for
+        the next :meth:`flush`."""
+        if self._worker is not None and self._worker.is_alive():
+            self._q.join()
+
     def flush(self) -> None:
         """Block until all background writes have landed.
 
@@ -527,17 +615,13 @@ class ShardedCheckpointStore:
         silently-lost mirror write would otherwise surface only at recovery
         time, when the data is already gone.
         """
-        if self._worker is not None and self._worker.is_alive():
-            self._q.join()
+        self.wait_writes()
         if self._worker_error is not None:
             err, self._worker_error = self._worker_error, None
             ctx, self._worker_error_ctx = self._worker_error_ctx, None
-            detail = ""
-            if ctx:
-                detail = (f" (step {ctx.get('step')}, "
-                          f"segment {ctx.get('segment')}, "
-                          f"host {ctx.get('host')}, "
-                          f"shard {ctx.get('path')})")
+            detail = ", ".join(f"{k} {v}" for k, v in (ctx or {}).items()
+                               if v is not None)
+            detail = f" ({detail})" if detail else ""
             raise RuntimeError(
                 f"background checkpoint write failed{detail}") from err
 

@@ -6,7 +6,9 @@ Host-side orchestrator that owns the running checkpoint and drives:
    score blocks (priority), update the in-memory running checkpoint
    (jitted, device-resident), and mirror the saved blocks to persistent
    storage. Training resumes as soon as the in-memory cache is updated;
-   the disk write is a background-able host callback (paper §4.3 step 4).
+   under ``policy.async_persist`` the disk writes (the saved blocks, then
+   the parity mirror) go to the store's background writer, one save in
+   flight (paper §4.3 step 4).
 2. *Recovery coordination* — on a detected failure (a lost block mask),
    partially (or fully) restore from the running checkpoint. If the
    in-memory replica itself was lost (total failure), reload from the
@@ -362,12 +364,18 @@ class FTController:
                     data = np.asarray(
                         self._ckpt_arena.reshape(-1, ARENA_TILE)[tiles])
                     sp.add_bytes(data.nbytes)
-                with self.recorder.span("scar/save/store_enqueue"):
+            if self.policy.async_persist:
+                # one save in flight: the previous save's writes land
+                # before this one queues its own, so the host holds at
+                # most two saves' payloads however slow the disk is
+                with self.recorder.span("scar/save/store_wait"):
+                    self.store.wait_writes()
+            with self.recorder.span("scar/save/store_enqueue"):
+                if self._arena_layout is not None:
                     self.stats["bytes_mirrored"] += self.store.write_arena(
                         mask_np, tiles, data, step,
                         background=self.policy.async_persist)
-            else:
-                with self.recorder.span("scar/save/store_enqueue"):
+                else:
                     self.stats["bytes_mirrored"] += self.store.write_blocks(
                         mask, self.ckpt.values, step,
                         background=self.policy.async_persist)
@@ -384,14 +392,17 @@ class FTController:
                     and self.fabric.parity.parity is not None
                     and hasattr(self.store, "write_parity")):
                 # mirror parity to disk: blocks whose domain shard died stay
-                # reconstructable offline from survivors + parity
+                # reconstructable offline from survivors + parity. Under
+                # async_persist the writer takes it after this save's
+                # shard write, from a host snapshot taken here
                 with self.recorder.span("scar/save/parity_to_host") as sp:
                     parity = np.asarray(self.fabric.parity.parity)
                     sp.add_bytes(parity.nbytes)
                 self.stats["bytes_mirrored"] += self.store.write_parity(
                     int(step), parity, self.fabric.parity.parity_homes,
                     domains=self.fabric.domains,
-                    members=self.fabric.parity.members)
+                    members=self.fabric.parity.members,
+                    background=self.policy.async_persist)
         return mask
 
     def _tree_select(self, step: int, params: PyTree, live) -> tuple:

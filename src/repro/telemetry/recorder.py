@@ -54,9 +54,10 @@ EVENT_SCHEMA: dict[str, tuple[str, ...]] = {
     "maintain": ("step", "mode", "bytes_moved", "replica", "parity"),
     "save":     ("step", "blocks", "bytes_moved", "seconds", "mode"),
     "mirror":   ("step", "bytes", "segments", "background", "queue_depth"),
-    "store_write_failed": ("step", "segment", "host", "path", "error"),
-    "store_write_retried": ("step", "segment", "host", "path", "error",
-                            "attempt", "delay_seconds"),
+    "store_write_failed": ("step", "segment", "host", "path", "write",
+                           "error"),
+    "store_write_retried": ("step", "segment", "host", "path", "write",
+                            "error", "attempt", "delay_seconds"),
     "tier_fallback": ("step", "group", "lost_members", "unavailable",
                       "strength", "fresh"),
     "silent_error_detected": ("step", "group", "error_kind", "member",

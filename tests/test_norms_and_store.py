@@ -148,3 +148,242 @@ def test_compact_drops_segments_of_missing_shards(tmp_path):
     for g in survivors:
         np.testing.assert_array_equal(arr[g * 4:(g + 1) * 4],
                                       np.asarray(params["w"])[g * 4:(g + 1) * 4])
+
+
+# ---------------------------------------------------------------------------
+# the parity mirror under async_persist: the store's background writer,
+# one save in flight
+# ---------------------------------------------------------------------------
+
+def _parity_ctl(root, async_persist, recorder=None):
+    """An arena controller with an elastic fabric and a domain-keyed
+    store, and its (drifted) live parameters."""
+    from repro.core.controller import FTController
+    from repro.core.policy import (CheckpointPolicy, RecoveryMode,
+                                   SelectionStrategy)
+    from repro.fabric import FabricConfig
+    rng = np.random.default_rng(7)
+    params = {"w": jnp.asarray(rng.normal(size=(50, 6)), jnp.float32),
+              "emb": jnp.asarray(rng.normal(size=(33, 8)), jnp.float32)}
+    pol = CheckpointPolicy(fraction=0.25, full_interval=1,
+                           strategy=SelectionStrategy.ROUND_ROBIN,
+                           recovery=RecoveryMode.PARTIAL, block_rows=16,
+                           async_persist=async_persist)
+    store = ShardedCheckpointStore(str(root))
+    ctl = FTController(params, pol, store=store, recorder=recorder,
+                       fabric=FabricConfig(elastic=True))
+    live = jax.tree_util.tree_map(lambda x: x + 0.5, params)
+    return ctl, live
+
+
+def _hold_writer(store):
+    """Hold the store's background writer before its next shard write
+    until the returned Event is set; everything queued behind waits."""
+    import threading
+    gate = threading.Event()
+    real = store._do_write
+
+    def held(jobs, step):
+        assert gate.wait(60), "writer never released"
+        return real(jobs, step)
+
+    store._do_write = held
+    return gate
+
+
+def _parity_as_saved(fab):
+    """What the save's mirror must hold: a copy of the parity, members
+    and homes taken right after the save returned."""
+    return (np.array(fab.parity.parity),
+            [[int(b) for b in row if b >= 0]
+             for row in np.asarray(fab.parity.members)],
+            np.asarray(fab.parity.parity_homes).tolist())
+
+
+@pytest.mark.parametrize("persist", ["sync", "background",
+                                     "background_restripe"])
+def test_parity_mirror_is_the_saves_snapshot(tmp_path, persist):
+    """Without async_persist the mirror is on disk when checkpoint_now
+    returns. With it, checkpoint_now returns while the writer is held;
+    once released and flushed the mirror holds that save's parity bit for
+    bit, with its step, members, homes and host keying as of the save,
+    even when a host loss re-stripes the codec in between."""
+    background = persist != "sync"
+    ctl, live = _parity_ctl(tmp_path, async_persist=background)
+    store, fab = ctl.store, ctl.fabric
+    gate = _hold_writer(store) if background else None
+    ctl.maintain(1, live)
+    ctl.checkpoint_now(1, live)
+    want, members, homes = _parity_as_saved(fab)
+    if background:
+        assert store.read_parity() is None      # queued, not written
+        if persist == "background_restripe":
+            live, _ = ctl.on_domain_event(live, "host", 0, step=1)
+            ctl.maintain(2, live)
+            now = _parity_as_saved(fab)
+            assert now[1] != members or now[2] != homes  # re-striped
+        gate.set()
+        store.flush()
+    parity, meta = store.read_parity()
+    assert meta["step"] == 1
+    assert parity.dtype == want.dtype
+    np.testing.assert_array_equal(parity, want)
+    assert meta["members"] == members and meta["parity_homes"] == homes
+    hosts = fab.domains.host_of(np.asarray(homes))
+    assert meta["paths"] == [f"host_{int(h):04d}/parity_{g:06d}.npy"
+                             for g, h in enumerate(hosts)]
+    assert not [f for _, _, fs in os.walk(str(tmp_path)) for f in fs
+                if f.endswith(".tmp")]
+
+
+@pytest.mark.parametrize("keyed", [False, True])
+def test_parity_background_write_failure_names_step_and_file(tmp_path,
+                                                             keyed):
+    """A background parity write that fails every retry surfaces on the
+    next flush(), with its step and the group file it was writing; the
+    error is one-shot."""
+    from repro.fabric.domains import FailureDomainMap
+    from repro.telemetry.recorder import Recorder
+    params = {"w": jnp.arange(96.0, dtype=jnp.float32).reshape(32, 3)}
+    part = partition_pytree(params, block_rows=8)
+    dm = FailureDomainMap(4, 2, 2)
+    homes = np.arange(part.total_blocks, dtype=np.int32) % 4
+    store = ShardedCheckpointStore(str(tmp_path))
+    store._retry_base_delay = 1e-4
+    rec = Recorder()
+    store.attach_recorder(rec)
+    store.init(params, part, **(dict(homes=homes, domains=dm) if keyed
+                                else {}))
+    parity = np.arange(3 * 5, dtype=np.int32).reshape(3, 5)
+    phomes = np.array([1, 2, 3], np.int32)
+    rel = (f"host_{int(dm.host_of(2)):04d}/parity_000001.npy" if keyed
+           else "parity_000001.npy")
+    bad = os.path.join(str(tmp_path), rel)
+    os.makedirs(bad + ".tmp")       # the group's temp file cannot be made
+    assert store.write_parity(9, parity, phomes, domains=dm,
+                              background=True) == parity.nbytes
+    with pytest.raises(RuntimeError, match="background checkpoint write") \
+            as ei:
+        store.flush()
+    msg = str(ei.value)
+    assert "step 9" in msg and bad in msg
+    assert "attempts" in str(ei.value.__cause__)
+    assert isinstance(ei.value.__cause__.__cause__, OSError)
+    failed = [e for e in rec.events if e["kind"] == "store_write_failed"]
+    assert len(failed) == 1
+    assert failed[0]["step"] == 9 and failed[0]["path"] == bad
+    assert failed[0]["write"] == "parity"
+    retried = [e for e in rec.events if e["kind"] == "store_write_retried"]
+    assert len(retried) == store._retry_limit
+    assert store.read_parity() is None       # PARITY.json never published
+    store.flush()                             # one-shot
+
+
+def test_second_save_waits_for_the_first_saves_writes(tmp_path):
+    """Under async_persist a save queues its writes only once the writer
+    has finished every write of the previous save: the wait is booked in
+    scar/save/store_wait, and each enqueue finds at most its own save's
+    earlier write still in flight."""
+    import threading
+    from repro.telemetry.recorder import Recorder
+    rec = Recorder()
+    ctl, live = _parity_ctl(tmp_path, async_persist=True, recorder=rec)
+    store = ctl.store
+    in_flight = []
+    real_put = store._q.put
+
+    def put(item, *a, **kw):
+        in_flight.append(store._q.unfinished_tasks)
+        return real_put(item, *a, **kw)
+
+    store._q.put = put
+    gate = _hold_writer(store)
+    ctl.maintain(1, live)
+    ctl.checkpoint_now(1, live)          # returns with the writer held
+    assert in_flight == [0, 1]           # its shard write, then its parity
+    held = 0.3
+    threading.Timer(held, gate.set).start()
+    ctl.maintain(2, live)
+    ctl.checkpoint_now(2, live)          # waits for save 1's two writes
+    assert in_flight[2] == 0 and max(in_flight) <= 1
+    waits = [s for s in rec.tracer.spans if s.name == "scar/save/store_wait"]
+    assert [s.step for s in waits] == [1, 2]
+    assert waits[1].duration >= held / 2 > 0
+    save = {s.sid: s for s in rec.tracer.spans}[waits[1].parent]
+    assert save.name == "scar/save" and save.step == 2
+    store.flush()
+    assert store.read_parity()[1]["step"] == 2
+    writes = [s for s in rec.tracer.spans
+              if s.name == "scar/store/parity_write"]
+    assert [s.step for s in writes] == [1, 2]
+    assert all(s.args["bytes"] == np.asarray(ctl.fabric.parity.parity).nbytes
+               for s in writes)
+
+
+@pytest.mark.parametrize("keyed", [False, True])
+@pytest.mark.parametrize("codec", ["xor", "rs"])
+def test_parity_background_and_inline_write_the_same_bytes(tmp_path, keyed,
+                                                           codec):
+    """The background mirror writes the same files, byte for byte, as the
+    inline one: every group file and PARITY.json, for XOR ``(groups, E)``
+    and RS ``(groups, m, E)`` parity, keyed by host or not."""
+    from repro.fabric.domains import FailureDomainMap
+    params = {"w": jnp.arange(96.0, dtype=jnp.float32).reshape(32, 3)}
+    part = partition_pytree(params, block_rows=8)
+    dm = FailureDomainMap(4, 2, 2)
+    homes = np.arange(part.total_blocks, dtype=np.int32) % 4
+    rng = np.random.default_rng(3)
+    if codec == "xor":
+        parity = rng.integers(-2**31, 2**31, (3, 5), dtype=np.int32)
+        phomes = np.array([1, 2, 3], np.int32)
+    else:
+        parity = rng.integers(0, 256, (3, 2, 5), dtype=np.uint8)
+        phomes = np.array([[1, 2], [2, 3], [3, 0]], np.int32)
+    members = np.array([[0, 1, -1], [2, 3, 0], [1, -1, -1]], np.int32)
+    trees = {}
+    for how in ("inline", "background"):
+        root = tmp_path / how
+        store = ShardedCheckpointStore(str(root))
+        store.init(params, part, **(dict(homes=homes, domains=dm) if keyed
+                                    else {}))
+        store.write_parity(4, parity, phomes, domains=dm, members=members,
+                           background=how == "background")
+        store.flush()
+        trees[how] = {
+            os.path.relpath(os.path.join(d, f), root):
+                open(os.path.join(d, f), "rb").read()
+            for d, _, fs in os.walk(root) for f in fs
+            if f.startswith("parity_") or f == "PARITY.json"}
+    assert len(trees["inline"]) == parity.shape[0] + 1
+    assert trees["background"] == trees["inline"]
+
+
+@pytest.mark.parametrize("fails", [False, True])
+def test_background_writer_drops_each_snapshot_once_written(tmp_path,
+                                                           fails):
+    """The writer frees a queued parity snapshot as soon as its write has
+    landed or failed, not when the next item arrives: the host holds no
+    payload of a finished save."""
+    import gc
+    import weakref
+    params = {"w": jnp.arange(96.0, dtype=jnp.float32).reshape(32, 3)}
+    part = partition_pytree(params, block_rows=8)
+    store = ShardedCheckpointStore(str(tmp_path))
+    store._retry_base_delay = 1e-4
+    store.init(params, part)
+    parity = np.arange(3 * 5, dtype=np.int32).reshape(3, 5)
+    if fails:
+        os.makedirs(os.path.join(str(tmp_path), "parity_000000.npy.tmp"))
+    store.write_parity(2, parity, np.array([1, 2, 3], np.int32),
+                       background=True)
+    ref = weakref.ref(parity)
+    del parity
+    store.wait_writes()
+    gc.collect()
+    assert ref() is None
+    if fails:
+        with pytest.raises(RuntimeError, match="step 2"):
+            store.flush()
+    else:
+        store.flush()
+        assert store.read_parity()[1]["step"] == 2

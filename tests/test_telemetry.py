@@ -391,7 +391,7 @@ def lm_runs(tmp_path_factory):
 def test_lm_loop_step_rollup(lm_runs):
     """Every step's record carries the span rollup; on save steps the
     save's host path is broken down, and no child outlasts its parent."""
-    loop, _, _ = lm_runs
+    loop, _, rec = lm_runs
     saves = [m for m in loop.metrics if m.get("checkpointed")]
     assert [m["step"] for m in saves] == [4, 8]
     for m in loop.metrics:
@@ -399,9 +399,10 @@ def test_lm_loop_step_rollup(lm_runs):
         sp = m["spans"]
         for name, sec in sp.items():
             assert name.startswith("scar/") and sec >= 0
-            # the background writer's span runs on its own thread, and
+            # the background writer's spans run on its own thread, and
             # may have started in an earlier step
-            if name not in ("scar/step", "scar/store/write"):
+            if name not in ("scar/step", "scar/store/write",
+                            "scar/store/parity_write"):
                 assert sec <= sp["scar/step"]
         for k in ("scar/step/train", "scar/step/fence", "scar/maintain"):
             assert k in sp
@@ -410,13 +411,19 @@ def test_lm_loop_step_rollup(lm_runs):
         kids = [k for k in sp if k.startswith("scar/save/")]
         assert set(kids) >= {"scar/save/select", "scar/save/scatter",
                              "scar/save/tiles_to_host",
+                             "scar/save/store_wait",
                              "scar/save/store_enqueue",
                              "scar/save/parity_to_host"}
-        assert sum(sp[k] for k in kids) + sp["scar/store/parity_write"] \
-            <= sp["scar/save"]
+        assert sum(sp[k] for k in kids) <= sp["scar/save"]
         assert m["bytes"]["scar/save/tiles_to_host"] > 0
-        assert m["bytes"]["scar/save/parity_to_host"] \
-            == m["bytes"]["scar/store/parity_write"] > 0
+        assert m["bytes"]["scar/save/parity_to_host"] > 0
+    # the parity mirror is the background writer's (async_persist): one
+    # write per save, of the bytes each save copied to the host
+    assert len(rec.tracer.durations("scar/store/parity_write")) \
+        == len(saves)
+    assert sum(m["bytes"].get("scar/store/parity_write", 0)
+               for m in loop.metrics) == sum(
+        m["bytes"]["scar/save/parity_to_host"] for m in saves) > 0
     fail = next(m for m in loop.metrics if "failures" in m)["spans"]
     assert sum(fail[k] for k in fail if k.startswith("scar/recovery/")) \
         <= fail["scar/recovery"]
@@ -449,7 +456,8 @@ def test_recorder_changes_only_what_is_kept(lm_runs):
             == np.asarray(kept.controller.ckpt.saved_iter)).all()
     def booked(m, d):
         # the background writer books into whichever step it lands in
-        return {k: v for k, v in m[d].items() if k != "scar/store/write"}
+        return {k: v for k, v in m[d].items()
+                if k not in ("scar/store/write", "scar/store/parity_write")}
 
     for a, b in zip(bare.metrics, kept.metrics):
         assert set(booked(a, "spans")) == set(booked(b, "spans"))
