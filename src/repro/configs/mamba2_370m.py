@@ -1,6 +1,8 @@
 """mamba2-370m [ssm] — SSD (state-space duality) [arXiv:2405.21060].
 
-48L d_model=1024 (attention-free) vocab=50280, ssm_state=128.
+48L d_model=1024 (attention-free) vocab=50280, ssm_state=128; head tied
+to the embedding as published (``tie_embeddings`` in
+state-spaces/mamba2-370m's config.json).
 """
 from repro.configs.base import ModelConfig, register
 
@@ -13,6 +15,7 @@ CONFIG = register(ModelConfig(
     n_kv_heads=0,
     d_ff=0,
     vocab=50280,
+    tie_embeddings=True,
     ssm_state=128,
     ssm_headdim=64,
     source="arXiv:2405.21060",

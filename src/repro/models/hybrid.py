@@ -69,16 +69,13 @@ def _slice_layers(layers: PyTree, start: int, length: int) -> PyTree:
 def train_loss(params, batch, cfg: ModelConfig, ctx: DistContext, **_):
     h = L.embed_tokens(batch["tokens"], params, ctx)
     h = ctx.shard(h, "dp", None, None)
-    Bsz, Sq = batch["tokens"].shape
+    Sq = batch["tokens"].shape[1]
     positions = jnp.arange(Sq)
 
+    layer = S.remat_layer_fwd(cfg)
+
     def mamba_body(x, lp):
-        fn = S.mixer_fwd
-        if cfg.remat:
-            fn = jax.checkpoint(S.mixer_fwd, static_argnums=(2, 3),
-                                policy=jax.checkpoint_policies.nothing_saveable)
-        x = x + fn(L.rms_norm(x, lp["norm"]), lp["mixer"], cfg, ctx)
-        return ctx.shard(x, "dp", ctx.tp, None), None
+        return ctx.shard(layer(x, lp, cfg, ctx), "dp", ctx.tp, None), None
 
     shared_call = lambda x: T._layer_fwd(x, params["shared"], cfg, ctx,
                                          positions, window=0, q_chunk=1024,
@@ -136,10 +133,7 @@ def decode_step(params, state, tokens, cfg: ModelConfig, ctx: DistContext,
     ssm = state["ssm"]
 
     def mamba_body(x, xs):
-        lp, hs, cs = xs
-        out, new = S.mixer_decode(L.rms_norm(x, lp["norm"]), lp["mixer"],
-                                  {"h": hs, "conv": cs}, cfg, ctx)
-        return x + out, (new["h"], new["conv"])
+        return S.layer_decode(x, *xs, cfg, ctx)
 
     new_h, new_conv, new_k, new_v = [], [], [], []
     lp_sh = params["shared"]
@@ -182,25 +176,11 @@ def prefill(params, batch, cfg: ModelConfig, ctx: DistContext, spec=None):
     tokens = batch["tokens"]
     h = L.embed_tokens(tokens, params, ctx)
     h = ctx.shard(h, "dp", None, None)
-    Bsz, Sq = tokens.shape
+    Sq = tokens.shape[1]
     positions = jnp.arange(Sq)
 
     def mamba_body(x, lp):
-        xn = L.rms_norm(x, lp["norm"])
-        p = lp["mixer"]
-        zxbcdt = jnp.einsum("bsd,de->bse", xn, p["in_proj"])
-        z, xi, Bm, Cm, dtr = S._split_proj(zxbcdt, cfg)
-        xi, conv_state = S._causal_conv(xi, p["conv_w"])
-        H, P = cfg.ssm_heads, cfg.ssm_headdim
-        xh = xi.reshape(Bsz, Sq, H, P).astype(jnp.float32)
-        dt = jax.nn.softplus(dtr.astype(jnp.float32) + p["dt_bias"])
-        A = -jnp.exp(p["A_log"])
-        y, h_fin = S.ssd_chunked(xh, dt, A, Bm.astype(jnp.float32),
-                                 Cm.astype(jnp.float32), cfg, ctx)
-        y = y + xh * p["D_skip"][:, None]
-        y = y.reshape(Bsz, Sq, cfg.d_inner).astype(x.dtype) * jax.nn.silu(z)
-        out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
-        return x + ctx.shard(out, "dp", None, None), (h_fin, conv_state)
+        return S.layer_prefill(x, lp, cfg, ctx)
 
     lp_sh = params["shared"]
     hs_all, conv_all, k_all, v_all = [], [], [], []
