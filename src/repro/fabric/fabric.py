@@ -179,14 +179,16 @@ class CheckpointFabric:
         if self.replicas is not None and self._arena_sharding is not None:
             self.replicas.main_sharding = self._arena_sharding
         self.last_maintained_step = -1
-        # fused maintenance programs: (re)built lazily against the view's
-        # current striping (see _fused_maintain_fn / _arena_maintain_fn)
-        self._fused_fn = None
-        self._fused_version = -1
-        self._arena_fn = None
-        self._arena_version = -1
+        # the maintenance programs and their traffic model are functions
+        # of the parity striping, not of the alive set: one entry per
+        # striping seen, built lazily (see _maintain_program). A loss or
+        # heal that leaves the striping as it was rebuilds nothing; an
+        # elastic re-stripe back to a striping seen before reuses its
+        # entry; resize_mesh, which replaces the arena layout, clears them
+        self._programs: dict[tuple, dict] = {}
+        # (key, view version) each program kind was last served under
+        self._program_served: dict[str, tuple] = {}
         self._pack_fn = None
-        self._traffic = None
         self.last_scores = None
         self.last_scores_step = -1
         # True once a maintain has been fed the live arena itself
@@ -229,6 +231,9 @@ class CheckpointFabric:
             "mesh_resizes": 0, "tier_fallbacks": 0,
             "rs_arena_encodes": 0, "scrubs": 0,
             "silent_errors_detected": 0, "silent_errors_corrected": 0,
+            # maintenance programs built, and served again from the cache
+            # after the view moved (a loss, heal or re-stripe)
+            "maintain_program_builds": 0, "maintain_program_reuses": 0,
             "arena_padding_ratio": 0.0,
             # which arena sweep the last built program runs ("pallas" or
             # "jnp") and, for "jnp", why the Pallas kernel is not eligible
@@ -624,38 +629,67 @@ class CheckpointFabric:
                                                self._arena_sharding)
         return self._pack_fn(ckpt_values)
 
-    def _arena_maintain_fn(self):
-        """The arena sweep program, rebuilt whenever the placement engine
-        re-striped since the last build."""
-        if self._arena_fn is None or self._arena_version != self.view.version:
-            from repro.kernels.fused_maintain.ops import ArenaMaintainProgram
-            # the host side of a rebuild: routing tables for the new
-            # striping (the program's trace and compile follow at its
-            # first call, in the caller's span)
+    def _program_entry(self) -> tuple[tuple, dict]:
+        """The cache key of what the maintenance programs and the traffic
+        model are built from, and its entry: the parity striping (the
+        codec's ``striping``, set once per cut), the arena's shard count
+        and the sweep's backend. The alive set is not part of it."""
+        key = (None if self.parity is None else self.parity.striping,
+               None if self.arena_layout is None
+               else self.arena_layout.shards,
+               self.cfg.use_pallas)
+        return key, self._programs.setdefault(key, {})
+
+    def _maintain_program(self, kind: str, build):
+        """The ``kind`` program for the current striping: built once per
+        striping (``build()`` under ``scar/maintain/build``), then served
+        from the cache. A call after the view moved that finds its
+        striping's program books a reuse."""
+        key, entry = self._program_entry()
+        prog = entry.get(kind)
+        served = (key, self.view.version)
+        if prog is None:
             with self.recorder.span("scar/maintain/build"):
-                self._arena_fn = ArenaMaintainProgram(
-                    self.partition, self.arena_layout, self.parity.layout,
-                    self.parity.group_of, self.parity.n_groups,
-                    use_pallas=self.cfg.use_pallas,
-                    out_sharding=self._arena_sharding)
-            self._arena_version = self.view.version
-            self._traffic = None
-            self.stats["arena_sweep"] = self._arena_fn.sweep
-            self.stats["arena_sweep_reason"] = self._arena_fn.sweep_reason
-        return self._arena_fn
+                prog = entry[kind] = build()
+            self.stats["maintain_program_builds"] += 1
+        elif self._program_served.get(kind) != served:
+            self.stats["maintain_program_reuses"] += 1
+        self._program_served[kind] = served
+        return prog
+
+    def _arena_maintain_fn(self):
+        """The arena sweep program for the parity's current striping,
+        built the first time that striping is swept. A view change that
+        keeps the striping (every loss and heal of a non-elastic fabric)
+        and an elastic re-stripe back to a striping seen before build
+        nothing."""
+        return self._maintain_program("arena", self._build_arena_program)
+
+    def _build_arena_program(self):
+        # the host side of a build: routing tables for the striping (the
+        # program's trace and compile follow at its first call, in the
+        # caller's span)
+        from repro.kernels.fused_maintain.ops import ArenaMaintainProgram
+        prog = ArenaMaintainProgram(
+            self.partition, self.arena_layout, self.parity.layout,
+            self.parity.group_of, self.parity.n_groups,
+            use_pallas=self.cfg.use_pallas,
+            out_sharding=self._arena_sharding)
+        self.stats["arena_sweep"] = prog.sweep
+        self.stats["arena_sweep_reason"] = prog.sweep_reason
+        return prog
 
     def _fused_maintain_fn(self):
-        """The jitted single-sweep program, rebuilt whenever the placement
-        engine re-striped since the last build (view.version moves on
-        every re-home/re-stripe/heal)."""
-        if self._fused_fn is None or self._fused_version != self.view.version:
-            from repro.kernels.fused_maintain.ops import make_fused_maintain_fn
-            self._fused_fn = make_fused_maintain_fn(
-                self.partition, self.parity.layout, self.parity.group_of,
-                self.parity.n_groups, use_pallas=self.cfg.use_pallas)
-            self._fused_version = self.view.version
-            self._traffic = None
-        return self._fused_fn
+        """The jitted per-leaf single-sweep program for the parity's
+        current striping, cached by the same rule as
+        :meth:`_arena_maintain_fn`."""
+        return self._maintain_program("fused", self._build_fused_program)
+
+    def _build_fused_program(self):
+        from repro.kernels.fused_maintain.ops import make_fused_maintain_fn
+        return make_fused_maintain_fn(
+            self.partition, self.parity.layout, self.parity.group_of,
+            self.parity.n_groups, use_pallas=self.cfg.use_pallas)
 
     def block_until_maintained(self) -> None:
         """Block until the last maintenance sweep's device work is done
@@ -707,8 +741,9 @@ class CheckpointFabric:
 
     def _traffic_model(self) -> dict[str, int]:
         """Analytic bytes per maintenance step under the current striping
-        (cached; placement changes invalidate)."""
-        if self._traffic is None:
+        (cached per striping, beside its programs)."""
+        _, entry = self._program_entry()
+        if "traffic" not in entry:
             model = sum(
                 int(np.prod(l.shape) if l.shape else 1)
                 * np.dtype(l.dtype).itemsize for l in self.partition.leaves)
@@ -727,8 +762,8 @@ class CheckpointFabric:
                      "parity": 0, "staging_seed": 0, "staging_fused": 0,
                      "parity_pass": 0}
             t["replica_pass"] = 2 * t["model"]
-            self._traffic = t
-        return self._traffic
+            entry["traffic"] = t
+        return entry["traffic"]
 
     def redundancy_state(self) -> dict:
         """Cheap per-step health snapshot of the redundancy tiers under
@@ -907,8 +942,8 @@ class CheckpointFabric:
         displaced = rehome_blocks(self.view)
         if self.arena_layout is not None:
             # arena mode: re-seed + re-stripe, then one arena sweep
-            # refreshes both tiers against the new striping (the program
-            # rebuild rides the view-version check)
+            # refreshes both tiers against the new striping (its program
+            # is looked up by the striping)
             self.replicas.reseed()
             self.parity.restripe()
             self._arena_maintain(step, params, None)
@@ -1123,9 +1158,8 @@ class CheckpointFabric:
         self.view.homes[:] = logical_ids[arena_block_homes(new_layout)]
         self.view.version += 1
         # every cached artifact below is laid out for the old shard count
-        self._arena_fn = None
+        self._programs.clear()
         self._pack_fn = None
-        self._traffic = None
         self._slots = [None, None]
         if self.replicas is not None:
             self.replicas.reseed()

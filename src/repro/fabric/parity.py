@@ -27,6 +27,7 @@ one-f32-image-per-element convention.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 from typing import Any, Optional
 
 import jax
@@ -180,6 +181,12 @@ class ParityCodec:
         self.valid = (self.members >= 0)
         # -1 members gather row 0 but are masked out by ``valid``
         self._gather_ids = np.where(self.valid, self.members, 0)
+        # what the fabric keys its maintenance programs on: it changes
+        # exactly when the groups do, and is computed here, once per cut,
+        # so a maintain only compares it
+        self.striping = (self.n_groups, self.members.shape[1],
+                         hashlib.blake2b(self.group_of.tobytes(),
+                                         digest_size=16).digest())
 
     def _build_encode(self) -> None:
         # encode runs every maintenance interval (the hot loop): fuse

@@ -313,6 +313,131 @@ def test_healing_readmits_and_reseeds():
 
 
 # ---------------------------------------------------------------------------
+# Maintenance programs: cached by the parity striping, not the view version
+# ---------------------------------------------------------------------------
+
+def _striping_params(seed):
+    # the striping is a function of the shapes alone (block homes by
+    # bytes); the values only give the sweeps something to encode
+    rng = np.random.default_rng(seed)
+    return {"w": jnp.asarray(rng.normal(size=(256, 6)), jnp.float32),
+            "b": jnp.asarray(rng.normal(size=(8,)), jnp.float32)}
+
+
+def _program_counts(fab):
+    return (fab.stats["maintain_program_builds"],
+            fab.stats["maintain_program_reuses"])
+
+
+def _sweep_outputs(fab, pipeline, live, z):
+    """One call of the fabric's cached program, and of one built afresh
+    from the codec's current striping, on the same values."""
+    from repro.kernels.fused_maintain.ops import (ArenaMaintainProgram,
+                                                  make_fused_maintain_fn)
+    codec = fab.parity
+    if pipeline == "arena":
+        fresh = ArenaMaintainProgram(fab.partition, fab.arena_layout,
+                                     codec.layout, codec.group_of,
+                                     codec.n_groups, use_pallas=False)
+        return fab._arena_maintain_fn()(live, z), fresh(live, z)
+    fresh = make_fused_maintain_fn(fab.partition, codec.layout,
+                                   codec.group_of, codec.n_groups,
+                                   use_pallas=False)
+    return fab._fused_maintain_fn()(live, z), fresh(live, z)
+
+
+@pytest.mark.parametrize("pipeline", ["arena", "fused"])
+def test_inplace_loss_and_heal_reuse_the_maintain_program(pipeline):
+    """Without ``elastic`` a loss and a heal move the view but never the
+    striping: the sweep program is built once, every maintain after a
+    view move reuses it, and each maintain's parity is bit-identical to a
+    plain ``ParityCodec.encode`` of the same values."""
+    params = _striping_params(0)
+    part = partition_pytree(params, 16)
+    fab = _fabric(part, elastic=False, arena=pipeline == "arena")
+    assert (fab.arena_layout is not None) == (pipeline == "arena")
+    ref = ParityCodec(part, ClusterView(fab.domains, fab.view.homes.copy()),
+                      group_size=fab.cfg.parity_group, use_pallas=False)
+    ckpt = init_running_checkpoint(params, part)
+
+    def maintain_and_check(step):
+        live = _noisy(params, seed=step)
+        fab.maintain(step, live, ckpt.values)
+        ref.encode(step, live)
+        np.testing.assert_array_equal(np.asarray(fab.parity.parity),
+                                      np.asarray(ref.parity))
+        return live
+
+    for step in (1, 2, 3):
+        live = maintain_and_check(step)
+    assert _program_counts(fab) == (1, 0)   # no view move: nothing to reuse
+    striping = fab.parity.striping
+    lost, failed = fab.domain_failure("host", 0)
+    fab.on_failure(live, ckpt.values, lost, failed, step=3,
+                   persist_failure=True)
+    assert not fab.view.alive[failed].any()
+    live = maintain_and_check(4)
+    fab.heal_domain("host", 0, live, step=4)
+    live = maintain_and_check(5)
+    lost, failed = fab.domain_failure("host", 2)
+    fab.on_failure(live, ckpt.values, lost, failed, step=5,
+                   persist_failure=True)
+    maintain_and_check(6)
+    maintain_and_check(7)
+    assert fab.parity.striping == striping
+    assert _program_counts(fab) == (1, 3)
+    swept = "arena_maintains" if pipeline == "arena" else "fused_maintains"
+    assert fab.stats[swept] == 7
+
+
+@pytest.mark.parametrize("pipeline", ["arena", "fused"])
+def test_elastic_restripe_builds_once_per_striping(pipeline):
+    """An elastic loss re-stripes and builds a program for the new
+    striping. The heal rebalances onto the restored host, which is a
+    striping of its own; from then on a loss and a heal of that host
+    alternate between two stripings already built, so they build nothing,
+    and the cached program's outputs are bit-identical to a program built
+    afresh for the striping in force."""
+    params = _striping_params(1)
+    part = partition_pytree(params, 16)
+    fab = _fabric(part, arena=pipeline == "arena")
+    ckpt = init_running_checkpoint(params, part)
+    live = _noisy(params, seed=1)
+    z = fab._as_arena(ckpt.values) if pipeline == "arena" else ckpt.values
+    fab.maintain(1, live, ckpt.values)
+    seen = [fab.parity.striping]
+    counts = [_program_counts(fab)]
+
+    def record():
+        seen.append(fab.parity.striping)
+        counts.append(_program_counts(fab))
+        cached, fresh = _sweep_outputs(fab, pipeline, live, z)
+        for a, b in zip(jax.tree_util.tree_leaves(cached),
+                        jax.tree_util.tree_leaves(fresh)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def cycle(step):
+        lost, failed = fab.domain_failure("host", 0)
+        fab.on_failure(live, ckpt.values, lost, failed, step=step)
+        if pipeline == "fused":      # the arena re-plan sweeps at once
+            fab.maintain(step, live, ckpt.values, force=True)
+        record()
+        fab.heal_domain("host", 0, live, step=step + 1)
+        fab.maintain(step + 1, live, ckpt.values, force=True)
+        record()
+
+    cycle(2)
+    cycle(4)
+    cycle(6)
+    assert len(set(seen[:3])) == 3
+    assert seen[3:] == seen[1:3] * 2
+    builds = [b for b, _ in counts]
+    assert builds == [1, 2, 3, 3, 3, 3, 3]
+    assert counts[-1][1] >= 4
+    _assert_elastic_invariants(fab)
+
+
+# ---------------------------------------------------------------------------
 # Trace-driven soak: classic runner + controller accounting
 # ---------------------------------------------------------------------------
 
