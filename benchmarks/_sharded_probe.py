@@ -69,8 +69,7 @@ def _bench(quick: bool) -> dict:
         ctl = loop.controller
         fab = ctl.fabric
         b0 = fab.stats["maintain_bytes_moved"] + ctl.stats["save_bytes_moved"]
-        m0 = max(fab.stats["arena_maintains"] + fab.stats["fused_maintains"],
-                 1)
+        m0 = fab.stats["arena_maintains"]
         i0, d0 = fab.stats["ici_bytes_moved"], fab.stats["dcn_bytes_moved"]
         t0 = time.perf_counter()
         state = loop.run(state, it, steps)
@@ -78,8 +77,7 @@ def _bench(quick: bool) -> dict:
         ms = loop.metrics[warm:]
         overhead_us = float(np.median(
             [m["overhead_seconds"] for m in ms])) * 1e6
-        n_maint = max(fab.stats["arena_maintains"]
-                      + fab.stats["fused_maintains"] - m0, 1)
+        n_maint = max(fab.stats["arena_maintains"] - m0, 1)
         out[name] = {
             "bytes_per_step":
                 (fab.stats["maintain_bytes_moved"]

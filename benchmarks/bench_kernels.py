@@ -1,4 +1,4 @@
-"""Kernel micro-benchmarks: us_per_call for the 4 Pallas kernels vs their
+"""Kernel micro-benchmarks: us_per_call for 3 Pallas kernels vs their
 pure-jnp oracles (interpret mode on CPU — relative numbers demonstrate the
 harness; absolute perf is a TPU question answered by §Roofline)."""
 from __future__ import annotations
@@ -8,8 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import csv_row, timed
-from repro.kernels.block_dist.kernel import block_dist_pallas
-from repro.kernels.block_dist.ref import block_dist_ref
 from repro.kernels.masked_restore.kernel import masked_restore_pallas
 from repro.kernels.masked_restore.ref import masked_restore_ref
 from repro.kernels.ssd_scan.kernel import ssd_intra_pallas
@@ -24,15 +22,6 @@ def run(trials: int = 3, quick: bool = False) -> list[str]:
 
     a = jnp.asarray(rng.normal(size=(64, 1024)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(64, 1024)), jnp.float32)
-    ref = jax.jit(block_dist_ref)
-    _, us_ref = timed(lambda: ref(a, b).block_until_ready(), repeats=trials)
-    _, us_krn = timed(lambda: block_dist_pallas(a, b, interpret=True
-                                                ).block_until_ready(),
-                      repeats=trials)
-    rows.append(csv_row("kernel_block_dist_ref", us_ref, "shape=64x1024"))
-    rows.append(csv_row("kernel_block_dist_pallas_interp", us_krn,
-                        "shape=64x1024"))
-
     m = jnp.asarray(rng.random(64) < 0.5)
     refm = jax.jit(masked_restore_ref)
     _, us_ref = timed(lambda: refm(a, b, m).block_until_ready(), repeats=trials)

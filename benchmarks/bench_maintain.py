@@ -1,4 +1,4 @@
-"""Beyond-paper: fused single-pass redundancy maintenance vs the seed path.
+"""Beyond-paper: single-pass arena maintenance vs the seed path.
 
 The paper's §4.3 constant-budget property (a fraction-r partial checkpoint
 writes the same bytes per C iterations as a full checkpoint) only holds if
@@ -12,8 +12,9 @@ Measured here, on the reduced qwen2 config (quick mode shrinks repeats,
 not the model):
 
   maint_sweep_*      — analytic HBM bytes + measured wall-clock per
-                       maintenance step, fused single sweep vs the seed
-                       three-pass path (both including PRIORITY scoring).
+                       maintenance step, the arena sweep (resident and
+                       packing a tree) vs the seed three-pass path (all
+                       including PRIORITY scoring).
   maint_sweep_quant  — word-level quantized arena: the reduced model's
                        redundancy bytes per sweep (replica + parity +
                        staging) and analytic bytes/step with every leaf
@@ -32,8 +33,8 @@ not the model):
                        rewrite (the §4.3 property, now true in memory).
   maint_store_packed — packed append-mode shard mirror: bytes appended per
                        partial save, live index bytes, compaction reclaim.
-  maint_kernel       — interpret-mode bit-exactness of the fused_maintain
-                       kernel vs its jnp oracles.
+  maint_arena_kernel — interpret-mode bit-exactness of the arena sweep
+                       kernel vs the tree-path oracles.
   e2e_step_maintain  — full trainer pipeline (train step + maintain +
                        partial save) on the reduced LM, PyTree-pack path
                        vs arena-resident training state: accounted
@@ -131,8 +132,7 @@ def _kernel_check_rows(quick: bool) -> list[str]:
     from repro.fabric.domains import FailureDomainMap
     from repro.fabric.placement import ClusterView
     from repro.fabric.parity import ParityCodec
-    from repro.kernels.fused_maintain.ops import (ArenaMaintainProgram,
-                                                  make_fused_maintain_fn)
+    from repro.kernels.fused_maintain.ops import ArenaMaintainProgram
     from repro.sharding.partition import block_device_homes
 
     rng = np.random.default_rng(5)
@@ -146,23 +146,8 @@ def _kernel_check_rows(quick: bool) -> list[str]:
                        block_device_homes(part, 8))
     codec = ParityCodec(part, view, group_size=3, use_pallas=False)
     codec.encode(0, params)
-    fn = make_fused_maintain_fn(part, codec.layout, codec.group_of,
-                                codec.n_groups, use_pallas=True,
-                                interpret=True)
-    (rep, sc, par), us = timed(
-        lambda: jax.block_until_ready(fn(params, ck)), repeats=2)
-    rep_ok = all(
-        bool((np.asarray(a) == np.asarray(b)).all())
-        for a, b in zip(jax.tree_util.tree_leaves(rep),
-                        jax.tree_util.tree_leaves(params)))
-    par_ok = bool((np.asarray(par) == np.asarray(codec.parity)).all())
     want_sc = np.asarray(block_scores(params, ck, part, get_norm("l2")))
-    sc_ok = bool(np.allclose(np.asarray(sc), want_sc, rtol=1e-5, atol=1e-5))
-    rows = [csv_row(
-        "maint_kernel", us,
-        f"replica_bit_exact={rep_ok};parity_bit_exact={par_ok};"
-        f"scores_match={sc_ok};blocks={part.total_blocks}")]
-    # interpret-mode arena sweep vs the same tree-path oracles: the whole
+    # interpret-mode arena sweep vs the tree-path oracles: the whole
     # model in ONE Pallas dispatch
     layout = build_arena_layout(part)
     prog = ArenaMaintainProgram(part, layout, codec.layout, codec.group_of,
@@ -176,67 +161,58 @@ def _kernel_check_rows(quick: bool) -> list[str]:
     apar_ok = bool((np.asarray(apar) == np.asarray(codec.parity)).all())
     asc_ok = bool(np.allclose(np.asarray(asc), want_sc,
                               rtol=1e-5, atol=1e-5))
-    rows.append(csv_row(
+    return [csv_row(
         "maint_arena_kernel", aus,
         f"replica_bit_exact={arep_ok};parity_bit_exact={apar_ok};"
-        f"scores_match={asc_ok};tiles={layout.n_tiles};dispatches=1"))
-    return rows
+        f"scores_match={asc_ok};tiles={layout.n_tiles};dispatches=1")]
 
 
-def _sweep_rows(params, quick: bool) -> tuple[list[str], dict]:
-    """Arena-resident vs arena-pack vs per-leaf-fused vs seed maintenance
-    sweep: analytic bytes + wall clock. ``arena_resident`` feeds the
-    sweep the live flat arena itself (the trainer default — pack-free,
-    pure 2-read/1-write); ``arena`` packs a live tree first (one pack +
-    ONE kernel dispatch); ``arena=False`` gives the per-leaf fused path
-    (one dispatch per leaf), ``fused=False`` the seed three-pass path."""
+def _sweep_rows(params, quick: bool) -> list[str]:
+    """Arena-resident vs arena-pack maintenance sweep vs the seed passes:
+    analytic bytes + wall clock. ``arena_resident`` feeds the sweep the
+    live flat arena itself (the trainer default — pack-free, pure
+    2-read/1-write); ``arena`` packs a live tree first (one pack + ONE
+    kernel dispatch); ``seed`` calls the seed's three passes directly
+    (``replicas.refresh``, ``parity.encode``, ``block_scores``)."""
+    from repro.core.arena import pack_arena
     part = partition_pytree(params, 128)
     ck_values = _drift(params)
     reps = 2 if quick else 4
     out = {}
     rows = []
-    variants = (("arena_resident", FabricConfig()),
-                ("arena", FabricConfig()),
-                ("fused", FabricConfig(arena=False)),
-                ("seed", FabricConfig(fused=False)))
-    for name, cfg in variants:
-        fab = CheckpointFabric(part, cfg)
-        ck_arg = ck_values
-        live_arg = params
-        if name in ("arena", "arena_resident"):
-            from repro.core.arena import pack_arena
-            pack = jax.jit(lambda t: pack_arena(t, fab.arena_layout))
-            ck_arg = pack(ck_values)
-            if name == "arena_resident":
-                # arena-resident live state: the sweep's input IS the
-                # flat arena — no pack inside the maintain at all
-                live_arg = pack(params)
-        fab.maintain(0, live_arg, ckpt_values=ck_arg, force=True)  # compile
+    for name in ("arena_resident", "arena", "seed"):
+        fab = CheckpointFabric(part, FabricConfig())
+        pack = jax.jit(lambda t: pack_arena(t, fab.arena_layout))
+        ck_arg = pack(ck_values)
+        # arena-resident live state: the sweep's input IS the flat arena
+        # — no pack inside the maintain at all
+        live_arg = pack(params) if name == "arena_resident" else params
+
+        def maintain(i):
+            if name != "seed":
+                fab.maintain(i, live_arg, ckpt_values=ck_arg, force=True)
+                return
+            fab.replicas.refresh(i, params)
+            fab.parity.encode(i, params)
+            # the seed scores separately: the third full pass the arena
+            # sweep folds in
+            jax.block_until_ready(
+                block_scores(params, ck_values, part, get_norm("l2")))
+
+        maintain(0)                                         # compile
         t0 = time.perf_counter()
         for i in range(1, reps + 1):
-            fab.maintain(i, live_arg, ckpt_values=ck_arg, force=True)
-            if name == "seed":
-                # the seed path scores separately (the third full pass the
-                # fused sweep folds in)
-                jax.block_until_ready(
-                    block_scores(params, ck_values, part, get_norm("l2")))
+            maintain(i)
         jax.block_until_ready(fab.parity.parity)
         wall_us = (time.perf_counter() - t0) / reps * 1e6
         t = fab._traffic_model()
-        bytes_step = {"arena_resident": t.get("arena_resident"),
-                      "arena": t.get("arena"), "fused": t["fused"],
-                      "seed": t["seed"]}[name]
-        staging = {"arena_resident": t.get("staging_arena"),
-                   "arena": t.get("staging_arena"),
-                   "fused": t["staging_fused"],
-                   "seed": t["staging_seed"]}[name]
-        out[name] = {"bytes": bytes_step, "us": wall_us, "staging": staging,
-                     "nbytes": fab.redundancy_nbytes()}
+        bytes_step = t[name]
+        staging = t["staging_seed" if name == "seed" else "staging_arena"]
+        out[name] = {"bytes": bytes_step, "us": wall_us}
         rows.append(csv_row(
             f"maint_sweep_{name}", wall_us,
             f"bytes_per_step={bytes_step};staging_bytes={staging};"
-            f"model_bytes={t['model']};fused_maintains="
-            f"{fab.stats['fused_maintains']};arena_maintains="
+            f"model_bytes={t['model']};arena_maintains="
             f"{fab.stats['arena_maintains']}"))
     # headline: the default (arena) path vs the seed path — the committed
     # floor the CI regression guard holds every run
@@ -244,14 +220,12 @@ def _sweep_rows(params, quick: bool) -> tuple[list[str], dict]:
     wall_ratio = out["seed"]["us"] / max(out["arena"]["us"], 1e-9)
     rows.append(csv_row(
         "maint_headline", 0.0,
-        f"bytes_ratio_seed_over_fused={ratio:.2f};"
+        f"bytes_ratio_seed_over_arena={ratio:.2f};"
         f"meets_2x={bool(ratio >= 2.0)};"
-        f"wall_ratio_seed_over_fused={wall_ratio:.2f};"
-        f"arena_wall_vs_leaf_fused="
-        f"{out['fused']['us'] / max(out['arena']['us'], 1e-9):.2f};"
+        f"wall_ratio_seed_over_arena={wall_ratio:.2f};"
         f"resident_bytes_vs_pack="
         f"{out['arena_resident']['bytes'] / max(out['arena']['bytes'], 1):.3f}"))
-    return rows, out
+    return rows
 
 
 def _padding_rows(params, quick: bool) -> list[str]:
@@ -890,7 +864,7 @@ def run(trials: int = 4, quick: bool = False,
         telemetry_out: str = "") -> list[str]:
     rows = _kernel_check_rows(quick)
     params = _reduced_params()
-    sweep_rows, _ = _sweep_rows(params, quick)
+    sweep_rows = _sweep_rows(params, quick)
     rows.extend(sweep_rows)
     rows.extend(_padding_rows(params, quick))
     rows.extend(_partial_save_rows(params, quick))

@@ -9,7 +9,7 @@ per-step ``pack_arena`` the arena-resident training state eliminated must
 stay eliminated. Wall-clock ratios are *recorded* alongside (CI machines
 are too noisy to gate on, but the trajectory should be visible in the job
 log and artifact), and the headline invariants (bit-exactness, the ≥2×
-seed-over-fused floor, near-r byte budget, the e2e bit-equality of the
+seed-over-arena floor, near-r byte budget, the e2e bit-equality of the
 arena-resident and PyTree training paths, the bit-equality of the async
 double-buffered maintenance pipeline against the sync path plus its
 overhead halving, and the SPMD rows' same-mesh loss bit-equality /
@@ -34,7 +34,6 @@ import sys
 GUARDED_BYTES = {
     "maint_sweep_arena_resident": "bytes_per_step",
     "maint_sweep_arena": "bytes_per_step",
-    "maint_sweep_fused": "bytes_per_step",
     "maint_sweep_sharded": "bytes_per_step",
     "maint_partial_save_inplace": "bytes_moved_per_save",
     "e2e_step_maintain_arena": "bytes_per_step",
@@ -53,9 +52,6 @@ CROSS_GUARDS = [
 # headline flags that must stay true on every run (exactness + analytic
 # byte floors only — deterministic on any machine)
 REQUIRED_FLAGS = [
-    ("maint_kernel", "replica_bit_exact=True"),
-    ("maint_kernel", "parity_bit_exact=True"),
-    ("maint_kernel", "scores_match=True"),
     ("maint_arena_kernel", "replica_bit_exact=True"),
     ("maint_arena_kernel", "parity_bit_exact=True"),
     ("maint_arena_kernel", "scores_match=True"),

@@ -2,7 +2,7 @@
 
 Trains a small classic model (multinomial logistic regression — one of the
 paper's §5 workloads), takes prioritized partial checkpoints through the
-**arena-resident** fault-tolerance path (the live params feed the fused
+**arena-resident** fault-tolerance path (the live params feed the arena
 maintenance sweep and the partial save as one flat arena — the default),
 kills half the parameters mid-training, partially recovers, and reports
 the measured iteration cost next to the Theorem 3.2 bound plus the

@@ -80,7 +80,7 @@ def select_save_mask(ckpt: RunningCheckpoint, params: PyTree, *,
                      scores: Optional[jnp.ndarray] = None) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Choose which blocks to save. Returns (mask, new_rr_cursor).
 
-    ``scores`` may be precomputed (e.g. by the Pallas block_dist kernel);
+    ``scores`` may be precomputed (e.g. by the fabric's arena sweep);
     otherwise they are computed with ``norm_fn`` for the PRIORITY strategy.
     """
     total = partition.total_blocks

@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import time
 from functools import partial
-from typing import Any, Callable, Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,7 +57,6 @@ class FTController:
     def __init__(self, params: PyTree, policy: CheckpointPolicy, *,
                  norm_aux: Optional[dict] = None,
                  store: Optional[Any] = None,
-                 score_fn: Optional[Callable] = None,
                  rng: Optional[jax.Array] = None,
                  colocate: tuple = (),
                  fabric: Optional[Any] = None,
@@ -91,7 +90,6 @@ class FTController:
         self._arena_score_live_jit = None
         self._ckpt = init_running_checkpoint(params, self.partition)
         self.store = store
-        self._score_fn = score_fn  # optional kernel-backed scorer
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
         # np generator for topology sampling, derived from the jax key
         # (key_data handles both legacy uint32 and typed key arrays)
@@ -131,11 +129,10 @@ class FTController:
         # the fabric's flat parameter arena — every partial save is ONE
         # donated tile scatter sourced from the maintenance sweep's
         # replica arena. Requires an arena-capable fabric, the in-place
-        # save, and (for PRIORITY) squared-L2 scoring — custom scorers
-        # and norms keep the tree-path save.
+        # save, and (for PRIORITY) squared-L2 scoring — other norms keep
+        # the tree-path save.
         if (inplace_save and self.fabric is not None
                 and getattr(self.fabric, "arena_layout", None) is not None
-                and score_fn is None
                 and (policy.strategy != SelectionStrategy.PRIORITY
                      or policy.norm == "l2")):
             from repro.core.arena import (arena_pack_program,
@@ -412,16 +409,14 @@ class FTController:
                               "checkpoint path (arena-capable fabric)")
         self._rng, sub = jax.random.split(self._rng)
         scores = None
-        if self.policy.strategy == SelectionStrategy.PRIORITY:
-            if self._score_fn is not None:
-                scores = self._score_fn(params, self.ckpt.values)
-            elif (self.fabric is not None
-                    and self.fabric.last_scores_step == int(step)
-                    and self.policy.norm == "l2"):
-                # this step's fused maintenance sweep already measured
-                # the drift vs the running checkpoint — reuse it
-                # instead of a third full read of params + ckpt
-                scores = self.fabric.last_scores
+        if (self.policy.strategy == SelectionStrategy.PRIORITY
+                and self.fabric is not None
+                and self.fabric.last_scores_step == int(step)
+                and self.policy.norm == "l2"):
+            # this step's maintenance sweep already measured the drift
+            # vs the running checkpoint — reuse it instead of a third
+            # full read of params + ckpt
+            scores = self.fabric.last_scores
         if not self.inplace_save:
             return sub, scores, None, None, None
         mask, cursor = self._jit_select(self.ckpt, params, rng=sub,
@@ -543,11 +538,11 @@ class FTController:
         """Per-iteration fabric upkeep (replica refresh / parity re-encode
         on their configured intervals). No-op without a fabric.
 
-        When the policy's PRIORITY selection can consume fused scores
-        (squared-L2 drift, no custom scorer), the running-checkpoint
-        values ride along so the fused sweep scores blocks in the same
-        read — the loops call maintain() *before* maybe_checkpoint() so a
-        same-step save reuses them.
+        When the policy's PRIORITY selection can consume the sweep's
+        scores (squared-L2 drift), the running-checkpoint values ride
+        along so the arena sweep scores blocks in the same read — the
+        loops call maintain() *before* maybe_checkpoint() so a same-step
+        save reuses them.
 
         ``params`` may be the live flat arena (arena-resident training
         state): the sweep then runs pack-free against it directly.
@@ -559,7 +554,6 @@ class FTController:
             return
         want_scores = (self.policy.strategy == SelectionStrategy.PRIORITY
                        and self.policy.norm == "l2"
-                       and self._score_fn is None
                        and self.should_checkpoint(int(step)))
         if not want_scores:
             ckpt_values = None

@@ -60,9 +60,7 @@ class FabricConfig:
     rs_parity: int = 0             # 0 = XOR codec; m >= 1 = RS(k, m) codec
                                    # with m GF(256) parity rows per group
     elastic: bool = False          # post-failure re-homing/re-seeding
-    fused: bool = True             # single-sweep maintenance pipeline
-    arena: bool = True             # flat-arena single-dispatch maintenance
-    async_maintain: bool = False   # double-buffered pipelined sweep
+    async_maintain: bool = False   # double-buffered pipelined arena sweep
     use_pallas: Optional[bool] = None   # None = auto: Pallas on TPU only
 
     def __post_init__(self):
@@ -74,11 +72,6 @@ class FabricConfig:
         if self.rs_parity < 0:
             raise ValueError("rs_parity must be >= 0 (0 selects the XOR "
                              "codec, m >= 1 the RS(k, m) codec)")
-        if self.async_maintain and not (self.fused and self.arena):
-            raise ValueError(
-                "async_maintain requires the fused arena pipeline "
-                "(fused=True, arena=True): the double-buffer snapshot and "
-                "deferred fence only exist for the single-dispatch sweep")
 
 
 class CheckpointFabric:
@@ -94,12 +87,11 @@ class CheckpointFabric:
                                         self.cfg.devices_per_host,
                                         self.cfg.hosts_per_rack)
         # flat parameter arena: the canonical hot-path representation —
-        # requires the single-sweep pipeline (``fused=False`` is the seed
-        # baseline), both tiers (the sweep's pack is the replica write,
-        # its XOR routing needs the parity striping), and word-packable
-        # leaf dtypes (f32/bf16/f16/fp8/int8… stored as raw bit patterns;
-        # only f64/int64/complex/bool gate — they fall back to the
-        # per-leaf fused path with a warn+event upstream). With a
+        # requires both tiers (the sweep's pack is the replica write, its
+        # XOR routing needs the parity striping) and word-packable leaf
+        # dtypes (f32/bf16/f16/fp8/int8… stored as raw bit patterns; only
+        # f64/int64/complex/bool gate — they fall back to the component
+        # passes with a warn+event upstream). With a
         # ``mesh`` the layout is built with one tile-aligned shard per
         # device (``shards=mesh size``) so every device owns a contiguous
         # span and the sweep runs shard-local (see arena.py "Sharded
@@ -108,8 +100,7 @@ class CheckpointFabric:
         # tile-divisible, so the flat optimizer sharding would not line
         # up with the word shards.
         self.arena_layout = None
-        if self.cfg.arena and self.cfg.fused and self.cfg.replicate \
-                and self.cfg.parity:
+        if self.cfg.replicate and self.cfg.parity:
             from repro.core.arena import arena_compatible, build_arena_layout
             uniform_f32 = all(np.dtype(l.dtype) == np.dtype(np.float32)
                               for l in partition.leaves)
@@ -120,6 +111,11 @@ class CheckpointFabric:
                     shards = int(np.asarray(mesh.devices).size)
                 self.arena_layout = build_arena_layout(partition,
                                                        shards=shards)
+        if self.cfg.async_maintain and self.arena_layout is None:
+            raise ValueError(
+                "async_maintain needs an arena layout (both tiers on and "
+                "word-packable leaf dtypes): the double-buffer snapshot and "
+                "deferred fence only exist for the arena sweep")
         # SPMD binding: mesh position i (row-major) IS fabric logical
         # device i, so the sharded arena's span owners line up with the
         # failure-domain map. Requires the mesh to cover the configured
@@ -140,10 +136,10 @@ class CheckpointFabric:
                     "failure domains map onto real devices)")
             if self.arena_layout is None:
                 raise ValueError(
-                    "a meshed fabric needs the sharded arena pipeline "
-                    "(arena=True, fused=True, both tiers, and an all-f32 "
-                    "model — quantized dtypes are single-host-arena only "
-                    "for now) — there is no sharded per-leaf fallback")
+                    "a meshed fabric needs the sharded arena layout: both "
+                    "tiers on and an all-f32 model (quantized dtypes are "
+                    "single-host-arena only for now) — the component "
+                    "passes have no sharded form")
             self._bind_mesh(mesh, np.arange(n, dtype=np.int32))
         if homes is not None:
             initial = np.asarray(homes, np.int32)
@@ -179,15 +175,16 @@ class CheckpointFabric:
         if self.replicas is not None and self._arena_sharding is not None:
             self.replicas.main_sharding = self._arena_sharding
         self.last_maintained_step = -1
-        # the maintenance programs and their traffic model are functions
-        # of the parity striping, not of the alive set: one entry per
-        # striping seen, built lazily (see _maintain_program). A loss or
-        # heal that leaves the striping as it was rebuilds nothing; an
-        # elastic re-stripe back to a striping seen before reuses its
-        # entry; resize_mesh, which replaces the arena layout, clears them
+        # the arena routing, the sweep program and the traffic model are
+        # functions of the parity striping, not of the alive set: one
+        # entry per striping seen, each part built lazily (see
+        # _program_entry). A loss or heal that leaves the striping as it
+        # was rebuilds nothing; an elastic re-stripe back to a striping
+        # seen before reuses its entry; resize_mesh, which replaces the
+        # arena layout, clears them
         self._programs: dict[tuple, dict] = {}
-        # (key, view version) each program kind was last served under
-        self._program_served: dict[str, tuple] = {}
+        # (key, view version) the sweep program was last served under
+        self._program_served: Optional[tuple] = None
         self._pack_fn = None
         self.last_scores = None
         self.last_scores_step = -1
@@ -223,7 +220,7 @@ class CheckpointFabric:
         self.stats = self.recorder.scope("fabric", {
             "replica_refreshes": 0, "parity_encodes": 0,
             "recoveries": 0, "rehomes": 0, "heals": 0,
-            "fused_maintains": 0, "arena_maintains": 0,
+            "arena_maintains": 0,
             "arena_resident_maintains": 0, "live_packs": 0,
             "async_maintains": 0, "fence_count": 0,
             "maintain_bytes_moved": 0,
@@ -333,14 +330,15 @@ class CheckpointFabric:
                  force: bool = False, own_live: bool = False) -> None:
         """Refresh redundancy tiers from live params (idempotent per step).
 
-        With ``cfg.fused`` (default) and both tiers due, the refresh runs
-        as one fused sweep (``kernels/fused_maintain``): each live leaf is
-        read once and yields the replica snapshot, the XOR parity frames,
-        and — when ``ckpt_values`` is passed — per-block PRIORITY scores
-        against the running checkpoint, cached on ``last_scores`` for the
-        controller's next partial save. Off-interval steps and
-        partial-tier configs fall back to the independent per-component
-        passes.
+        With an arena layout and both tiers due, the refresh runs as one
+        arena sweep (``kernels/fused_maintain``): the live values are read
+        once and yield the replica snapshot, the XOR parity frames, and —
+        when ``ckpt_values`` is passed — per-block PRIORITY scores against
+        the running checkpoint, cached on ``last_scores`` for the
+        controller's next partial save. Off-interval tree input and
+        fabrics without an arena layout (one tier off, or a leaf dtype
+        the arena cannot pack) run the component passes
+        (``replicas.refresh`` / ``parity.encode``).
 
         ``params`` may be the live flat arena itself (arena-resident
         training state, requires ``arena_layout``): the sweep then runs
@@ -398,9 +396,6 @@ class CheckpointFabric:
                                  own_live=own_live)
             mode = ("arena_resident" if self.live_arena_mode
                     and live is not None and not own_live else "arena")
-        elif self.cfg.fused and due_replica and due_parity:
-            self._fused_maintain(step, params, ckpt_values)
-            mode = "fused"
         else:
             t = self._traffic_model()
             if due_replica:
@@ -421,30 +416,6 @@ class CheckpointFabric:
                 ici_bytes=self.stats["ici_bytes_moved"] - i0,
                 dcn_bytes=self.stats["dcn_bytes_moved"] - d0,
                 replica=due_replica, parity=due_parity)
-
-    def _fused_maintain(self, step: int, params: PyTree,
-                        ckpt_values: Optional[PyTree]) -> None:
-        fn = self._fused_maintain_fn()
-        # without checkpoint values there is nothing to score against —
-        # the sweep still runs, diffing params against itself (zero
-        # scores, discarded), so the program stays one cached jit
-        z = ckpt_values if ckpt_values is not None else params
-        replica, scores, parity = fn(params, z)
-        self.replicas.ingest(step, replica)
-        if self.parity.needs_arena_encode:
-            # the sweep's XOR parity does not generalize to RS rows —
-            # re-encode from the live tree (per-leaf path has no arena)
-            self.parity.encode(step, params)
-        else:
-            self.parity.ingest(step, parity)
-        if ckpt_values is not None:
-            self.last_scores = scores
-            self.last_scores_step = step
-        self.stats["replica_refreshes"] += 1
-        self.stats["parity_encodes"] += 1
-        self.stats["fused_maintains"] += 1
-        self.stats["maintain_bytes_moved"] += self._traffic_model()["fused"]
-        self.published_epoch = int(step)
 
     def _arena_maintain(self, step: int, params: PyTree,
                         ckpt_values, own_live: bool = False) -> None:
@@ -482,7 +453,6 @@ class CheckpointFabric:
             self.last_scores_step = step
         self.stats["replica_refreshes"] += 1
         self.stats["parity_encodes"] += 1
-        self.stats["fused_maintains"] += 1
         self.stats["arena_maintains"] += 1
         if is_arena:
             # either way every maintain from here on is an arena sweep —
@@ -564,7 +534,6 @@ class CheckpointFabric:
         self._pending = {"step": int(step), "t0": t0, "span_t0": span_t0}
         self.stats["replica_refreshes"] += 1
         self.stats["parity_encodes"] += 1
-        self.stats["fused_maintains"] += 1
         self.stats["arena_maintains"] += 1
         self.stats["async_maintains"] += 1
         self.stats["maintain_bytes_moved"] += self._traffic_model()[
@@ -630,40 +599,46 @@ class CheckpointFabric:
         return self._pack_fn(ckpt_values)
 
     def _program_entry(self) -> tuple[tuple, dict]:
-        """The cache key of what the maintenance programs and the traffic
-        model are built from, and its entry: the parity striping (the
-        codec's ``striping``, set once per cut), the arena's shard count
-        and the sweep's backend. The alive set is not part of it."""
+        """The cache key of what the arena routing, the sweep program and
+        the traffic model are built from, and its entry: the parity
+        striping (the codec's ``striping``, set once per cut), the arena's
+        shard count and the sweep's backend. The alive set is not part of
+        it."""
         key = (None if self.parity is None else self.parity.striping,
                None if self.arena_layout is None
                else self.arena_layout.shards,
                self.cfg.use_pallas)
         return key, self._programs.setdefault(key, {})
 
-    def _maintain_program(self, kind: str, build):
-        """The ``kind`` program for the current striping: built once per
-        striping (``build()`` under ``scar/maintain/build``), then served
-        from the cache. A call after the view moved that finds its
-        striping's program books a reuse."""
-        key, entry = self._program_entry()
-        prog = entry.get(kind)
-        served = (key, self.view.version)
-        if prog is None:
-            with self.recorder.span("scar/maintain/build"):
-                prog = entry[kind] = build()
-            self.stats["maintain_program_builds"] += 1
-        elif self._program_served.get(kind) != served:
-            self.stats["maintain_program_reuses"] += 1
-        self._program_served[kind] = served
-        return prog
+    def _arena_routing(self):
+        """The current striping's arena routing, built once per striping
+        and read by both the sweep program and the traffic model."""
+        _, entry = self._program_entry()
+        if "routing" not in entry:
+            from repro.kernels.fused_maintain.ops import arena_routing
+            entry["routing"] = arena_routing(
+                self.arena_layout, self.parity.layout, self.parity.group_of)
+        return entry["routing"]
 
     def _arena_maintain_fn(self):
         """The arena sweep program for the parity's current striping,
-        built the first time that striping is swept. A view change that
-        keeps the striping (every loss and heal of a non-elastic fabric)
-        and an elastic re-stripe back to a striping seen before build
-        nothing."""
-        return self._maintain_program("arena", self._build_arena_program)
+        built the first time that striping is swept (under
+        ``scar/maintain/build``). A view change that keeps the striping
+        (every loss and heal of a non-elastic fabric) and an elastic
+        re-stripe back to a striping seen before build nothing; a call
+        after the view moved that finds its striping's program books a
+        reuse."""
+        key, entry = self._program_entry()
+        prog = entry.get("arena")
+        served = (key, self.view.version)
+        if prog is None:
+            with self.recorder.span("scar/maintain/build"):
+                prog = entry["arena"] = self._build_arena_program()
+            self.stats["maintain_program_builds"] += 1
+        elif self._program_served != served:
+            self.stats["maintain_program_reuses"] += 1
+        self._program_served = served
+        return prog
 
     def _build_arena_program(self):
         # the host side of a build: routing tables for the striping (the
@@ -674,22 +649,11 @@ class CheckpointFabric:
             self.partition, self.arena_layout, self.parity.layout,
             self.parity.group_of, self.parity.n_groups,
             use_pallas=self.cfg.use_pallas,
-            out_sharding=self._arena_sharding)
+            out_sharding=self._arena_sharding,
+            routing=self._arena_routing())
         self.stats["arena_sweep"] = prog.sweep
         self.stats["arena_sweep_reason"] = prog.sweep_reason
         return prog
-
-    def _fused_maintain_fn(self):
-        """The jitted per-leaf single-sweep program for the parity's
-        current striping, cached by the same rule as
-        :meth:`_arena_maintain_fn`."""
-        return self._maintain_program("fused", self._build_fused_program)
-
-    def _build_fused_program(self):
-        from repro.kernels.fused_maintain.ops import make_fused_maintain_fn
-        return make_fused_maintain_fn(
-            self.partition, self.parity.layout, self.parity.group_of,
-            self.parity.n_groups, use_pallas=self.cfg.use_pallas)
 
     def block_until_maintained(self) -> None:
         """Block until the last maintenance sweep's device work is done
@@ -741,7 +705,7 @@ class CheckpointFabric:
 
     def _traffic_model(self) -> dict[str, int]:
         """Analytic bytes per maintenance step under the current striping
-        (cached per striping, beside its programs)."""
+        (cached per striping, beside its routing and program)."""
         _, entry = self._program_entry()
         if "traffic" not in entry:
             model = sum(
@@ -749,18 +713,19 @@ class CheckpointFabric:
                 * np.dtype(l.dtype).itemsize for l in self.partition.leaves)
             if self.parity is not None:
                 from repro.kernels.fused_maintain.ops import maintain_traffic
+                arena = self.arena_layout is not None
                 t = dict(maintain_traffic(
-                    self.partition, self.parity.layout, self.parity.group_of,
-                    self.parity.n_groups, self.parity.members.shape[1],
-                    arena_layout=self.arena_layout))
+                    self.partition, self.parity.layout, self.parity.n_groups,
+                    self.parity.members.shape[1],
+                    arena_layout=self.arena_layout,
+                    routing=self._arena_routing() if arena else None))
                 # per-component splits for off-interval steps: the scoring
                 # pass (2·model) only happens at PRIORITY checkpoint time
                 # on the seed path, so it is excluded from both
                 t["parity_pass"] = t["seed"] - 4 * t["model"]
             else:
-                t = {"seed": 2 * model, "fused": 2 * model, "model": model,
-                     "parity": 0, "staging_seed": 0, "staging_fused": 0,
-                     "parity_pass": 0}
+                t = {"seed": 2 * model, "model": model, "parity": 0,
+                     "staging_seed": 0, "parity_pass": 0}
             t["replica_pass"] = 2 * t["model"]
             entry["traffic"] = t
         return entry["traffic"]
@@ -802,33 +767,24 @@ class CheckpointFabric:
 
     def redundancy_nbytes(self, store: Optional[Any] = None) -> dict[str, int]:
         """Real memory/disk footprint of the redundancy machinery: replica
-        and parity payloads, the parity codec's staging buffers (packed
-        frames + member gather on the seed path, compact per-leaf
-        contributions on the fused path — previously unaccounted), and,
-        when a persistent ``store`` is given, its on-disk shard bytes."""
+        and parity payloads, the parity staging buffers (the arena sweep's
+        compact outputs, or the seed encode's packed frames + member
+        gather), and, when a persistent ``store`` is given, its on-disk
+        shard bytes."""
         staging = 0
         if self.parity is not None:
-            # the fused sweep's compact staging applies only when every
-            # maintain actually takes the fused branch — mismatched tier
-            # intervals route off-interval steps through the seed encode,
-            # whose frames+gather footprint is the real peak. In
-            # live-arena mode that fallback no longer exists: every
-            # maintain (on- or off-interval) is the resident arena sweep,
-            # so neither the seed frames+gather staging nor the pack's
-            # snapshot write ever materializes — only the sweep's compact
-            # outputs count, whatever the tier intervals are.
-            all_fused = ((self.cfg.fused or self.arena_layout is not None)
-                         and self.cfg.replicate
-                         and self.cfg.replicate_interval
-                         == self.cfg.parity_interval)
-            if self.live_arena_mode:
-                staging = self._traffic_model()["staging_arena"]
-            elif not all_fused:
-                staging = self.parity.staging_nbytes()
-            elif self.arena_layout is not None:
+            # the arena sweep's compact staging applies only when every
+            # maintain takes the sweep: in live-arena mode every maintain
+            # (on- or off-interval) is the resident sweep; with tree input
+            # mismatched tier intervals route off-interval steps through
+            # the seed encode, whose frames+gather footprint is the peak
+            all_arena = self.live_arena_mode or (
+                self.arena_layout is not None
+                and self.cfg.replicate_interval == self.cfg.parity_interval)
+            if all_arena:
                 staging = self._traffic_model()["staging_arena"]
             else:
-                staging = self._traffic_model()["staging_fused"]
+                staging = self.parity.staging_nbytes()
         out = {
             "replica": self.replicas.nbytes() if self.replicas else 0,
             "parity": self.parity.nbytes() if self.parity else 0,

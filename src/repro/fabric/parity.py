@@ -133,7 +133,7 @@ class ParityCodec:
     """
 
     # single parity row per group; the RS subclass raises both. The
-    # fused arena sweep emits XOR parity directly (needs_arena_encode
+    # arena sweep emits XOR parity directly (needs_arena_encode
     # False); codecs that must re-encode from the snapshot arena set it.
     n_parity = 1
     needs_arena_encode = False
@@ -209,8 +209,8 @@ class ParityCodec:
         self.encoded_step = int(step)
 
     def ingest(self, step: int, parity: jnp.ndarray) -> None:
-        """Adopt a parity buffer encoded elsewhere (the fused maintenance
-        sweep XOR-folds leaf bit patterns straight into group frames —
+        """Adopt a parity buffer encoded elsewhere (the arena maintenance
+        sweep XOR-folds arena tiles straight into group frames —
         bit-identical to :meth:`encode` under the same striping)."""
         self.parity = parity
         self.encoded_step = int(step)
@@ -236,9 +236,9 @@ class ParityCodec:
         """Peak staging footprint of one seed-path :meth:`encode`: the
         packed ``(total_blocks, frame_elems)`` bit-pattern buffer plus the
         ``(n_groups, width, frame_elems)`` member gather the XOR fold
-        consumes. The fused maintenance path replaces both with compact
-        per-leaf contributions (see ``kernels/fused_maintain``); callers
-        accounting real memory overhead must include whichever applies."""
+        consumes. The arena maintenance sweep replaces both with compact
+        parity tiles (see ``kernels/fused_maintain``); callers accounting
+        real memory overhead must include whichever applies."""
         frames = self.partition.total_blocks * self.layout.frame_elems * 4
         gathered = int(self.members.size) * self.layout.frame_elems * 4
         return frames + gathered

@@ -12,8 +12,8 @@ no disk), cheap enough to run every iteration, so a replica-recovered block
 is restored to its *live* value: zero perturbation in the Thm 4.1
 accounting (see DESIGN.md).
 
-The snapshot lives in one of two forms: a PyTree (the seed/per-leaf fused
-paths) or a flat **parameter arena** (:mod:`repro.core.arena`) ingested by
+The snapshot lives in one of two forms: a PyTree (the seed component
+pass) or a flat **parameter arena** (:mod:`repro.core.arena`) ingested by
 the arena maintenance sweep — the canonical hot-path form. Recovery reads
 whichever is present; ``values`` materializes a tree from the arena on
 demand (recovery-path only, never the hot loop).
@@ -56,14 +56,6 @@ class ReplicaSet:
     def refresh(self, step: int, params: PyTree) -> None:
         """Snapshot live params into the replicas (device copy)."""
         self._tree = jax.tree_util.tree_map(jnp.array, params)
-        self._arena = None
-        self.refreshed_step = int(step)
-
-    def ingest(self, step: int, values: PyTree) -> None:
-        """Adopt a snapshot already produced elsewhere (the fused
-        maintenance sweep emits the replica copy in the same pass that
-        encodes parity — no second read of the live params)."""
-        self._tree = values
         self._arena = None
         self.refreshed_step = int(step)
 

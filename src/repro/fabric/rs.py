@@ -50,7 +50,7 @@ class RSCodec(ParityCodec):
 
     ``group_size`` is k (data members per group, subject to the same
     topology clamp and tail-fold as the XOR codec); ``n_parity`` is m.
-    The fused arena sweep only emits XOR parity, so this codec re-encodes
+    The arena sweep only emits XOR parity, so this codec re-encodes
     its rows from the snapshot arena each maintenance
     (``needs_arena_encode``) — m extra MAC passes over the frame bytes.
     """

@@ -1,38 +1,35 @@
-"""Pallas TPU kernels: fused single-pass redundancy maintenance.
+"""Pallas TPU kernels: single-pass redundancy maintenance over the arena.
 
-The checkpoint fabric's hot loop previously made three-plus independent
-full passes over the live parameters every maintained step: a full-tree
+The checkpoint fabric's hot loop once made three-plus independent full
+passes over the live parameters every maintained step: a full-tree
 replica copy, a pack-into-frames + gather + XOR parity encode (two
 materialized full-model intermediates), and a third full read for PRIORITY
-block scoring. Both kernels here collapse that to the memory-roofline
-floor:
+block scoring. The kernels here collapse that to the memory-roofline
+floor over the flat parameter arena (``core/arena.py``):
 
-``fused_maintain`` — one sweep per parameter leaf that reads each element
-of the live leaf (and its running-checkpoint counterpart) from HBM exactly
-once and, in that single pass,
+``arena_maintain`` — ONE dispatch for the whole model. The grid walks the
+arena's ``(8, 128)`` f32 tiles in an order sorted by parity destination,
+driven by three scalar-prefetched routing tables: ``perm`` (the tile
+visited at each step), ``dest`` (its compact parity tile) and ``first``
+(seed vs fold). All tiles that XOR into one parity tile arrive on
+consecutive grid steps, so the parity output tile is revisit-accumulated
+in VMEM; each step also emits a squared-L2 score partial for PRIORITY
+selection. No ``(total_blocks, frame_width)`` packed intermediate and no
+``(n_groups, g, E)`` gather buffer ever exists. The kernel is an
+aligned-tile f32 program: the fabric runs it only on uniform-f32 layouts
+without a tail region and without a mesh, and the identical-output jnp
+word sweep everywhere else (``ArenaMaintainProgram.sweep`` says which).
 
-  (a) writes the replica snapshot (plain copy, original dtype),
-  (b) XOR-accumulates the leaf's float32 bit-pattern rows directly into
-      compact per-group parity frames — no ``(total_blocks, frame_width)``
-      packed intermediate and no ``(n_groups, g, E)`` gather buffer ever
-      exists, and
-  (c) emits per-block squared-L2 distance partials for PRIORITY selection.
+``arena_scatter`` — the whole-model partial save as one donated tile
+scatter: the running-checkpoint arena is aliased as the output and the
+grid walks only the selected tiles.
 
-Layout: the grid is ``(E_tiles, S)`` — element tiles *outer*, blocks
-*inner* — and the block axis is driven by three scalar-prefetched arrays:
-``perm`` visits the leaf's blocks sorted by parity group, so all members
-of one group arrive on consecutive grid steps and the parity output block
-can be revisit-accumulated in VMEM (init on ``first``, XOR otherwise)
-exactly like ``block_dist``'s running sum; ``outrow`` maps each sorted
-position to its compact parity row. Replica rows and score partials are
-written back through the inverse map so they land in natural block order.
-
-``scatter_save`` — donation-based in-place partial-checkpoint write: the
-running checkpoint buffer is aliased as the output and the grid walks only
-the ``k`` selected blocks (scalar-prefetched row ids), so saving ``k``
-blocks moves ``O(k · block_bytes)`` — never the full leaf. Unvisited rows
-are never DMA'd and keep their previous contents (the §4.3 running
-checkpoint is a mutable mix of iterations by construction).
+``scatter_save`` — the same in-place write over one leaf's raw row matrix
+(the tree-path save): the grid walks only the ``k`` selected blocks
+(scalar-prefetched row ids), so saving ``k`` blocks moves
+``O(k · block_bytes)`` — never the full leaf. Unvisited rows are never
+DMA'd and keep their previous contents (the §4.3 running checkpoint is a
+mutable mix of iterations by construction).
 """
 from __future__ import annotations
 
@@ -48,12 +45,6 @@ SCORE_SLOTS = 8 * LANES   # per-step scores held by one (8, 128) output tile
 # grid steps per call of a sweep whose routing is scalar-prefetched: three
 # int32 tables of this length take 384 KiB of the 1 MiB of SMEM
 SMEM_STEPS = 1 << 15
-
-
-def _sublanes(dtype) -> int:
-    """Rows of one native ``(rows, 128)`` VMEM tile for ``dtype``: 8 for
-    32-bit, 16 for 16-bit elements."""
-    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
 def _put_score(sc_ref, s, val):
@@ -80,95 +71,6 @@ def _put_score(sc_ref, s, val):
 def _score_rows(n_steps: int) -> int:
     """Rows of the lane-dense score output for ``n_steps`` grid steps."""
     return -(-max(n_steps, 1) // SCORE_SLOTS) * 8
-
-
-# ---------------------------------------------------------------------------
-# fused_maintain: replica copy + parity XOR + priority scores, one read
-# ---------------------------------------------------------------------------
-
-def _fused_maintain_kernel(perm_ref, outrow_ref, first_ref, x_ref, z_ref,
-                           rep_ref, sc_ref, par_ref):
-    s = pl.program_id(1)
-    x = x_ref[...]                               # (sub, 128), leaf dtype
-    rep_ref[...] = x                             # (a) replica snapshot
-    x32 = x.astype(jnp.float32)
-    d = x32 - z_ref[...].astype(jnp.float32)
-    _put_score(sc_ref, s, jnp.sum(d * d))        # (c) score partial
-    bits = jax.lax.bitcast_convert_type(x32, jnp.int32)
-
-    @pl.when(first_ref[s] == 1)
-    def _init():                                 # (b) first member: seed
-        par_ref[...] = bits
-
-    @pl.when(first_ref[s] == 0)
-    def _fold():                                 # (b) later member: fold
-        par_ref[...] ^= bits
-
-
-def fused_maintain_pallas(x: jnp.ndarray, z: jnp.ndarray,
-                          perm: jnp.ndarray, outrow: jnp.ndarray,
-                          first: jnp.ndarray, n_out_rows: int,
-                          interpret: bool = False,
-                          ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One fused maintenance sweep over a leaf's block view.
-
-    x, z:    (S, E) live leaf view / running-checkpoint view (same shapes).
-    perm:    (S,) int32 — block ids sorted by parity group (group members
-             consecutive; within a group any order).
-    outrow:  (S,) int32 — compact parity row of sorted position s.
-    first:   (S,) int32 — 1 where s is the first sorted position of its row.
-    n_out_rows — number of distinct parity rows (static).
-
-    Each block row is retiled as ``(E / 128, 128)`` and swept in native
-    ``(sub, 128)`` tiles (``sub`` = 8 for f32, 16 for 16-bit dtypes).
-
-    Returns (replica (S, E) x.dtype, scores (S,) f32,
-    parity_contrib (n_out_rows, E) int32 — XOR of the f32 bit patterns of
-    each row's member blocks).
-    """
-    s_dim, e = x.shape
-    sub = _sublanes(x.dtype)
-    e_pad = -e % (sub * LANES)
-    if e_pad:
-        x = jnp.pad(x, ((0, 0), (0, e_pad)))
-        z = jnp.pad(z, ((0, 0), (0, e_pad)))
-    ep = x.shape[1]
-    jt = ep // (sub * LANES)
-    x3 = x.reshape(s_dim, ep // LANES, LANES)
-    z3 = z.reshape(s_dim, ep // LANES, LANES)
-    tile = (None, sub, LANES)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(jt, s_dim),                        # E tiles OUTER: parity row
-        in_specs=[                               # revisits stay consecutive
-            pl.BlockSpec(tile, lambda j, s, p, o, f: (p[s], j, 0)),
-            pl.BlockSpec(tile, lambda j, s, p, o, f: (p[s], j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(tile, lambda j, s, p, o, f: (p[s], j, 0)),
-            pl.BlockSpec((None, 8, LANES),
-                         lambda j, s, p, o, f: (j, s // SCORE_SLOTS, 0)),
-            pl.BlockSpec(tile, lambda j, s, p, o, f: (o[s], j, 0)),
-        ],
-    )
-    rep, sc, par = pl.pallas_call(
-        _fused_maintain_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(x3.shape, x.dtype),
-            jax.ShapeDtypeStruct((jt, _score_rows(s_dim), LANES),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((n_out_rows, ep // LANES, LANES),
-                                 jnp.int32),
-        ],
-        interpret=interpret,
-    )(perm, outrow, first, x3, z3)
-    # per-step partials are in sorted order: sum the E tiles, then undo
-    # the group sort so scores come back in natural block order
-    sc_sorted = jnp.sum(sc.reshape(jt, -1)[:, :s_dim], axis=0)
-    scores = jnp.zeros((s_dim,), jnp.float32).at[perm].set(sc_sorted)
-    return (rep.reshape(s_dim, ep)[:, :e], scores,
-            par.reshape(n_out_rows, ep)[:, :e])
 
 
 # ---------------------------------------------------------------------------
@@ -262,7 +164,7 @@ def arena_maintain_pallas(x2d: jnp.ndarray, z2d: jnp.ndarray,
            XOR-ing into one parity tile arrive consecutively).
     dest:  (T,) int32 — compact parity output tile per sorted step.
     first: (T,) int32 — 1 at the first step of its destination (seed vs
-           fold, exactly the per-leaf kernel's revisit accumulation).
+           fold: the parity tile is revisit-accumulated in VMEM).
 
     The routing tables are scalar-prefetched into SMEM, which holds
     ``SMEM_STEPS`` steps of them; a longer sweep is cut at destination

@@ -1,15 +1,18 @@
-"""Tree-level drivers for the fused_maintain kernel family.
+"""Drivers for the fused_maintain kernel family.
 
-``make_fused_maintain_fn`` builds the fabric's hot-loop program: one jitted
-function ``(params, ckpt_values) -> (replica_tree, scores, parity)`` that
-reads each live leaf once and produces all three maintenance outputs. The
-host-side group metadata (sorted block order, compact parity rows, member
-matrices) is precomputed per parity striping and baked into the program —
-rebuilt by the fabric whenever the placement engine re-stripes.
+``ArenaMaintainProgram`` is the fabric's maintenance program: one jitted
+sweep over the flat parameter arena that reads the live values once and
+yields the replica snapshot, the XOR parity and the PRIORITY scores. Its
+host-side routing (``arena_routing``: every arena tile's parity
+destination) is a function of the parity striping alone; the fabric
+builds it once per striping and hands it to the program and to the
+analytic traffic model (``maintain_traffic``).
 
-``tree_scatter_save`` is the checkpoint-side counterpart: a donation-based
-in-place partial save that moves only the selected blocks' bytes into the
-running checkpoint instead of rewriting every leaf through ``jnp.where``.
+``arena_scatter_save`` and ``tree_scatter_save`` are the checkpoint-side
+counterparts: donation-based in-place partial saves that move only the
+selected blocks' bytes into the running checkpoint (one dispatch over the
+arena, one per touched leaf over a tree) instead of rewriting every leaf
+through ``jnp.where``.
 
 Backend contract matches the other kernel packages: compiled Pallas on
 TPU, the jnp path elsewhere (interpret-mode Pallas is for validation
@@ -19,153 +22,21 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Callable, Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.blocks import (BlockPartition, leaf_block_view,
-                               leaf_block_words, leaf_view_shape)
+from repro.core.blocks import BlockPartition, leaf_view_shape
 from repro.fabric.parity import FrameLayout
-from repro.kernels.fused_maintain.kernel import (fused_maintain_pallas,
-                                                 scatter_save_pallas)
+from repro.kernels.fused_maintain.kernel import scatter_save_pallas
 
 PyTree = Any
 
 
 def _is_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-# ---------------------------------------------------------------------------
-# Host-side group metadata (static per parity striping)
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class LeafGroupMeta:
-    """Per-leaf routing tables for the fused sweep (numpy, host-resident)."""
-    perm: np.ndarray        # (S,) block ids sorted by parity group
-    outrow: np.ndarray      # (S,) compact parity row per sorted position
-    first: np.ndarray       # (S,) 1 at the first sorted position of its row
-    touched: np.ndarray     # (n_out,) global group ids, ascending
-    members: np.ndarray     # (n_out, m_hat) local block ids, -1 padded
-    col: int                # column of this leaf's payload in the frame
-    width: int              # payload width (int32 words)
-
-
-def leaf_group_metas(partition: BlockPartition, layout: FrameLayout,
-                     group_of: np.ndarray) -> list[LeafGroupMeta]:
-    """Build each leaf's routing tables from the codec's group assignment."""
-    group_of = np.asarray(group_of, np.int32)
-    metas = []
-    for leaf, col, width in zip(partition.leaves, layout.cols, layout.widths):
-        gids = group_of[leaf.offset:leaf.offset + leaf.n_blocks]
-        assert (gids >= 0).all(), \
-            f"leaf {leaf.name}: blocks outside any parity group"
-        order = np.argsort(gids, kind="stable").astype(np.int32)
-        touched, inverse = np.unique(gids, return_inverse=True)
-        outrow = inverse.astype(np.int32)[order]
-        first = np.ones_like(outrow)
-        first[1:] = (outrow[1:] != outrow[:-1]).astype(np.int32)
-        m_hat = int(np.bincount(outrow).max())
-        members = np.full((touched.size, m_hat), -1, np.int32)
-        fill = np.zeros((touched.size,), np.int64)
-        for pos, row in zip(order, outrow):
-            members[row, fill[row]] = pos
-            fill[row] += 1
-        metas.append(LeafGroupMeta(perm=order, outrow=outrow, first=first,
-                                   touched=touched.astype(np.int32),
-                                   members=members, col=int(col),
-                                   width=int(width)))
-    return metas
-
-
-# ---------------------------------------------------------------------------
-# Fused maintenance program
-# ---------------------------------------------------------------------------
-
-def _leaf_sweep_pallas(x, z, meta: LeafGroupMeta, block_rows: int,
-                       interpret: bool):
-    xv = leaf_block_view(x, block_rows)
-    zv = leaf_block_view(z.astype(x.dtype), block_rows)
-    return fused_maintain_pallas(xv, zv, jnp.asarray(meta.perm),
-                                 jnp.asarray(meta.outrow),
-                                 jnp.asarray(meta.first),
-                                 n_out_rows=int(meta.touched.size),
-                                 interpret=interpret)
-
-
-def _leaf_sweep_jnp(x, z, meta: LeafGroupMeta, block_rows: int):
-    """jnp fast path: same outputs, one compact gather+fold per leaf —
-    never the (total_blocks, frame_width) packed buffer of the seed path.
-    Scores diff f32 views of the values (what ``block_scores`` does);
-    the parity contribution is the leaf's raw bit-packed words."""
-    xv = leaf_block_view(x.astype(jnp.float32), block_rows)
-    zv = leaf_block_view(z.astype(jnp.float32), block_rows)
-    scores = jnp.sum((xv - zv) ** 2, axis=1)
-    bits = leaf_block_words(x, block_rows)
-    idx = jnp.asarray(meta.members)
-    valid = idx >= 0
-    gathered = bits[jnp.where(valid, idx, 0)]        # (n_out, m_hat, E)
-    contrib = jax.lax.reduce(jnp.where(valid[..., None], gathered, 0),
-                             jnp.int32(0), jax.lax.bitwise_xor, (1,))
-    replica = jax.tree_util.tree_map(jnp.array, x)
-    return replica, scores, contrib
-
-
-def make_fused_maintain_fn(partition: BlockPartition, layout: FrameLayout,
-                           group_of: np.ndarray, n_groups: int,
-                           use_pallas: Optional[bool] = None,
-                           interpret: Optional[bool] = None,
-                           ) -> Callable[[PyTree, PyTree], tuple]:
-    """Build the jitted single-sweep maintenance program.
-
-    Returns ``fn(params, ckpt_values) -> (replica_tree, scores, parity)``
-    where ``scores`` is the (total_blocks,) squared-L2 drift vs the
-    running checkpoint (colocated leaves accumulate, like
-    :func:`repro.core.blocks.block_scores`) and ``parity`` is the
-    (n_groups, frame_elems) int32 XOR parity — bit-identical to
-    :meth:`ParityCodec.encode`'s result under the same striping.
-    """
-    if use_pallas is None:
-        use_pallas = _is_tpu()
-    if interpret is None:
-        interpret = not _is_tpu()
-    metas = leaf_group_metas(partition, layout, group_of)
-    br = partition.block_rows
-
-    def _maintain(params: PyTree, ckpt_values: PyTree):
-        flat = jax.tree_util.tree_leaves(params)
-        zflat = jax.tree_util.tree_leaves(ckpt_values)
-        scores = jnp.zeros((partition.total_blocks,), jnp.float32)
-        parity = jnp.zeros((n_groups, layout.frame_elems), jnp.int32)
-        replicas = []
-        for x, z, leaf, meta in zip(flat, zflat, partition.leaves, metas):
-            # the Pallas leaf kernel is an element-width f32 program; for
-            # word-packed dtypes (bf16/fp8/int8 — element count != word
-            # count) the jnp word path computes the same outputs
-            if use_pallas and np.dtype(leaf.dtype) == np.dtype(np.float32):
-                rep_v, sc, contrib = _leaf_sweep_pallas(x, z, meta, br,
-                                                        interpret)
-                rows = max(leaf.rows, 1)
-                rep = rep_v.reshape(-1, max(leaf.row_width, 1))[:rows]
-                rep = rep.reshape(leaf.shape)
-            else:
-                rep, sc, contrib = _leaf_sweep_jnp(x, z, meta, br)
-            replicas.append(rep)
-            scores = jax.lax.dynamic_update_slice(
-                scores, jax.lax.dynamic_slice(
-                    scores, (leaf.offset,), (leaf.n_blocks,)) + sc,
-                (leaf.offset,))
-            rows = jnp.asarray(meta.touched)
-            cols = slice(meta.col, meta.col + meta.width)
-            parity = parity.at[rows, cols].set(parity[rows, cols] ^ contrib)
-        replica_tree = jax.tree_util.tree_unflatten(partition.treedef,
-                                                    replicas)
-        return replica_tree, scores, parity
-
-    return jax.jit(_maintain)
 
 
 # ---------------------------------------------------------------------------
@@ -192,8 +63,8 @@ def arena_routing(arena_layout, frame_layout: FrameLayout,
     ``group_of[gid]`` at columns ``cols[l] + k·ARENA_TILE`` — whole tiles
     because the frame layout is arena-tile aligned. Sorting tiles by
     destination makes every parity output tile's contributors consecutive
-    grid steps (seed on ``first``, XOR-fold after), exactly the per-leaf
-    kernel's revisit accumulation but across the entire model at once.
+    grid steps (seed on ``first``, XOR-fold after) across the entire
+    model at once.
 
     Tail-packed blocks (word-granular, tile-sharing) are *not* routed
     here — :class:`ArenaMaintainProgram` XOR-folds their payload words
@@ -272,12 +143,17 @@ class ArenaMaintainProgram:
     training state): the pack disappears entirely and the sweep is the
     pure 2-read/1-write pass — read live + checkpoint arenas, write the
     replica copy + compact outputs. Outputs are bit-identical to the
-    pack path on the same values (``pack ∘ unpack`` is the identity)."""
+    pack path on the same values (``pack ∘ unpack`` is the identity).
+
+    ``routing`` is the striping's :func:`arena_routing`, when the caller
+    already holds it (the fabric builds it once per striping); without
+    it the program builds its own."""
 
     def __init__(self, partition: BlockPartition, arena_layout,
                  frame_layout: FrameLayout, group_of: np.ndarray,
                  n_groups: int, use_pallas: Optional[bool] = None,
-                 interpret: Optional[bool] = None, out_sharding=None):
+                 interpret: Optional[bool] = None, out_sharding=None,
+                 routing: Optional[ArenaRouting] = None):
         from repro.core.arena import (ARENA_TILE, arena_drift_scores,
                                       pack_arena)
         if use_pallas is None:
@@ -298,8 +174,9 @@ class ArenaMaintainProgram:
         self.sweep = "pallas" if use_pallas else "jnp"
         self.sweep_reason = ", ".join(reasons)
         self.layout = arena_layout
-        self.routing = arena_routing(arena_layout, frame_layout, group_of)
-        r = self.routing
+        if routing is None:
+            routing = arena_routing(arena_layout, frame_layout, group_of)
+        self.routing = r = routing
         total = partition.total_blocks
         n_dest = int(r.touched.size)
         full_tiles = n_groups * r.frame_tiles
@@ -642,24 +519,25 @@ def _tree_nbytes(partition: BlockPartition) -> int:
 
 
 def maintain_traffic(partition: BlockPartition, layout: FrameLayout,
-                     group_of: np.ndarray, n_groups: int,
-                     group_width: int, arena_layout=None) -> dict[str, int]:
+                     n_groups: int, group_width: int, arena_layout=None,
+                     routing: Optional[ArenaRouting] = None,
+                     ) -> dict[str, int]:
     """Analytic HBM bytes moved by one full maintenance step (replica
-    refresh + parity encode + priority scoring), seed path vs fused path.
+    refresh + parity encode + priority scoring): the seed's component
+    passes and, given the arena layout and its striping's ``routing``,
+    the arena sweep.
 
     The seed path reads the live tree once per pass (replica copy, frame
     pack, score) plus writes/reads two full-model staging buffers (the
     packed ``(total_blocks, frame_elems)`` frames and the
-    ``(n_groups, g, E)`` gather); the fused path reads the live tree and
-    the checkpoint once, writes the replica, and touches only the compact
-    per-leaf parity contributions.
+    ``(n_groups, g, E)`` gather); the arena sweep reads the live and
+    checkpoint arenas once each and touches only compact parity tiles and
+    per-tile score partials besides the replica write.
     """
     model = _tree_nbytes(partition)
     frames = partition.total_blocks * layout.frame_elems * 4
     gathered = n_groups * group_width * layout.frame_elems * 4
     parity = n_groups * layout.frame_elems * 4
-    metas = leaf_group_metas(partition, layout, group_of)
-    contrib = sum(m.touched.size * m.width * 4 for m in metas)
     seed = (
         model + model            # replica: read live + write replica
         + model + frames         # pack_frames: read live + write frames
@@ -667,15 +545,8 @@ def maintain_traffic(partition: BlockPartition, layout: FrameLayout,
         + gathered + parity      # encode: read grouped + write parity
         + model + model          # block_scores: read live + read ckpt
     )
-    fused = (
-        model + model            # one sweep: read live + read ckpt
-        + model                  # write replica
-        + contrib                # write compact parity contributions
-        + 2 * contrib + parity   # combine: read contribs, rmw parity cols
-    )
-    out = {"seed": int(seed), "fused": int(fused), "model": int(model),
-           "parity": int(parity), "staging_seed": int(frames + gathered),
-           "staging_fused": int(contrib)}
+    out = {"seed": int(seed), "model": int(model), "parity": int(parity),
+           "staging_seed": int(frames + gathered)}
     if arena_layout is not None:
         # arena path: the pack (read live + write the arena snapshot) IS
         # the replica refresh; the single-dispatch sweep then reads the
@@ -684,11 +555,10 @@ def maintain_traffic(partition: BlockPartition, layout: FrameLayout,
         # scatters the compact tiles into the codec parity layout
         from repro.core.arena import ARENA_TILE
         a = arena_layout.nbytes
-        r = arena_routing(arena_layout, layout, group_of)
         tail_words = sum(ab.payload for ab in arena_layout.blocks
                          if ab.offset >= arena_layout.tail_start) \
             if arena_layout.has_tail else 0
-        compact = int(r.touched.size) * ARENA_TILE * 4 + tail_words * 4
+        compact = int(routing.touched.size) * ARENA_TILE * 4 + tail_words * 4
         partials = arena_layout.n_tiles * 4
         out["arena_bytes"] = int(a)
         # pad words / live payload words: the alignment overhead tail

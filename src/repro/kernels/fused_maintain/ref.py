@@ -1,29 +1,8 @@
-"""Pure-jnp oracles for the fused_maintain kernel family."""
+"""Pure-numpy oracles for the fused_maintain kernel family."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-def fused_maintain_ref(x: jnp.ndarray, z: jnp.ndarray,
-                       outrow_per_block: np.ndarray, n_out_rows: int,
-                       ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Oracle for one leaf sweep: (replica copy, per-block squared-L2
-    scores, per-row XOR of the blocks' float32 bit patterns).
-
-    ``outrow_per_block[b]`` is the compact parity row block ``b`` folds
-    into (natural block order, unlike the kernel's sorted ``perm``/
-    ``outrow`` encoding).
-    """
-    x32 = x.astype(jnp.float32)
-    z32 = z.astype(jnp.float32)
-    scores = jnp.sum((x32 - z32) ** 2, axis=1)
-    bits = np.asarray(jax.lax.bitcast_convert_type(x32, jnp.int32))
-    par = np.zeros((n_out_rows, x.shape[1]), np.int32)
-    for b, row in enumerate(np.asarray(outrow_per_block)):
-        par[int(row)] ^= bits[b]
-    return jnp.array(x), scores, jnp.asarray(par)
 
 
 def arena_maintain_ref(x2d: jnp.ndarray, z2d: jnp.ndarray,

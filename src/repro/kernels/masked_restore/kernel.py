@@ -6,7 +6,7 @@ select avoids materializing a full-size expanded boolean mask (the jnp
 path builds a (rows, 1)-broadcast bool per leaf) and performs exactly one
 HBM read per input element and one write — memory-roofline optimal.
 
-Grid/layout identical to block_dist: (n_blocks, E) tiles of (BB, BE);
+Grid/layout: (n_blocks, E) tiles of (BB, BE), the element axis inner;
 the mask rides along the i axis as a (BB, 1) int32 column block (a
 rank-1 (BB,) block is not a legal TPU block shape).
 """

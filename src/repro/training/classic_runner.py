@@ -147,7 +147,7 @@ def run_with_failure(model: IterativeModel, policy: CheckpointPolicy, *,
     maint_seconds = 0.0
     for i in range(1, max_iters + 1):
         p = model.step(p, key(i), i)
-        # maintain before the checkpoint: the fused sweep's PRIORITY
+        # maintain before the checkpoint: the arena sweep's PRIORITY
         # scores are measured against the pre-save running checkpoint
         t0 = time.perf_counter()
         # pack only on iterations whose maintain/save reads the live

@@ -431,7 +431,7 @@ class TrainLoop:
         rec = {"step": int(state.step), "loss": loss, "seconds": dt}
         if self.controller is None:
             return state, rec
-        # maintain first: the fused maintenance sweep scores the blocks
+        # maintain first: the arena maintenance sweep scores the blocks
         # against the running checkpoint in the same read, and a
         # same-step partial save below reuses those scores
         tm0 = time.perf_counter()
@@ -590,7 +590,7 @@ class TrainLoop:
                                      if fab is not None else 0.0)
         if self.controller is not None and self.controller.fabric is not None:
             fab = self.controller.fabric
-            # one parity encode per maintained step (fused or not) under
+            # one parity encode per maintained step (swept or not) under
             # the default same-interval tiers — the per-step denominator
             maintains = max(fab.stats["parity_encodes"], 1)
             out["maintain_bytes_per_step"] = (

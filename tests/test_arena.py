@@ -13,7 +13,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.arena import (ARENA_TILE, ArenaLayout, arena_compatible,
-                              arena_restore, build_arena_layout,
+                              arena_drift_scores, arena_restore,
+                              build_arena_layout,
                               frames_from_arena, frames_gather_index,
                               pack_arena, unpack_arena)
 from repro.core.blocks import (block_scores, partition_pytree, select_blocks,
@@ -123,7 +124,7 @@ def test_arena_compatible_gates_dtypes():
     assert arena_compatible(good)
     assert not arena_compatible(bad)
     fab = CheckpointFabric(bad, FabricConfig())
-    assert fab.arena_layout is None             # falls back to per-leaf
+    assert fab.arena_layout is None             # component passes
 
 
 # ---------------------------------------------------------------------------
@@ -217,6 +218,37 @@ def test_arena_maintain_matches_tree_reference(use_pallas):
     np.testing.assert_array_equal(np.asarray(rep2), np.asarray(rep))
     np.testing.assert_array_equal(np.asarray(par2), np.asarray(par))
     assert not np.asarray(sc2).any()
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (5, 100), (8, 512), (33, 777),
+                                   (128, 2048), (7, 4096)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_arena_drift_scores_sweep(shape, dtype):
+    """The sweep's word scorer against ``block_scores`` on one leaf of
+    ``shape`` cut into 4-row blocks, beside a small leaf of the other
+    dtype (a mixed-dtype arena, as mamba2's is)."""
+    other = jnp.float32 if dtype == jnp.bfloat16 else jnp.bfloat16
+    tree = {"x": jnp.asarray(RNG.normal(size=shape), dtype),
+            "y": jnp.asarray(RNG.normal(size=(3, 5)), other)}
+    ref = _drift(tree)
+    part = partition_pytree(tree, 4)
+    lay = build_arena_layout(part)
+    got = arena_drift_scores(pack_arena(tree, lay), pack_arena(ref, lay),
+                             lay)
+    want = block_scores(tree, ref, part, get_norm("l2"))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_arena_drift_scores_zero_distance():
+    tree = {"x": jnp.asarray(RNG.normal(size=(16, 300)), jnp.float32),
+            "y": jnp.asarray(RNG.normal(size=(9, 7)), jnp.bfloat16)}
+    part = partition_pytree(tree, 4)
+    lay = build_arena_layout(part)
+    a = pack_arena(tree, lay)
+    got = np.asarray(arena_drift_scores(a, a, lay))
+    assert got.shape == (part.total_blocks,)
+    assert not got.any()
 
 
 def test_arena_maintain_colocated_leaves():

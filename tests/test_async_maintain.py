@@ -31,7 +31,7 @@ from repro.core.controller import FTController
 from repro.core.policy import (CheckpointPolicy, RecoveryMode,
                                SelectionStrategy)
 from repro.data.pipeline import ShardedLMDataset
-from repro.fabric import FabricConfig
+from repro.fabric import CheckpointFabric, FabricConfig
 from repro.models.classic import make_model
 from repro.sharding import single_device_ctx
 from repro.telemetry.recorder import Recorder
@@ -74,12 +74,31 @@ def _controller(model, async_maintain: bool, elastic: bool = False,
 # config gate + traffic model
 # ---------------------------------------------------------------------------
 
+def _async_part(dtype=np.float32):
+    # np arrays: jnp would silently downcast f64 -> f32 without x64 mode
+    return partition_pytree({"w": np.zeros((32, 4), dtype)}, 8)
+
+
 def test_async_config_requires_fused_arena():
+    """The pipelined sweep exists only for the arena layout: a fabric
+    with a tier off builds none and refuses ``async_maintain`` at
+    construction; the default configuration takes it."""
+    for kw in (dict(replicate=False), dict(parity=False)):
+        with pytest.raises(ValueError, match="async_maintain"):
+            CheckpointFabric(_async_part(),
+                             FabricConfig(async_maintain=True, **kw))
+    fab = CheckpointFabric(_async_part(), FabricConfig(async_maintain=True))
+    assert fab.arena_layout is not None
+
+
+def test_async_config_requires_word_packable_dtypes():
+    """Both tiers on, but a leaf dtype the arena cannot pack: no layout,
+    so ``async_maintain`` is refused too."""
     with pytest.raises(ValueError, match="async_maintain"):
-        FabricConfig(async_maintain=True, fused=False)
-    with pytest.raises(ValueError, match="async_maintain"):
-        FabricConfig(async_maintain=True, arena=False)
-    FabricConfig(async_maintain=True)   # default pipeline is eligible
+        CheckpointFabric(_async_part(np.float64),
+                         FabricConfig(async_maintain=True))
+    assert CheckpointFabric(_async_part(np.float64),
+                            FabricConfig()).arena_layout is None
 
 
 def test_async_traffic_is_resident_plus_snapshot():

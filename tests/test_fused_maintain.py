@@ -1,4 +1,5 @@
-"""Fused single-pass maintenance: kernels, tree drivers, fabric + controller
+"""Single-pass maintenance: the arena sweep against the seed oracles, the
+component passes of fabrics without an arena layout, fabric + controller
 integration, and the donation-based in-place partial save.
 
 Kernels run in interpret=True mode on CPU (the kernel body executes in
@@ -12,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.arena import build_arena_layout, pack_arena
 from repro.core.blocks import (block_scores, partition_pytree, select_blocks,
                                tree_sq_norm)
 from repro.core.checkpoint import init_running_checkpoint
@@ -22,12 +24,11 @@ from repro.fabric import CheckpointFabric, FabricConfig
 from repro.fabric.domains import FailureDomainMap
 from repro.fabric.parity import ParityCodec
 from repro.fabric.placement import ClusterView
-from repro.kernels.fused_maintain.kernel import (fused_maintain_pallas,
-                                                 scatter_save_pallas)
-from repro.kernels.fused_maintain.ref import (fused_maintain_ref,
-                                              scatter_save_ref)
-from repro.kernels.fused_maintain.ops import (leaf_group_metas,
-                                              make_fused_maintain_fn,
+from repro.fabric.replica import ReplicaSet
+from repro.kernels.fused_maintain.kernel import scatter_save_pallas
+from repro.kernels.fused_maintain.ref import scatter_save_ref
+from repro.kernels.fused_maintain.ops import (ArenaMaintainProgram,
+                                              arena_routing,
                                               maintain_traffic,
                                               tree_scatter_save)
 from repro.sharding.partition import block_device_homes
@@ -62,26 +63,31 @@ def _codec(params, part, group_size=3):
 
 @pytest.mark.parametrize("shape", [(1, 1), (5, 100), (8, 512), (13, 777)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_fused_maintain_kernel_sweep(shape, dtype):
-    s, e = shape
-    x = jnp.asarray(RNG.normal(size=shape), dtype)
-    z = jnp.asarray(RNG.normal(size=shape), dtype)
-    group_of = RNG.integers(0, max(s // 2, 1), (s,))
-    order = np.argsort(group_of, kind="stable").astype(np.int32)
-    touched, inverse = np.unique(group_of, return_inverse=True)
-    outrow = inverse.astype(np.int32)[order]
-    first = np.ones_like(outrow)
-    first[1:] = (outrow[1:] != outrow[:-1]).astype(np.int32)
-    rep, sc, par = fused_maintain_pallas(
-        x, z, jnp.asarray(order), jnp.asarray(outrow), jnp.asarray(first),
-        n_out_rows=int(touched.size), interpret=True)
-    want_rep, want_sc, want_par = fused_maintain_ref(
-        x, z, inverse, int(touched.size))
-    np.testing.assert_array_equal(np.asarray(rep), np.asarray(want_rep))
-    np.testing.assert_array_equal(np.asarray(par), np.asarray(want_par))
-    tol = 1e-5 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(np.asarray(sc), np.asarray(want_sc),
-                               rtol=tol, atol=tol)
+def test_arena_maintain_leaf_sweep(shape, dtype):
+    """One leaf of ``shape`` (a block per row) through the arena sweep:
+    the replica is the packed live values, the parity is bit-equal to the
+    seed encode and the scores match ``block_scores``. Both layouts: the
+    default tail-packed one (the jnp word sweep) and the tile-aligned one
+    (the Pallas kernel in interpret mode, where the leaf is f32)."""
+    tree = {"x": jnp.asarray(RNG.normal(size=shape), dtype)}
+    ck = {"x": jnp.asarray(RNG.normal(size=shape), dtype)}
+    part = partition_pytree(tree, 1)
+    codec = _codec(tree, part, group_size=2)
+    want = block_scores(tree, ck, part, get_norm("l2"))
+    for tail_pack in (True, False):
+        lay = build_arena_layout(part, tail_pack=tail_pack)
+        prog = ArenaMaintainProgram(part, lay, codec.layout, codec.group_of,
+                                    codec.n_groups, use_pallas=True,
+                                    interpret=True)
+        aligned_f32 = not lay.has_tail and dtype == jnp.float32
+        assert prog.sweep == ("pallas" if aligned_f32 else "jnp")
+        rep, sc, par = prog(tree, pack_arena(ck, lay))
+        np.testing.assert_array_equal(np.asarray(rep),
+                                      np.asarray(pack_arena(tree, lay)))
+        np.testing.assert_array_equal(np.asarray(par),
+                                      np.asarray(codec.parity))
+        np.testing.assert_allclose(np.asarray(sc), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("shape,block_rows", [((50, 6), 16), ((7, 3), 4),
@@ -108,73 +114,23 @@ def test_scatter_save_kernel_duplicate_rows_idempotent():
 
 
 # ---------------------------------------------------------------------------
-# tree-level drivers vs the seed-path oracles
+# traffic model
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_tree_fused_maintain_matches_oracles(use_pallas):
-    params = _params()
-    ck = jax.tree_util.tree_map(
-        lambda x: x + jnp.asarray(RNG.normal(size=x.shape), x.dtype), params)
-    part = partition_pytree(params, 16)
-    codec = _codec(params, part)
-    fn = make_fused_maintain_fn(part, codec.layout, codec.group_of,
-                                codec.n_groups, use_pallas=use_pallas,
-                                interpret=True)
-    rep, sc, par = fn(params, ck)
-    _tree_equal(rep, params)                               # replica == copy
-    np.testing.assert_array_equal(np.asarray(par),         # parity bit-exact
-                                  np.asarray(codec.parity))
-    want = block_scores(params, ck, part, get_norm("l2"))
-    np.testing.assert_allclose(np.asarray(sc), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_tree_fused_maintain_colocated_leaves():
-    """Colocated leaves share block ids: scores accumulate per group and
-    every colocated payload folds into the same parity rows at its own
-    frame columns — exactly like the seed pack_frames/encode path."""
-    tree = {"net": {"w": jnp.asarray(RNG.normal(size=(16, 3)), jnp.float32)},
-            "mu": {"w": jnp.asarray(RNG.normal(size=(16, 3)), jnp.float32)},
-            "t": jnp.float32(1.0)}
-    ck = jax.tree_util.tree_map(
-        lambda x: x + jnp.asarray(RNG.normal(size=x.shape), x.dtype), tree)
-    part = partition_pytree(tree, 8, colocate=("net", "mu"))
-    codec = _codec(tree, part, group_size=2)
-    for use_pallas in (False, True):
-        fn = make_fused_maintain_fn(part, codec.layout, codec.group_of,
-                                    codec.n_groups, use_pallas=use_pallas,
-                                    interpret=True)
-        rep, sc, par = fn(tree, ck)
-        _tree_equal(rep, tree)
-        np.testing.assert_array_equal(np.asarray(par),
-                                      np.asarray(codec.parity))
-        want = block_scores(tree, ck, part, get_norm("l2"))
-        np.testing.assert_allclose(np.asarray(sc), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
-
-def test_leaf_group_metas_cover_all_blocks():
-    params = _params()
-    part = partition_pytree(params, 16)
-    codec = _codec(params, part)
-    metas = leaf_group_metas(part, codec.layout, codec.group_of)
-    for leaf, meta in zip(part.leaves, metas):
-        assert sorted(meta.perm.tolist()) == list(range(leaf.n_blocks))
-        assert meta.first[0] == 1
-        # members matrix lists every block exactly once
-        listed = meta.members[meta.members >= 0]
-        assert sorted(listed.tolist()) == list(range(leaf.n_blocks))
-
-
 def test_maintain_traffic_model_fused_wins():
+    """The arena sweep moves fewer bytes than the seed's three passes, and
+    feeding it the live arena (no pack) fewer still; its compact staging
+    is smaller than the seed's frames + gather."""
     params = _params()
     part = partition_pytree(params, 16)
     codec = _codec(params, part)
-    t = maintain_traffic(part, codec.layout, codec.group_of, codec.n_groups,
-                         codec.members.shape[1])
-    assert t["fused"] < t["seed"]
-    assert t["staging_fused"] < t["staging_seed"]
+    lay = build_arena_layout(part)
+    t = maintain_traffic(part, codec.layout, codec.n_groups,
+                         codec.members.shape[1], arena_layout=lay,
+                         routing=arena_routing(lay, codec.layout,
+                                               codec.group_of))
+    assert t["arena_resident"] < t["arena"] < t["seed"]
+    assert t["staging_arena"] < t["staging_seed"]
 
 
 # ---------------------------------------------------------------------------
@@ -182,28 +138,33 @@ def test_maintain_traffic_model_fused_wins():
 # ---------------------------------------------------------------------------
 
 def test_fabric_fused_matches_seed_maintain():
+    """The arena fabric's replica and parity equal ``replicas.refresh`` +
+    ``ParityCodec.encode`` of the same values, at fewer bytes."""
     params = _params()
     part = partition_pytree(params, 16)
-    fused = CheckpointFabric(part, FabricConfig(fused=True))
-    seed = CheckpointFabric(part, FabricConfig(fused=False))
-    fused.maintain(3, params)
-    seed.maintain(3, params)
-    assert fused.stats["fused_maintains"] == 1
-    assert seed.stats["fused_maintains"] == 0
-    _tree_equal(fused.replicas.values, seed.replicas.values)
-    np.testing.assert_array_equal(np.asarray(fused.parity.parity),
-                                  np.asarray(seed.parity.parity))
-    assert fused.replicas.is_fresh(3) and fused.parity.is_fresh(3)
-    assert fused.stats["maintain_bytes_moved"] < \
-        seed.stats["maintain_bytes_moved"]
+    fab = CheckpointFabric(part, FabricConfig())
+    fab.maintain(3, params)
+    assert fab.stats["arena_maintains"] == 1
+    seed = ReplicaSet(part, fab.view)
+    seed.refresh(3, params)
+    _tree_equal(fab.replicas.values, seed.values)
+    codec = ParityCodec(part, fab.view, group_size=fab.cfg.parity_group,
+                        use_pallas=False)
+    codec.encode(3, params)
+    np.testing.assert_array_equal(np.asarray(fab.parity.parity),
+                                  np.asarray(codec.parity))
+    assert fab.replicas.is_fresh(3) and fab.parity.is_fresh(3)
+    t = fab._traffic_model()
+    assert fab.stats["maintain_bytes_moved"] == t["arena"]
+    assert t["arena"] < t["replica_pass"] + t["parity_pass"]
 
 
 def test_fabric_fused_recovery_after_domain_loss():
-    """A host loss recovered from fused-maintained tiers is exact, and the
-    fused program rebuilds against the re-striped topology."""
+    """A host loss recovered from arena-maintained tiers is exact, and the
+    sweep program rebuilds against the re-striped topology."""
     params = _params()
     part = partition_pytree(params, 16)
-    fab = CheckpointFabric(part, FabricConfig(elastic=True, fused=True))
+    fab = CheckpointFabric(part, FabricConfig(elastic=True))
     ck = init_running_checkpoint(params, part)
     fab.maintain(5, params)
     lost, failed = fab.domain_failure("host", 0)
@@ -211,7 +172,7 @@ def test_fabric_fused_recovery_after_domain_loss():
     recovered, stats = fab.on_failure(params, ck.values, lost,
                                       failed_devices=failed, step=5)
     assert float(tree_sq_norm(recovered, params)) == 0.0
-    # elastic replan re-striped: next fused maintain must rebuild and stay
+    # elastic replan re-striped: next maintain must rebuild and stay
     # bit-consistent with a fresh seed encode on the same topology
     fab.maintain(6, params, force=True)
     want = jnp.array(fab.parity.parity)
@@ -220,10 +181,83 @@ def test_fabric_fused_recovery_after_domain_loss():
                                   np.asarray(fab.parity.parity))
 
 
+@pytest.mark.parametrize("case", ["replicate_only", "parity_only",
+                                  "off_interval"])
+def test_component_passes_match_the_oracles(case):
+    """Fabrics that cannot take the arena sweep — one tier off (no arena
+    layout), or tree input on a step where only one tier is due — run the
+    seed's component passes, and their tiers equal ``ReplicaSet.refresh``
+    / ``ParityCodec.encode`` of the same values."""
+    params = _params()
+    part = partition_pytree(params, 16)
+    kw = {"replicate_only": dict(parity=False),
+          "parity_only": dict(replicate=False),
+          "off_interval": dict(parity_interval=2)}[case]
+    fab = CheckpointFabric(part, FabricConfig(use_pallas=False, **kw))
+    assert (fab.arena_layout is None) == (case != "off_interval")
+    live = jax.tree_util.tree_map(lambda x: x + 1, params)
+    fab.maintain(2, params)       # both tiers due on step 2 everywhere
+    fab.maintain(3, live)         # off_interval: only the replica is due
+    assert fab.stats["arena_maintains"] == (case == "off_interval")
+    t = fab._traffic_model()
+    if case != "parity_only":
+        seed = ReplicaSet(part, fab.view)
+        seed.refresh(3, live)
+        _tree_equal(fab.replicas.values, seed.values)
+        assert fab.replicas.is_fresh(3)
+    if case == "off_interval":
+        # the parity still holds step 2's sweep; the replica pass alone ran
+        assert fab.parity.encoded_step == 2
+        assert fab.stats["maintain_bytes_moved"] == \
+            t["arena"] + t["replica_pass"]
+    elif case == "parity_only":
+        codec = ParityCodec(part, fab.view, group_size=fab.cfg.parity_group,
+                            use_pallas=False)
+        codec.encode(3, live)
+        np.testing.assert_array_equal(np.asarray(fab.parity.parity),
+                                      np.asarray(codec.parity))
+        assert fab.stats["maintain_bytes_moved"] == 2 * t["parity_pass"]
+    else:
+        assert fab.stats["maintain_bytes_moved"] == 2 * t["replica_pass"]
+
+
+def test_arena_routing_runs_once_per_striping(monkeypatch):
+    """The arena routing is built once per parity striping and read by
+    both the sweep program and the traffic model: three maintains, a loss
+    and a heal of a non-elastic fabric (the striping never changes)
+    build it exactly once."""
+    from repro.kernels.fused_maintain import ops
+    calls = []
+    real = ops.arena_routing
+
+    def counting(*a, **k):
+        calls.append(1)
+        return real(*a, **k)
+
+    monkeypatch.setattr(ops, "arena_routing", counting)
+    params = _params()
+    part = partition_pytree(params, 16)
+    fab = CheckpointFabric(part, FabricConfig(use_pallas=False))
+    ck = init_running_checkpoint(params, part)
+    fab._traffic_model()
+    for step in (1, 2, 3):
+        fab.maintain(step, params, ck.values)
+    lost, failed = fab.domain_failure("host", 0)
+    fab.on_failure(params, ck.values, lost, failed, step=3,
+                   persist_failure=True)
+    fab.maintain(4, params, ck.values)
+    fab.heal_domain("host", 0, params, step=4)
+    fab.maintain(5, params, ck.values)
+    fab.redundancy_nbytes()
+    assert len(calls) == 1
+    assert fab.stats["maintain_program_builds"] == 1
+    assert fab._arena_maintain_fn().routing is fab._arena_routing()
+
+
 def test_fabric_scores_cache_lifecycle():
     params = _params()
     part = partition_pytree(params, 16)
-    fab = CheckpointFabric(part, FabricConfig(fused=True))
+    fab = CheckpointFabric(part, FabricConfig())
     ck = init_running_checkpoint(params, part)
     drifted = jax.tree_util.tree_map(lambda x: x + 1, params)
     fab.maintain(2, drifted, ckpt_values=ck.values)
@@ -249,7 +283,7 @@ def test_checkpoint_forces_freshness_despite_off_interval_maintain():
                            recovery=RecoveryMode.PARTIAL, block_rows=16)
     ctl = FTController(params, pol,
                        fabric=FabricConfig(replicate_interval=2,
-                                           parity_interval=2, fused=True))
+                                           parity_interval=2))
     live = jax.tree_util.tree_map(lambda x: x + 1, params)
     ctl.maintain(3, live)                      # 3 % 2 != 0: refreshes nothing
     assert not ctl.fabric.is_fresh(3)
@@ -322,13 +356,13 @@ def test_controller_inplace_save_matches_rewrite_path():
 
 
 def test_controller_fused_scores_reused_for_priority():
-    """maintain() before a PRIORITY save caches fused scores; the save
+    """maintain() before a PRIORITY save caches the sweep's scores; the save
     consumes them (no third pass) and still selects the same blocks."""
     params = _params()
     pol = CheckpointPolicy(fraction=0.25, full_interval=1,
                            strategy=SelectionStrategy.PRIORITY,
                            recovery=RecoveryMode.PARTIAL, block_rows=16)
-    fab = FabricConfig(fused=True)
+    fab = FabricConfig()
     ctl = FTController(params, pol, fabric=fab, rng=jax.random.PRNGKey(0))
     plain = FTController(params, pol, rng=jax.random.PRNGKey(0))
     live = jax.tree_util.tree_map(lambda x: x + 1, params)

@@ -8,8 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.block_dist.kernel import block_dist_pallas
-from repro.kernels.block_dist.ref import block_dist_ref
 from repro.kernels.masked_restore.kernel import masked_restore_pallas
 from repro.kernels.masked_restore.ref import masked_restore_ref
 from repro.kernels.ssd_scan.kernel import ssd_intra_pallas
@@ -19,28 +17,6 @@ from repro.kernels.sw_attention.kernel import sw_attention_pallas
 from repro.kernels.sw_attention.ref import sw_attention_ref
 
 RNG = np.random.default_rng(7)
-
-
-# ---------------------------------------------------------------------------
-# block_dist
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("shape", [(1, 1), (5, 100), (8, 512), (33, 777),
-                                   (128, 2048), (7, 4096)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_block_dist_sweep(shape, dtype):
-    a = jnp.asarray(RNG.normal(size=shape), dtype)
-    b = jnp.asarray(RNG.normal(size=shape), dtype)
-    got = block_dist_pallas(a, b, interpret=True)
-    want = block_dist_ref(a, b)
-    tol = 1e-5 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
-
-
-def test_block_dist_zero_distance():
-    a = jnp.asarray(RNG.normal(size=(16, 300)), jnp.float32)
-    np.testing.assert_allclose(block_dist_pallas(a, a, interpret=True),
-                               np.zeros(16), atol=1e-7)
 
 
 # ---------------------------------------------------------------------------

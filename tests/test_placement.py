@@ -74,9 +74,12 @@ def _assert_elastic_invariants(fab):
                 != np.asarray(view.host_of(view.homes))), \
                 "replica shares its primary's host"
     if fab.parity is not None:
+        # a 1-member XOR group is a bare copy, so XOR folds the tail; with
+        # m >= 2 parity rows RS keeps a 1-member tail group as it is
+        least = 2 if fab.parity.n_parity < 2 else 1
         for j, row in enumerate(fab.parity.members):
             ids = row[row >= 0]
-            assert ids.size >= 2, f"parity group {j} has < 2 members"
+            assert ids.size >= least, f"parity group {j} has < {least} members"
             assert view.alive[view.homes[ids]].all(), \
                 f"parity group {j} has a dead member home"
         assert view.alive[fab.parity.parity_homes].all()
@@ -329,35 +332,40 @@ def _program_counts(fab):
             fab.stats["maintain_program_reuses"])
 
 
-def _sweep_outputs(fab, pipeline, live, z):
+def _sweep_outputs(fab, live, z):
     """One call of the fabric's cached program, and of one built afresh
     from the codec's current striping, on the same values."""
-    from repro.kernels.fused_maintain.ops import (ArenaMaintainProgram,
-                                                  make_fused_maintain_fn)
+    from repro.kernels.fused_maintain.ops import ArenaMaintainProgram
     codec = fab.parity
-    if pipeline == "arena":
-        fresh = ArenaMaintainProgram(fab.partition, fab.arena_layout,
-                                     codec.layout, codec.group_of,
-                                     codec.n_groups, use_pallas=False)
-        return fab._arena_maintain_fn()(live, z), fresh(live, z)
-    fresh = make_fused_maintain_fn(fab.partition, codec.layout,
-                                   codec.group_of, codec.n_groups,
-                                   use_pallas=False)
-    return fab._fused_maintain_fn()(live, z), fresh(live, z)
+    fresh = ArenaMaintainProgram(fab.partition, fab.arena_layout,
+                                 codec.layout, codec.group_of,
+                                 codec.n_groups, use_pallas=False)
+    return fab._arena_maintain_fn()(live, z), fresh(live, z)
 
 
-@pytest.mark.parametrize("pipeline", ["arena", "fused"])
-def test_inplace_loss_and_heal_reuse_the_maintain_program(pipeline):
+# the XOR codec, and RS(k, 2), whose rows the fabric re-encodes from the
+# sweep's snapshot arena beside the same cached program
+CODECS = [pytest.param(0, id="arena"), pytest.param(2, id="arena_rs2")]
+
+
+@pytest.mark.parametrize("rs_parity", CODECS)
+def test_inplace_loss_and_heal_reuse_the_maintain_program(rs_parity):
     """Without ``elastic`` a loss and a heal move the view but never the
     striping: the sweep program is built once, every maintain after a
     view move reuses it, and each maintain's parity is bit-identical to a
-    plain ``ParityCodec.encode`` of the same values."""
+    plain encode of the same values by the fabric's codec."""
+    from repro.fabric.rs import RSCodec
     params = _striping_params(0)
     part = partition_pytree(params, 16)
-    fab = _fabric(part, elastic=False, arena=pipeline == "arena")
-    assert (fab.arena_layout is not None) == (pipeline == "arena")
-    ref = ParityCodec(part, ClusterView(fab.domains, fab.view.homes.copy()),
-                      group_size=fab.cfg.parity_group, use_pallas=False)
+    fab = _fabric(part, elastic=False, rs_parity=rs_parity)
+    assert fab.arena_layout is not None
+    view = ClusterView(fab.domains, fab.view.homes.copy())
+    if rs_parity:
+        ref = RSCodec(part, view, group_size=fab.cfg.parity_group,
+                      n_parity=rs_parity, use_pallas=False)
+    else:
+        ref = ParityCodec(part, view, group_size=fab.cfg.parity_group,
+                          use_pallas=False)
     ckpt = init_running_checkpoint(params, part)
 
     def maintain_and_check(step):
@@ -386,12 +394,12 @@ def test_inplace_loss_and_heal_reuse_the_maintain_program(pipeline):
     maintain_and_check(7)
     assert fab.parity.striping == striping
     assert _program_counts(fab) == (1, 3)
-    swept = "arena_maintains" if pipeline == "arena" else "fused_maintains"
-    assert fab.stats[swept] == 7
+    assert fab.stats["arena_maintains"] == 7
+    assert fab.stats["rs_arena_encodes"] == (7 if rs_parity else 0)
 
 
-@pytest.mark.parametrize("pipeline", ["arena", "fused"])
-def test_elastic_restripe_builds_once_per_striping(pipeline):
+@pytest.mark.parametrize("rs_parity", CODECS)
+def test_elastic_restripe_builds_once_per_striping(rs_parity):
     """An elastic loss re-stripes and builds a program for the new
     striping. The heal rebalances onto the restored host, which is a
     striping of its own; from then on a loss and a heal of that host
@@ -400,10 +408,10 @@ def test_elastic_restripe_builds_once_per_striping(pipeline):
     afresh for the striping in force."""
     params = _striping_params(1)
     part = partition_pytree(params, 16)
-    fab = _fabric(part, arena=pipeline == "arena")
+    fab = _fabric(part, rs_parity=rs_parity)
     ckpt = init_running_checkpoint(params, part)
     live = _noisy(params, seed=1)
-    z = fab._as_arena(ckpt.values) if pipeline == "arena" else ckpt.values
+    z = fab._as_arena(ckpt.values)
     fab.maintain(1, live, ckpt.values)
     seen = [fab.parity.striping]
     counts = [_program_counts(fab)]
@@ -411,7 +419,7 @@ def test_elastic_restripe_builds_once_per_striping(pipeline):
     def record():
         seen.append(fab.parity.striping)
         counts.append(_program_counts(fab))
-        cached, fresh = _sweep_outputs(fab, pipeline, live, z)
+        cached, fresh = _sweep_outputs(fab, live, z)
         for a, b in zip(jax.tree_util.tree_leaves(cached),
                         jax.tree_util.tree_leaves(fresh)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -419,9 +427,7 @@ def test_elastic_restripe_builds_once_per_striping(pipeline):
     def cycle(step):
         lost, failed = fab.domain_failure("host", 0)
         fab.on_failure(live, ckpt.values, lost, failed, step=step)
-        if pipeline == "fused":      # the arena re-plan sweeps at once
-            fab.maintain(step, live, ckpt.values, force=True)
-        record()
+        record()                     # the re-plan sweeps at once
         fab.heal_domain("host", 0, live, step=step + 1)
         fab.maintain(step + 1, live, ckpt.values, force=True)
         record()

@@ -152,7 +152,7 @@ def test_arena_gated_fallback_warns_and_records():
     rec = Recorder()
     loop = TrainLoop(cfg, ctx, loop_cfg=TrainLoopConfig(
         policy=CheckpointPolicy.scar(fraction=0.25, interval=2),
-        fabric=FabricConfig(fused=False),       # gates the arena pipeline
+        fabric=FabricConfig(parity=False),      # no arena layout: one tier
         arena_state=True, recorder=rec))
     with pytest.warns(UserWarning, match="not arena-capable"):
         state = loop.init_state()
@@ -278,7 +278,7 @@ from repro.fabric import CheckpointFabric
 import jax.numpy as jnp
 part = partition_pytree({"w": jnp.zeros((64, 8), jnp.float32)}, block_rows=8)
 try:
-    CheckpointFabric(part, FabricConfig(fused=False), mesh=mesh)
+    CheckpointFabric(part, FabricConfig(parity=False), mesh=mesh)
 except ValueError as e:
     assert "arena" in str(e).lower(), e
     print("SPMD-GATE-OK")

@@ -77,24 +77,6 @@ def test_controller_with_persistent_store_lifecycle():
         assert ctl.stats["bytes_mirrored"] > 0
 
 
-def test_kernel_backed_controller_matches_jnp(key):
-    """FTController with the Pallas block_dist scorer selects the same
-    priority blocks as the jnp path."""
-    from repro.core.blocks import partition_pytree
-    from repro.kernels.block_dist.ops import make_score_fn
-    params = {"w": jnp.asarray(np.random.default_rng(0).normal(
-        size=(256, 8)), jnp.float32)}
-    pol = CheckpointPolicy.scar(0.25, 8)
-    part = partition_pytree(params, pol.block_rows)
-    ctl_jnp = FTController(params, pol)
-    ctl_krn = FTController(params, pol,
-                           score_fn=make_score_fn(part, interpret=True))
-    p2 = {"w": params["w"].at[:64].add(50.0)}
-    m1 = ctl_jnp.checkpoint_now(1, p2)
-    m2 = ctl_krn.checkpoint_now(1, p2)
-    np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
-
-
 def test_server_generates(key):
     ctx = single_device_ctx()
     cfg = get_config("granite-8b", reduced=True)

@@ -1,5 +1,6 @@
 """Compile every Pallas kernel of the fault-tolerance path for a TPU v5e
-chip, at the arena sizes of qwen2-1.5b (published widths, 4 layers).
+chip, at the arena sizes of qwen2-1.5b (published widths, 4 layers), and
+the arena kernels also at mamba2-370m's.
 
 Interpret mode (every other kernel test) runs the kernel body in Python
 and never checks what the TPU compiler refuses: block shapes that are not
@@ -38,10 +39,9 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def qwen():
-    """Partition, f32 arena layout and parity striping of qwen2-1.5b at
-    its published widths (f32 leaves: the compiled arena sweep is an f32
+def _arena_case(name):
+    """Partition, f32 arena layout and parity striping of ``name`` at its
+    published widths (f32 leaves: the compiled arena sweep is an f32
     program), built from shapes only."""
     from repro.configs import get_config
     from repro.core.arena import build_arena_layout
@@ -52,7 +52,7 @@ def qwen():
     from repro.kernels.fused_maintain.ops import arena_routing
     from repro.models import get_model
     from repro.sharding.partition import block_device_homes
-    cfg = dataclasses.replace(get_config("qwen2-1.5b"), n_layers=LAYERS,
+    cfg = dataclasses.replace(get_config(name), n_layers=LAYERS,
                               dtype="float32")
     ops = get_model(cfg)
     shapes = jax.eval_shape(lambda r: ops.init_params(r, cfg),
@@ -67,6 +67,16 @@ def qwen():
                 embed=embed)
 
 
+@pytest.fixture(scope="module")
+def qwen():
+    return _arena_case("qwen2-1.5b")
+
+
+@pytest.fixture(scope="module")
+def mamba2():
+    return _arena_case("mamba2-370m")
+
+
 def _compile(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
@@ -77,9 +87,9 @@ def _spec(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-def test_arena_maintain_compiles(one_chip, qwen):
+def _compile_arena_maintain(one_chip, case):
     from repro.kernels.fused_maintain.kernel import arena_maintain_pallas
-    lay, r = qwen["layout"], qwen["routing"]
+    lay, r = case["layout"], case["routing"]
     arena = _spec(one_chip, (lay.total_words // 128, 128), jnp.float32)
     _compile(functools.partial(arena_maintain_pallas, perm=r.perm,
                                dest=r.dest, first=r.first,
@@ -88,24 +98,29 @@ def test_arena_maintain_compiles(one_chip, qwen):
              arena, arena)
 
 
-def test_arena_scatter_compiles(one_chip, qwen):
+def _compile_arena_scatter(one_chip, case):
     from repro.kernels.fused_maintain.kernel import arena_scatter_pallas
-    lay = qwen["layout"]
+    lay = case["layout"]
     arena = _spec(one_chip, (lay.total_words // 128, 128), jnp.float32)
     k = 1 << int(np.ceil(np.log2(lay.n_tiles // 8)))   # a 1/8 save, bucketed
     _compile(functools.partial(arena_scatter_pallas, interpret=False),
              arena, arena, _spec(one_chip, (k,), jnp.int32))
 
 
-def test_fused_maintain_compiles(one_chip, qwen):
-    from repro.kernels.fused_maintain.kernel import fused_maintain_pallas
-    leaf = qwen["embed"]
-    s, e = leaf.n_blocks, 128 * leaf.row_width
-    view = _spec(one_chip, (s, e), jnp.float32)
-    idx = _spec(one_chip, (s,), jnp.int32)
-    _compile(functools.partial(fused_maintain_pallas,
-                               n_out_rows=-(-s // 4), interpret=False),
-             view, view, idx, idx, idx)
+def test_arena_maintain_compiles(one_chip, qwen):
+    _compile_arena_maintain(one_chip, qwen)
+
+
+def test_arena_scatter_compiles(one_chip, qwen):
+    _compile_arena_scatter(one_chip, qwen)
+
+
+def test_arena_maintain_compiles_mamba2(one_chip, mamba2):
+    _compile_arena_maintain(one_chip, mamba2)
+
+
+def test_arena_scatter_compiles_mamba2(one_chip, mamba2):
+    _compile_arena_scatter(one_chip, mamba2)
 
 
 def test_scatter_save_compiles(one_chip, qwen):
@@ -145,12 +160,3 @@ def test_masked_restore_compiles(one_chip, qwen):
                  jnp.float32)
     _compile(functools.partial(masked_restore_pallas, interpret=False),
              view, view, _spec(one_chip, (leaf.n_blocks,), jnp.bool_))
-
-
-def test_block_dist_compiles(one_chip, qwen):
-    from repro.kernels.block_dist.kernel import block_dist_pallas
-    leaf = qwen["embed"]
-    view = _spec(one_chip, (leaf.n_blocks, 128 * leaf.row_width),
-                 jnp.float32)
-    _compile(functools.partial(block_dist_pallas, interpret=False),
-             view, view)
